@@ -317,7 +317,14 @@ def fleet_main(n_fleet: int, n_clients: int, smoke: bool) -> int:
     import os
     import signal
 
+    import jax
     import numpy as np
+
+    # A chip serves one process at a time and the members need it: this
+    # coordinator builds, refreshes and computes its serial reference on
+    # the CPU backend (results are venue-independent), so it never holds
+    # a chip while the supervisor spawns members.
+    jax.config.update("jax_platforms", "cpu")
 
     from hyperspace_tpu import Hyperspace, HyperspaceSession, IndexConfig, col
     from hyperspace_tpu import stats as hs_stats

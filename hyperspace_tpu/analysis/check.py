@@ -57,10 +57,10 @@ engine (analysis/program.py → callgraph.py → locks.py):
   static arguments and pad widths derive from declared bounded domains
   — ``compat.KNOWN_STATIC_DOMAINS``), donation/aliasing safety
   (zero-copy staged views are never mutated or donated; callers go
-  through ``ColumnTable.own_arrays``), and kernel fallback-ladder
+  through ``ColumnTable.own_arrays``), and kernel eligibility-ladder
   completeness (``ops.KNOWN_KERNELS``: every Pallas engagement proves
-  an exactness gate, a permanent per-shape fallback and its
-  ``device.kernel.*`` counters). The inferred trace graph, donation
+  an eligibility rule, its ``device.kernel.*`` counters, and no broad
+  handler that would swallow a lowering error). The inferred trace graph, donation
   proof and per-kernel ladder proofs land in the report's
   ``trace_domains``.
 - **HSL027-030 durability domains** (analysis/duradomain.py) — the

@@ -191,7 +191,7 @@ RULES: dict[str, RuleInfo] = {
                  "zero-copy staged view mutated or donated without own_arrays; donated buffer referenced after the jitted call",
                  scope="program"),
         RuleInfo("HSL026", "kernel-fallback-ladder",
-                 "Pallas engagement undeclared in ops.KNOWN_KERNELS or missing its exactness gate, permanent fallback, or device.kernel.* counters",
+                 "Pallas engagement undeclared in ops.KNOWN_KERNELS, missing its eligibility rule or device.kernel.* counters, or swallowing lowering errors in a broad except",
                  scope="program"),
         RuleInfo("HSL027", "durable-atomic-publish",
                  "durable write under a DURABLE_ROOTS plane does not reach the mkstemp + fsync + os.replace idiom — crash can surface a torn or zero-length file",

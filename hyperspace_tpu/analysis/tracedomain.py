@@ -6,8 +6,8 @@ bodies are host-effect-free, jit cache keys come from a bounded
 signature space (obs/runtime.py's ``jit.recompile_storm`` event merely
 *observes* violations after the fact), zero-copy staged arrays
 (execution/staging.py's writeable=False => identity-stable contract)
-are never mutated or donated, and every Pallas engagement sits behind a
-provable-exactness gate with a permanent per-shape fallback. This
+are never mutated or donated, and every Pallas engagement sits behind an
+explicit eligibility rule whose lowering errors raise. This
 module is the device-plane dual of :mod:`procdomain`: instead of
 inferring which code runs in which *process*, it infers which code runs
 inside a *trace*, then turns each convention into a checked rule.
@@ -64,17 +64,18 @@ inside a *trace*, then turns each convention into a checked rule.
   (empty today — that IS the proof), and the ``own_arrays`` ownership
   gateways with call-chain witnesses.
 
-- **HSL026 kernel fallback-ladder completeness.** Every Pallas
+- **HSL026 kernel eligibility-ladder completeness.** Every Pallas
   engagement must be declared in ``ops.KNOWN_KERNELS`` (mirroring
   ``faults.KNOWN_POINTS``, both directions: undeclared engagements and
   stale registry entries are findings), and its *engagement closure*
   (the kernel factory plus its same-module transitive callers) must
-  statically contain the full ladder: an exactness/eligibility gate (a
-  comparison against an uppercase module constant), a permanent
-  per-shape fallback (a ``*bad*`` set consulted with ``in``/``not in``
-  and grown with ``.add`` under a lock), and both a success and a
-  fallback ``device.kernel.*`` counter, each declared in
-  ``stats.KNOWN_COUNTERS``. The report carries a per-kernel ladder
+  statically contain the full ladder: an explicit eligibility rule (a
+  comparison against an uppercase module constant), both a success and
+  a fallback ``device.kernel.*`` counter, each declared in
+  ``stats.KNOWN_COUNTERS`` — and no broad handler (bare ``except``,
+  ``except Exception``/``BaseException``) anywhere in the closure: a
+  lowering error of an eligible call must raise, never reroute the
+  call to the lax path unseen. The report carries a per-kernel ladder
   proof with the caller-chain witness from the public op down to the
   factory.
 
@@ -154,6 +155,16 @@ def declared_static_domains(program: Program) -> set[str] | None:
                     if isinstance(k, ast.Constant) and isinstance(k.value, str)
                 )
     return out
+
+
+def _broad_handler(handler: ast.ExceptHandler) -> bool:
+    """A handler that catches every error: bare, Exception or BaseException
+    (alone or inside a tuple)."""
+    t = handler.type
+    if t is None:
+        return True
+    names = t.elts if isinstance(t, ast.Tuple) else [t]
+    return any(_dotted(n) in ("Exception", "BaseException") for n in names)
 
 
 def _registry_site(program: Program, name: str) -> tuple[ModuleInfo, int] | None:
@@ -976,7 +987,7 @@ class TraceDomains:
             self._donation["proven"] = False
         return out
 
-    # -- HSL026: kernel fallback-ladder completeness ---------------------------
+    # -- HSL026: kernel eligibility-ladder completeness ------------------------
 
     def _build_ladders(self) -> list[dict]:
         prog, cg = self.program, self.callgraph
@@ -1001,8 +1012,7 @@ class TraceDomains:
                 if path is not None:
                     engagement[q] = path
 
-            gate = bad_set = None
-            bad_add = False
+            gate = swallow = None
             counters: dict[str, tuple[str, int]] = {}
             for q in sorted(engagement):
                 fn = prog.functions[q]
@@ -1015,22 +1025,14 @@ class TraceDomains:
                             if isinstance(b, ast.Name) and _uppercase_const(b.id):
                                 gate = {"fn": q, "line": sub.lineno}
                                 break
-                    if isinstance(sub, ast.Compare) and bad_set is None:
-                        if any(isinstance(op, (ast.In, ast.NotIn)) for op in sub.ops):
-                            for b in ast.walk(sub):
-                                if (
-                                    isinstance(b, (ast.Name, ast.Attribute))
-                                    and "bad" in (_dotted(b) or "").lower()
-                                ):
-                                    bad_set = {"fn": q, "line": sub.lineno}
-                                    break
+                    if (
+                        isinstance(sub, ast.ExceptHandler)
+                        and swallow is None
+                        and _broad_handler(sub)
+                    ):
+                        swallow = {"fn": q, "line": sub.lineno}
                     if isinstance(sub, ast.Call):
                         d = _dotted(sub.func)
-                        if (
-                            d.rsplit(".", 1)[-1] == "add"
-                            and "bad" in d.lower()
-                        ):
-                            bad_add = True
                         if (
                             d.rsplit(".", 1)[-1] == "increment"
                             and sub.args
@@ -1047,14 +1049,14 @@ class TraceDomains:
                 "line": pallas_hosts[host],
                 "engagement": sorted(engagement),
                 "gate": gate,
-                "bad_set": bad_set if (bad_set and bad_add) else None,
+                "swallow": swallow,
                 "counters": {
                     name: {"fn": counters[name][0], "line": counters[name][1]}
                     for name in sorted(counters)
                 },
                 "witness": witness,
                 "proven": bool(
-                    gate and bad_set and bad_add
+                    gate and swallow is None
                     and any("fallback" in c for c in counters)
                     and any("fallback" not in c for c in counters)
                 ),
@@ -1081,7 +1083,7 @@ class TraceDomains:
                         message=(
                             f"Pallas engagement {lad['kernel']!r} (factory "
                             f"{host}) is not declared in ops.KNOWN_KERNELS — "
-                            f"declare it so the fallback ladder is tracked "
+                            f"declare it so the eligibility ladder is tracked "
                             f"(the declared-registry contract)"
                         ),
                         witness_paths=witness,
@@ -1090,9 +1092,10 @@ class TraceDomains:
             if lad["gate"] is None:
                 missing.append("exactness/eligibility gate (compare against an "
                                "uppercase bound constant)")
-            if lad["bad_set"] is None:
-                missing.append("permanent per-shape fallback (a *bad* set "
-                               "consulted with `in` and grown with .add)")
+            if lad["swallow"] is not None:
+                sw = lad["swallow"]
+                missing.append(f"lowering errors that raise (a broad except in "
+                               f"{sw['fn']} line {sw['line']} swallows them)")
             if not any("fallback" not in c for c in lad["counters"]):
                 missing.append("success counter (device.kernel.* increment on "
                                "the engaged path)")
@@ -1105,7 +1108,7 @@ class TraceDomains:
                     path=mod.path, line=lad["line"], col=0, rule=KERNEL_LADDER,
                     message=(
                         f"Pallas kernel {lad['kernel']!r} has an incomplete "
-                        f"fallback ladder (engagement chain {chain}): missing "
+                        f"eligibility ladder (engagement chain {chain}): missing "
                         + "; ".join(missing)
                     ),
                     witness_paths=witness,

@@ -7,10 +7,8 @@ shard_map`` that produced 66 collection errors and ~200 cascading test
 failures on jax 0.4.37. Every symbol jax has moved (or is likely to
 move) is resolved HERE and nowhere else:
 
-- ``shard_map``: ``jax.shard_map`` (new public API) falling back to
-  ``jax.experimental.shard_map.shard_map`` (0.4.x). Callers always use
-  the NEW kwarg spelling ``check_vma=``; the shim renames it to the
-  older ``check_rep=`` when the resolved function predates the rename.
+- ``shard_map``: ``jax.shard_map`` (jax 0.9; callers spell its
+  ``check_vma=`` kwarg).
 - Pallas: ``resolve_pallas()`` returns the ``pallas`` module from its
   current home (``jax.experimental.pallas`` today).
 - ``jit``: the package's one jit entry point. Same surface as
@@ -30,7 +28,6 @@ cannot be reintroduced by a future PR.
 from __future__ import annotations
 
 import functools
-import inspect
 
 #: The bounded signature-space registry (static-analysis rule HSL024,
 #: analysis/tracedomain.py). Every value that reaches a jit static
@@ -56,44 +53,16 @@ KNOWN_STATIC_DOMAINS = {
 }
 
 
-def _resolve_shard_map():
+def shard_map(f=None, **kwargs):
+    """``jax.shard_map``, usable directly or through
+    ``functools.partial(shard_map, mesh=..., ...)`` as a decorator (the
+    call style ops/* use); calling with the keyword arguments alone
+    returns a decorator, matching jax's own behavior."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None and not callable(sm):
-        # Some versions expose jax.shard_map as a MODULE holding the fn.
-        sm = getattr(sm, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: HSL001
-    return sm
-
-
-_SHARD_MAP = _resolve_shard_map()
-try:
-    _SHARD_MAP_PARAMS = frozenset(inspect.signature(_SHARD_MAP).parameters)
-except (TypeError, ValueError):
-    # No introspectable signature: assume the modern kwarg surface.
-    _SHARD_MAP_PARAMS = frozenset()
-
-
-def shard_map(f=None, **kwargs):
-    """``jax.shard_map`` with the modern kwarg surface on every jax.
-
-    Accepts the new-style ``check_vma=`` kwarg and rewrites it to the
-    pre-rename ``check_rep=`` when the installed jax wants that. Usable
-    directly or through ``functools.partial(shard_map, mesh=..., ...)``
-    as a decorator (the call style ops/* use); calling with the keyword
-    arguments alone returns a decorator, matching jax's own behavior.
-    """
-    if (
-        "check_vma" in kwargs
-        and _SHARD_MAP_PARAMS
-        and "check_vma" not in _SHARD_MAP_PARAMS
-    ):
-        kwargs["check_rep"] = kwargs.pop("check_vma")
     if f is None:
         return functools.partial(shard_map, **kwargs)
-    return _SHARD_MAP(f, **kwargs)
+    return jax.shard_map(f, **kwargs)
 
 
 def jit(fn=None, *, key: "str | None" = None, **jit_kwargs):
@@ -120,20 +89,15 @@ def jit(fn=None, *, key: "str | None" = None, **jit_kwargs):
 
 
 def enable_x64(new_val: bool = True):
-    """Scoped-x64 context manager: ``jax.enable_x64`` (new public API)
-    falling back to ``jax.experimental.enable_x64`` (0.4.x)."""
+    """Scoped-x64 context manager (``jax.enable_x64``)."""
     import jax
 
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx  # noqa: HSL001
-    return ctx(new_val)
+    return jax.enable_x64(new_val)
 
 
 def resolve_pallas():
     """The Pallas module, wherever this jax puts it. Kernel factories
-    import it lazily through here (Pallas is optional at runtime — the
-    topk kernel falls back to lax.top_k when lowering fails)."""
+    import it lazily through here."""
     from jax.experimental import pallas  # noqa: HSL001
 
     return pallas

@@ -32,7 +32,7 @@ INDEX_BUILD_MEMORY_BUDGET = "hyperspace.index.build.memoryBudgetBytes"
 INDEX_BUILD_CHUNK_BYTES = "hyperspace.index.build.chunkBytes"
 # Materialized-join execution venue: "auto" picks the host-native merge
 # kernel when measured device->host bandwidth is below joinVenueMinMbps
-# (the match pairs land on host either way; on tunneled devices the
+# (the match pairs land on host either way; over a slow link the
 # readback dominates), else the device kernel. "device"/"host" force it.
 JOIN_VENUE = "hyperspace.join.venue"
 JOIN_VENUE_MIN_MBPS = "hyperspace.join.venueMinMbps"

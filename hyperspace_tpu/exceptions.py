@@ -112,6 +112,19 @@ class WorkerCrashed(HyperspaceError):
         self.exitcode = exitcode
 
 
+class FleetCapacityError(HyperspaceError):
+    """More device-using fleet members were asked for than there are
+    accelerator chips free for them. A chip belongs to one process at a
+    time — a member past the count would fail or hang on libtpu's lock,
+    and none is free while the supervising process holds the chips — so
+    the supervisor refuses to start it."""
+
+    def __init__(self, msg: str, requested: int, slots: int):
+        super().__init__(msg)
+        self.requested = requested
+        self.slots = slots
+
+
 class WorkerFailed(HyperspaceError):
     """A pooled-build worker's task body raised: the worker posted the
     error (type, message, full traceback text) through the result queue

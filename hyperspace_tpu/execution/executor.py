@@ -336,7 +336,7 @@ class Executor(
 
     def _join_venue(self) -> str:
         """auto: host when the measured device→host link is slower than
-        the configured floor (tunneled deployments) AND the native library
+        the configured floor (a slow link) AND the native library
         built; the pairs land on host either way."""
         # Auto with a mesh keeps the distributed device kernel (the
         # query-plane sharding is the point); a forced "host" wins — the
@@ -417,31 +417,43 @@ class Executor(
         if not asc0:
             lanes = [~l for l in lanes]
         lu = lanes_as_unsigned(lanes[:2])
-        from hyperspace_tpu.parallel.mesh import mesh_size
+        from hyperspace_tpu.parallel.mesh import make_mesh, mesh_size
 
-        if (
-            self.mesh is not None
-            and mesh_size(self.mesh) > 1
-            # Venue-gated like every other operator: auto prefers the
-            # distributed kernel on a real mesh (the query-plane sharding
-            # is the point), HYPERSPACE_VENUE=host / sort_venue=host
-            # still force the host partition path.
-            and self._venue("sort_venue", "hyperspace.sort.venue", True, needs_native=False)
-            == "device"
-        ):
-            # Mesh-sharded selection: per-device first-n + one threshold
-            # broadcast; the ORDER BY participates in the mesh.
+        from hyperspace_tpu.parallel.bandwidth import pick_venue
+
+        sharded = self.mesh is not None and mesh_size(self.mesh) > 1
+        # Venue-gated like every other operator: auto prefers the
+        # distributed kernel on a real mesh (the query-plane sharding is
+        # the point); the host venue keeps the partition select. On one
+        # device auto stays on the host select whatever the link reads
+        # (the device select's full-length sort costs a ~50 s compile on
+        # v5e for a sub-second query); only an explicit device venue
+        # (conf or HYPERSPACE_VENUE) selects on that device.
+        if sharded:
+            venue = self._venue("sort_venue", "hyperspace.sort.venue", True, needs_native=False)
+        else:
+            venue = pick_venue(
+                self.conf.sort_venue if self.conf is not None else "auto",
+                float("inf"),
+                prefer_device=False,
+                what="hyperspace.sort.venue",
+                needs_native=False,
+            )
+        if venue == "device":
+            # Per-device first-n + one threshold broadcast; on a mesh
+            # the ORDER BY participates in every device.
             from hyperspace_tpu.ops.sortkeys import distributed_top_n_candidates
 
-            cand = distributed_top_n_candidates(lu, n, self.mesh)
+            mesh = self.mesh if sharded else make_mesh(n=1)
+            cand = distributed_top_n_candidates(lu, n, mesh)
             if cand is not None:
                 sub = table.take(cand)
                 self._phys(
                     "TopN",
                     n=n,
-                    kernel="mesh-sharded-select + sort",
+                    kernel=("mesh-sharded-select" if sharded else "device-select") + " + sort",
                     candidates=len(cand),
-                    devices=mesh_size(self.mesh),
+                    devices=mesh_size(mesh),
                 )
                 full = self._sorted_table(sub, sort_plan)
                 return full.take(np.arange(min(n, full.num_rows)))
@@ -455,7 +467,7 @@ class Executor(
         # candidate set settles the rest.
         cand = np.flatnonzero(kpack <= thr)
         sub = table.take(cand)
-        self._phys("TopN", n=n, kernel="partition-select + sort", candidates=len(cand))
+        self._phys("TopN", n=n, kernel="host-partition-select + sort", candidates=len(cand))
         full = self._sorted_table(sub, sort_plan)
         return full.take(np.arange(min(n, full.num_rows)))
 

@@ -32,39 +32,6 @@ _EVT_QUARANTINED = obs_events.declare("index.quarantined")
 _EVT_DEMOTED = obs_events.declare("advisor.routing.demoted")
 
 
-def _enable_persistent_compile_cache() -> None:
-    """Point XLA's persistent compilation cache at a stable directory so
-    short-lived processes skip the 1-40s first-compile cost (the fixed
-    overhead that dominated small-scale builds). Opt out with
-    HYPERSPACE_XLA_CACHE_DIR=''. Idempotent; failures are non-fatal."""
-    import os
-
-    d = os.environ.get("HYPERSPACE_XLA_CACHE_DIR")
-    if d is None:
-        base = os.environ.get("HYPERSPACE_CACHE_DIR") or os.path.expanduser(
-            "~/.cache/hyperspace_tpu"
-        )
-        d = os.path.join(base, "xla")
-    if not d:
-        return
-    try:
-        import jax
-
-        if jax.config.jax_compilation_cache_dir:
-            return  # user already configured one
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-    except Exception as e:
-        # Best-effort speedup, never fatal — but leave a trace so a
-        # mysteriously slow first compile is explainable.
-        import logging
-
-        logging.getLogger("hyperspace_tpu").debug(
-            "persistent compile cache unavailable: %s", e
-        )
-
-
 @dataclasses.dataclass
 class QueryOutcome:
     """Per-query handle state: everything one `run` produced, owned by
@@ -98,7 +65,9 @@ class HyperspaceSession:
             kwargs["system_path"] = str(system_path)
         if num_buckets is not None:
             kwargs["num_buckets"] = int(num_buckets)
-        _enable_persistent_compile_cache()
+        from hyperspace_tpu.parallel.mesh import enable_compile_cache
+
+        enable_compile_cache()
         self.conf = HyperspaceConf(**kwargs)
         self.mesh = mesh
         self._enabled = False

@@ -1,8 +1,8 @@
 """Loader for the native host-runtime kernels (hashing.cpp).
 
-Compiles the C++ on first use with g++ (cached as a .so keyed by source
-hash under ~/.cache/hyperspace_tpu/native) and binds it via ctypes — no
-pybind11 dependency. Every caller falls back to the numpy implementation
+Compiles the committed C++ on first use with g++ (cached as a .so keyed
+by source hash under <repo>/.native_cache, listed in .gitignore) and
+binds it via ctypes — no pybind11 dependency. Every caller falls back to the numpy implementation
 when the toolchain or the build is unavailable, so this module is a pure
 accelerator: `available()` reports which path is active.
 """
@@ -24,11 +24,8 @@ _tried = False
 
 
 def _cache_dir() -> Path:
-    root = os.environ.get(
-        "HYPERSPACE_TPU_NATIVE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "hyperspace_tpu", "native"),
-    )
-    return Path(root)
+    root = os.environ.get("HYPERSPACE_TPU_NATIVE_CACHE")
+    return Path(root) if root else _SRC.parents[2] / ".native_cache"
 
 
 def _build() -> Path | None:

@@ -272,8 +272,8 @@ void hs_sort_range(int64_t* perm, int64_t count, const uint32_t* lanes,
 
 // ---- bucket-parallel sorted merge join ------------------------------------
 // The host venue of the zero-exchange SMJ: both sides arrive as int32 key
-// codes sorted within each bucket (the index file layout). On tunneled-TPU
-// deployments device->host readback of the match pairs dominates the whole
+// codes sorted within each bucket (the index file layout). Over a slow
+// device->host link the readback of the match pairs dominates the whole
 // join; the pairs land on host either way, and the sorted runs are already
 // host-resident, so an exact two-pass merge here beats the device round-trip
 // whenever the link is slow (executor._join_venue decides by measured

@@ -3,10 +3,11 @@ from hyperspace_tpu.ops.hashing import bucket_ids, combine_hashes, hash_int_colu
 #: Every Pallas kernel the package ships, by its jit call-site key
 #: (static-analysis rule HSL026, analysis/tracedomain.py — the mirror
 #: of ``faults.KNOWN_POINTS``). Each declared kernel's engagement chain
-#: must statically carry the full fallback ladder: an exactness gate, a
-#: permanent per-shape bad-set fallback, and both ``device.kernel.*``
-#: counters. Undeclared engagements and stale entries are findings, so
-#: this tuple is provably the complete kernel inventory.
+#: must statically carry the full eligibility ladder: an explicit
+#: shape/dtype rule, both ``device.kernel.*`` counters, and no broad
+#: ``except`` that swallows a lowering error. Undeclared engagements and
+#: stale entries are findings, so this tuple is provably the complete
+#: kernel inventory.
 KNOWN_KERNELS = (
     "ops.aggregate.pallas_segment_reduce",
     "ops.sortkeys.pallas_run_bounds",

@@ -23,9 +23,9 @@ bounds come from the tiled Pallas searchsorted
 (ops/sortkeys.pallas_run_bounds — the secondary row resident in VMEM,
 one vectorized compare-and-count per tile) and feed the same lax
 epilogue; bounds are integers, so results are byte-identical to the
-all-lax path by construction. Ineligible shapes or failed lowerings
-fall back transparently (`device.kernel.fused`/`device.kernel.fallbacks`
-count the split).
+all-lax path by construction. Ineligible shapes take the lax
+searchsorted (`device.kernel.fused`/`device.kernel.fallbacks` count the
+split); a lowering failure of an eligible shape raises.
 """
 
 from __future__ import annotations
@@ -37,7 +37,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from hyperspace_tpu import stats
 from hyperspace_tpu.compat import jit
 from hyperspace_tpu.obs import trace as obs_trace
 
@@ -163,8 +162,9 @@ def fused_join_aggregate(
     """Host wrapper: pads the group dimension (+1 dead segment for pads)
     and runs the fused device program on the persistent x64 worker thread
     (parallel/x64.py). Returns [C, num_groups] float64. `fused` = "auto"
-    tries the Pallas run-bounds kernel first (identical integer bounds,
-    so identical results), with the lax searchsorted as the fallback."""
+    takes the Pallas run-bounds kernel where its shape rule admits the
+    call (identical integer bounds, so identical results), and the lax
+    searchsorted otherwise."""
     from hyperspace_tpu.execution.device_cache import device_put_cached
     from hyperspace_tpu.ops.sortkeys import pallas_run_bounds
     from hyperspace_tpu.parallel.x64 import run_x64
@@ -184,10 +184,6 @@ def fused_join_aggregate(
                 buckets=pk.shape[0], secondary=sk.shape[1],
             ):
                 bounds = pallas_run_bounds(pk_dev, sk_dev)
-            if bounds is not None:
-                stats.increment("device.kernel.fused")
-            else:
-                stats.increment("device.kernel.fallbacks")
         if bounds is not None:
             out = _fused_join_agg_bounds(
                 pk_dev, sk_dev, bounds[0], bounds[1],

@@ -39,6 +39,10 @@ from hyperspace_tpu import stats
 from hyperspace_tpu.exceptions import HyperspaceError
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _flip32(v: np.ndarray) -> np.ndarray:
     """IEEE-754 int32 bit pattern → uint32 whose unsigned order equals the
     float order (negatives reversed, sign toggled)."""
@@ -186,26 +190,25 @@ def device_order_perm(table, by: list[tuple[str, bool]]) -> np.ndarray:
 
 
 # -- fused Pallas run bounds --------------------------------------------------
-# Batched searchsorted for the fused join-aggregate: every (bucket,
-# primary-row tile) program holds the bucket's WHOLE sorted secondary
-# key row in VMEM and counts `sk < pk` / `sk <= pk` with one vectorized
-# compare-and-sum — exactly searchsorted left/right on a sorted row,
-# integer-exact by construction (so results stay byte-identical to the
-# lax path), without the per-element binary-search while_loop XLA lowers
-# jnp.searchsorted to. Generalizes the ops/topk.py tiling (grid over
-# tiles, whole-reduction rows resident in VMEM).
+# Batched searchsorted for the fused join-aggregate: every (bucket-row
+# block, primary-row tile) program holds its buckets' WHOLE sorted
+# secondary key rows in VMEM and counts `sk < pk` / `sk <= pk` with one
+# vectorized compare-and-sum per row — exactly searchsorted left/right
+# on a sorted row, integer-exact by construction (so results stay
+# byte-identical to the lax path), without the per-element
+# binary-search while_loop XLA lowers jnp.searchsorted to. Generalizes
+# the ops/topk.py tiling (grid over tiles, whole-reduction rows resident
+# in VMEM).
 _RB_TILE = 128
+# Bucket rows per program: the TPU's sublane count, so a block's last
+# two dimensions are (8, 128)-aligned for any bucket count.
+_RB_ROWS = 8
 # The secondary row must fit VMEM beside the (tile, Ls) compare block.
 _RB_MAX_SECONDARY = 8192
 # Interpret mode (CPU tests) pays a python-level grid loop per program:
 # bound total compare work so the fused path never engages where the
 # brute-force O(Lp*Ls) sweep would dwarf the O(Lp log Ls) lax path.
 _RB_INTERPRET_WORK = 1 << 24
-
-import threading as _threading
-
-_pallas_rb_bad: set = set()
-_pallas_rb_bad_lock = _threading.Lock()
 
 
 @functools.lru_cache(maxsize=32)
@@ -216,33 +219,39 @@ def _make_run_bounds_kernel(tile: int, ls_pad: int, interpret: bool):
     from hyperspace_tpu.compat import jit, resolve_pallas
 
     pl = resolve_pallas()
+    zero = np.int32(0)
 
     def kernel(pk_ref, sk_ref, st_ref, en_ref):
-        pk = pk_ref[0, :]  # (tile,) int32, sorted or not — bounds are per-element
-        sk = sk_ref[0, :]  # (ls_pad,) int32, sorted (pads carry dtype max)
-        cmp = sk[None, :] < pk[:, None]
-        st_ref[0, :] = jnp.sum(cmp.astype(jnp.int32), axis=1)
-        en_ref[0, :] = jnp.sum((sk[None, :] <= pk[:, None]).astype(jnp.int32), axis=1)
+        for r in range(_RB_ROWS):  # static unroll over the block's buckets
+            pk = pk_ref[r, :]  # (tile,) int32, sorted or not — bounds are per-element
+            sk = sk_ref[r, :]  # (ls_pad,) int32, sorted (pads carry dtype max)
+            st_ref[r, :] = jnp.sum(sk[None, :] < pk[:, None], axis=1, dtype=jnp.int32)
+            en_ref[r, :] = jnp.sum(sk[None, :] <= pk[:, None], axis=1, dtype=jnp.int32)
 
     def run(pk, sk):  # pk [B, lp_pad], sk [B, ls_pad]; lp_pad % tile == 0
         b, lp = pk.shape
-        return pl.pallas_call(
+        b_pad = _round_up(b, _RB_ROWS)
+        if b_pad != b:  # dead bucket rows; their bounds are sliced away
+            pk = jnp.pad(pk, ((0, b_pad - b), (0, 0)))
+            sk = jnp.pad(sk, ((0, b_pad - b), (0, 0)))
+        st, en = pl.pallas_call(
             kernel,
-            grid=(b, lp // tile),
+            grid=(b_pad // _RB_ROWS, lp // tile),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda i, j: (i, j)),
-                pl.BlockSpec((1, ls_pad), lambda i, j: (i, 0)),
+                pl.BlockSpec((_RB_ROWS, tile), lambda i, j: (i, j)),
+                pl.BlockSpec((_RB_ROWS, ls_pad), lambda i, j: (i, zero)),
             ],
             out_specs=[
-                pl.BlockSpec((1, tile), lambda i, j: (i, j)),
-                pl.BlockSpec((1, tile), lambda i, j: (i, j)),
+                pl.BlockSpec((_RB_ROWS, tile), lambda i, j: (i, j)),
+                pl.BlockSpec((_RB_ROWS, tile), lambda i, j: (i, j)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((b, lp), jnp.int32),
-                jax.ShapeDtypeStruct((b, lp), jnp.int32),
+                jax.ShapeDtypeStruct((b_pad, lp), jnp.int32),
+                jax.ShapeDtypeStruct((b_pad, lp), jnp.int32),
             ],
             interpret=interpret,
         )(pk, sk)
+        return st[:b], en[:b]
 
     return jit(run, key="ops.sortkeys.pallas_run_bounds")
 
@@ -251,31 +260,28 @@ def pallas_run_bounds(pk, sk):
     """(st, en) device arrays — per-row searchsorted left/right of the
     bucket-batched primary codes `pk` [B, Lp] into the sorted secondary
     codes `sk` [B, Ls] — via the fused Pallas kernel, or None when the
-    shape is ineligible or the lowering failed (caller keeps the lax
-    searchsorted path; results are identical either way). Lp must be a
-    multiple of the tile (the caller pads with sentinels)."""
+    shape is ineligible (caller keeps the lax searchsorted path; results
+    are identical either way). The rule is explicit: Lp a non-zero
+    multiple of the tile (the caller pads with sentinels), 0 < Ls <=
+    `_RB_MAX_SECONDARY`, and — in interpret mode only — bounded compare
+    work. A lowering or compile error of an eligible call raises."""
     import jax
 
     b, lp = pk.shape
     ls = sk.shape[1]
-    if ls > _RB_MAX_SECONDARY or lp % _RB_TILE or lp == 0 or ls == 0:
-        return None
     interpret = jax.default_backend() == "cpu"
-    if interpret and b * lp * ls > _RB_INTERPRET_WORK:
-        return None
-    with _pallas_rb_bad_lock:
-        if (_RB_TILE, ls) in _pallas_rb_bad:
-            return None
-    try:
-        run = _make_run_bounds_kernel(_RB_TILE, ls, interpret)
-        out = run(pk, sk)
-        stats.increment("device.kernel.fused")
-        return out
-    except Exception:  # noqa: BLE001 — fall back to the lax searchsorted
-        with _pallas_rb_bad_lock:
-            _pallas_rb_bad.add((_RB_TILE, ls))
+    if (
+        ls > _RB_MAX_SECONDARY or lp % _RB_TILE or lp == 0 or ls == 0
+        or (interpret and b * lp * ls > _RB_INTERPRET_WORK)
+    ):
         stats.increment("device.kernel.fallbacks")
         return None
+    out = _make_run_bounds_kernel(_RB_TILE, ls, interpret)(pk, sk)
+    stats.increment("device.kernel.fused")
+    return out
+
+
+_SORT_BATCH = 8
 
 
 @functools.lru_cache(maxsize=32)
@@ -347,7 +353,8 @@ def distributed_top_n_candidates(lanes_u32: np.ndarray, n: int, mesh) -> np.ndar
     by one local lax.sort; the n-th smallest prefix over the D*n union
     is an inclusive threshold; a sharded elementwise pass emits every
     row at or below it (prefix ties stay in — the exact candidate-set
-    sort settles total order). Returns None when the mesh cannot help."""
+    sort settles total order). On a one-device mesh this is the device
+    venue's selection. Returns None when the mesh cannot help."""
     import jax
     import jax.numpy as jnp
 
@@ -355,7 +362,7 @@ def distributed_top_n_candidates(lanes_u32: np.ndarray, n: int, mesh) -> np.ndar
 
     d = mesh_size(mesh)
     n_rows = lanes_u32.shape[1]
-    if d <= 1 or n <= 0 or n_rows < 2 * n * d:
+    if n <= 0 or n_rows < 2 * n * d:
         return None
     hi = lanes_u32[0]
     lo = lanes_u32[1] if lanes_u32.shape[0] > 1 else np.zeros(n_rows, np.uint32)
@@ -398,7 +405,10 @@ def device_sort_perms(tables, key_columns: list[str]) -> list[np.ndarray]:
     lane sinks pads unambiguously (a lane-max pad value could collide
     with real data). ONE lax.sort call sorts all tables (lax.sort
     batches over leading dims), one readback returns all permutations —
-    this is the streaming build's phase-2 device kernel."""
+    this is the streaming build's phase-2 device kernel. The batch pads
+    to a multiple of `_SORT_BATCH` all-pad rows: the streaming build
+    hands over 1-8 buckets per call, and a multi-key sort takes about a
+    minute to compile for TPU, so one program per length matters."""
     import jax
     import jax.numpy as jnp
 
@@ -407,12 +417,12 @@ def device_sort_perms(tables, key_columns: list[str]) -> list[np.ndarray]:
     lens = [t.num_rows for t in tables]
     lanes_list = [key_lanes(t, key_columns, force_validity=True) for t in tables]
     num_lanes = len(lanes_list[0])
-    b = len(tables)
+    b = _round_up(len(tables), _SORT_BATCH)
     mx = max(max(lens), 1)
     l_pad = 1 << (int(mx - 1).bit_length()) if mx > 1 else 1
-    is_pad = np.zeros((b, l_pad), np.int32)
+    is_pad = np.ones((b, l_pad), np.int32)
     for i, n in enumerate(lens):
-        is_pad[i, n:] = 1
+        is_pad[i, :n] = 0
     stacked = []
     for j in range(num_lanes):
         dt = lanes_list[0][j].dtype

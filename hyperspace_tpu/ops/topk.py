@@ -8,8 +8,10 @@ partial top-k lists; one tiny `lax.top_k` over the [tiles·k] partials
 merges the result. Work: O(n·k/T + tiles·k·log) ≈ one HBM pass.
 
 This is the ANN/vector-index hot path (BASELINE config 5). CPU tests run
-the same kernel in interpret mode; any Pallas failure falls back to
-lax.top_k transparently (`topk(..., impl="xla")` forces the fallback).
+the same kernel in interpret mode. Eligibility is an explicit shape rule
+(k <= `_MAX_PALLAS_K`, n >= `_MIN_PALLAS_N`); ineligible calls take
+lax.top_k (`topk(..., impl="xla")` forces it), and a lowering failure of
+an eligible call raises.
 """
 
 from __future__ import annotations
@@ -25,14 +27,7 @@ from hyperspace_tpu import stats
 
 _TILE = 2048
 _MAX_PALLAS_K = 64
-
-# (k, tile) combos whose Pallas lowering failed — only those fall back
-# permanently; other shapes keep the fast path. Lock-guarded: concurrent
-# serve-plane queries record failures from N worker threads.
-import threading
-
-_pallas_bad: set = set()
-_pallas_bad_lock = threading.Lock()
+_MIN_PALLAS_N = 512
 
 
 def _next_mult(n: int, m: int) -> int:
@@ -123,20 +118,11 @@ def topk(scores, k: int, impl: str = "auto") -> tuple[np.ndarray, np.ndarray]:
     scores = jnp.where(jnp.isnan(scores), -jnp.inf, scores)
     q, n = scores.shape
     k = min(k, n)
-    tile = min(_TILE, _next_mult(max(n, 128), 128))
-    use_pallas = impl == "pallas" or (
-        impl == "auto" and k <= _MAX_PALLAS_K and n >= 512 and (k, tile) not in _pallas_bad
-    )
-    if use_pallas:
-        try:
-            v, i = _pallas_topk(scores, k)
-            stats.increment("device.kernel.fused")
-            return np.asarray(v), np.asarray(i)
-        except Exception:  # noqa: BLE001 — fall back to the XLA path
-            if impl == "pallas":
-                raise
-            with _pallas_bad_lock:
-                _pallas_bad.add((k, tile))
-            stats.increment("device.kernel.fallbacks")
+    if impl == "pallas" or (impl == "auto" and k <= _MAX_PALLAS_K and n >= _MIN_PALLAS_N):
+        v, i = _pallas_topk(scores, k)
+        stats.increment("device.kernel.fused")
+        return np.asarray(v), np.asarray(i)
+    if impl == "auto":
+        stats.increment("device.kernel.fallbacks")
     v, i = jax.lax.top_k(scores, k)
     return np.asarray(v), np.asarray(i)

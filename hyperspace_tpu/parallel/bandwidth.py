@@ -1,11 +1,11 @@
-"""Device→host transfer probe.
+"""Device→host transfer probe and the venue choice it drives.
 
-On a directly-attached TPU, PCIe readback runs at GB/s; through a
-tunneled/remote device it can be tens of MB/s with ~100ms per-transfer
-latency — 100x slower than host memory. Operators whose OUTPUT must land
-on host (a materialized join's match pairs) pick their execution venue
-by this number: below the threshold, computing on host beats shipping
-results off the device. Probed once per process with a 4 MB transfer.
+Operators whose OUTPUT must land on host (a materialized join's match
+pairs, a sort permutation) pick their execution venue by the measured
+device→host link: below the configured floor, computing on host beats
+shipping results off the device. The link speed is a property of the
+deployment (PCIe generation, a shared or remote attachment), so it is
+measured, once per process, with a 4 MB readback.
 """
 
 from __future__ import annotations
@@ -66,76 +66,17 @@ def pick_venue(
     return "host" if d2h_mb_per_s() < floor_mbps else "device"
 
 
-# A measured link speed is a property of the deployment, not the
-# process: persist it so short-lived runs (the point-lookup CLI shape)
-# skip the ~0.3-1s probe entirely.
-_PROBE_TTL_S = 24 * 3600.0
-
-
-def _probe_cache_path():
-    import os
-    from pathlib import Path
-
-    d = os.environ.get("HYPERSPACE_CACHE_DIR") or os.path.expanduser("~/.cache/hyperspace_tpu")
-    return Path(d) / "bandwidth.json"
-
-
-def _device_key() -> str:
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        return f"{dev.platform}:{getattr(dev, 'device_kind', '?')}"
-    except Exception:
-        return "unknown"
-
-
 @functools.lru_cache(maxsize=1)
 def d2h_mb_per_s() -> float:
-    """Measured device→host bandwidth (MB/s), probed once per deployment
-    (persisted with a TTL) rather than once per process."""
-    import json
-
+    """Measured device→host bandwidth (MB/s), probed once per process
+    with a 4 MB readback."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    key = _device_key()
-    path = _probe_cache_path()
-    try:
-        data = json.loads(path.read_text())
-        if not isinstance(data, dict):
-            data = {}
-    except Exception:
-        data = {}
-    try:
-        # Missing/expired entry for THIS device must not discard other
-        # devices' cached entries on the rewrite below. The persisted
-        # stamp must be wall clock (monotonic() restarts per boot, and
-        # the file outlives the process) — so guard the clock-step
-        # hazard instead: a NEGATIVE age means the clock stepped
-        # backwards past the stamp, and the entry is treated as expired
-        # rather than living arbitrarily long.
-        ts, mbps = data[key]
-        age = time.time() - ts  # noqa: HSL007 — cross-process TTL, see above
-        if 0.0 <= age < _PROBE_TTL_S:
-            return float(mbps)
-    except (KeyError, ValueError, TypeError):
-        pass  # missing/corrupt cache entry: fall through to a fresh probe
-
-    try:
-        x = jnp.arange(1 << 20, dtype=jnp.uint32)  # 4 MB
-        x.block_until_ready()
-        t0 = time.perf_counter()
-        np.asarray(jax.device_get(x))
-        dt = time.perf_counter() - t0
-        mbps = 4.0 / max(dt, 1e-9)
-    except Exception:
-        return float("inf")  # probe failure: assume fast, keep device path
-    try:
-        path.parent.mkdir(parents=True, exist_ok=True)
-        data[key] = [time.time(), mbps]
-        path.write_text(json.dumps(data))
-    except OSError:
-        pass  # unwritable cache dir: the probe result still returns
-    return mbps
+    x = jnp.arange(1 << 20, dtype=jnp.uint32)  # 4 MB
+    x.block_until_ready()
+    t0 = time.perf_counter()
+    np.asarray(jax.device_get(x))
+    dt = time.perf_counter() - t0
+    return 4.0 / max(dt, 1e-9)

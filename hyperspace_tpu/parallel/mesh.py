@@ -13,6 +13,9 @@ so the carve/query planes are mesh-shape agnostic.
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import numpy as np
 
 import jax
@@ -33,30 +36,33 @@ def mesh_size(mesh: Mesh) -> int:
         out *= mesh.shape[name]
     return out
 
+# The checkout root: hyperspace_tpu/parallel/mesh.py -> <repo>. For an
+# installed package this is site-packages, which holds no cache.
+_REPO_ROOT = Path(__file__).resolve().parents[2]
 _cache_enabled = False
 
 
 def enable_compile_cache() -> None:
-    """Turn on XLA's persistent compilation cache. The build pipeline's
-    exchange+sort program takes tens of seconds to compile on TPU; caching
-    it on disk makes every process after the first start hot."""
+    """Turn on JAX's persistent compilation cache, once per process.
+
+    The build's exchange+sort program takes minutes to compile for TPU;
+    the cache makes every later process with the same programs start
+    hot. Where ``JAX_COMPILATION_CACHE_DIR`` is set, or the caller set
+    ``jax_compilation_cache_dir`` through ``jax.config``, JAX uses it and
+    nothing is set here. Otherwise, run from a checkout, the cache sits
+    at one fixed path inside it, ``<repo>/.jax_cache`` (listed in
+    .gitignore), so it never moves between runs. An installed package
+    sets no cache: its users set ``JAX_COMPILATION_CACHE_DIR``."""
     global _cache_enabled
     if _cache_enabled:
         return
-    import os
-
-    cache_dir = os.environ.get(
-        "HYPERSPACE_TPU_COMPILE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "hyperspace_tpu", "xla"),
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # noqa: BLE001,HSL017 — cache is an optimization, never fatal; nothing to repair or surface
-        pass
     _cache_enabled = True
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR") or jax.config.jax_compilation_cache_dir:
+        return
+    if _REPO_ROOT.name in ("site-packages", "dist-packages"):
+        return
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_ROOT / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 def make_mesh(devices=None, n: int | None = None) -> Mesh:

@@ -18,9 +18,8 @@ from concurrent.futures import ThreadPoolExecutor
 
 _pool: ThreadPoolExecutor | None = None
 _pool_lock = threading.Lock()
-# The entered context object must stay referenced: on jax versions where
-# enable_x64 is a generator-based contextmanager, dropping it lets GC
-# close the generator and silently REVERT x64 on the worker thread.
+# The entered context object stays referenced for the worker's lifetime:
+# it is entered once and never exited.
 _x64_ctx = None
 
 

@@ -54,6 +54,7 @@ import time
 from pathlib import Path
 
 from hyperspace_tpu import faults, stats
+from hyperspace_tpu.exceptions import FleetCapacityError
 from hyperspace_tpu.obs import events as obs_events
 from hyperspace_tpu.obs import metrics as obs_metrics
 from hyperspace_tpu.obs import trace as obs_trace
@@ -180,6 +181,69 @@ def _scrape_text(host: str, port: int, path: str, timeout: float = _HEALTH_TIMEO
         return None
 
 
+# Google's PCI vendor id and the device ids of its TPU chips (v2/v3, v4,
+# v5p, v5e, v6e). Only v5e's 0x0063 has been seen on the chip machine
+# (PERF.md); the others follow the public tpu-info table.
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset({"0x0027", "0x005e", "0x0062", "0x0063", "0x006f"})
+_ROOT = "/"  # tests point this at a fake /dev, /sys and /proc tree
+
+
+def _is_tpu(pci_dir: Path) -> bool:
+    try:
+        vendor = (pci_dir / "vendor").read_text().strip()
+        device = (pci_dir / "device").read_text().strip()
+    except OSError:
+        return False
+    return vendor == _GOOGLE_PCI_VENDOR and device in _TPU_PCI_DEVICES
+
+
+def tpu_chip_nodes() -> list[str]:
+    """The device nodes of the TPU chips this process can see, one per
+    chip: ``/dev/vfio/N`` (v5e and later: VFIO group N holds the chip's
+    PCI function) or ``/dev/accelN`` (earlier chips). Nodes of other
+    devices (a NIC passed through VFIO, another accel driver) are not
+    chips."""
+    root = Path(_ROOT)
+    nodes = [
+        node for node in sorted((root / "dev/vfio").glob("[0-9]*"))
+        if any(_is_tpu(d) for d in (root / "sys/kernel/iommu_groups" / node.name / "devices").glob("*"))
+    ]
+    nodes += [
+        node for node in sorted((root / "dev").glob("accel[0-9]*"))
+        if _is_tpu(root / "sys/class/accel" / node.name / "device")
+    ]
+    return [str(n) for n in nodes]
+
+
+def _held_device_nodes() -> set[str]:
+    """Device nodes this process holds open. libtpu opens its chip's node
+    when the TPU backend starts and keeps it open (my chip run, PR 21:
+    ``/dev/vfio/2`` after ``jax.devices()``, nothing before)."""
+    held = set()
+    for fd in (Path(_ROOT) / "proc/self/fd").iterdir():
+        try:
+            held.add(os.readlink(fd))
+        except OSError:
+            continue  # closed since the listing
+    return held
+
+
+def device_member_slots() -> int | None:
+    """How many fleet members may use a TPU chip: the chips this host
+    shows minus those this process holds open. None when the members
+    run on the CPU backend (``JAX_PLATFORMS`` names no TPU, or no chip
+    is visible), which bounds nothing."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return None
+    chips = tpu_chip_nodes()
+    if not chips:
+        return None
+    held = _held_device_nodes()
+    return sum(node not in held for node in chips)
+
+
 class FleetSupervisor:
     """Spawn and babysit N worker processes over one index store."""
 
@@ -226,9 +290,20 @@ class FleetSupervisor:
         self._last_seen: dict[int, float] = {}
 
     # -- lifecycle --------------------------------------------------------
+    @staticmethod
+    def _check_capacity(n: int) -> None:
+        slots = device_member_slots()
+        if slots is not None and n > slots:
+            raise FleetCapacityError(
+                f"{n} fleet members would share {slots} free accelerator chip(s); "
+                "a chip serves one process at a time",
+                requested=n, slots=slots,
+            )
+
     def start(self) -> "FleetSupervisor":
         Path(self.fleet_dir, WORKERS_DIRNAME).mkdir(parents=True, exist_ok=True)
         with self._lock:
+            self._check_capacity(self.n)
             for wid in range(self.n):
                 self._spawn(wid)
             _TARGET_WORKERS.set(self.n)
@@ -246,7 +321,8 @@ class FleetSupervisor:
         terminated, their registration JSON and restart state dropped,
         so `fleet_health` stops counting them. Clamped to at least
         `min_workers`; returns the applied target. Idempotent — a no-op
-        change emits nothing."""
+        change emits nothing. Growing past the free accelerator chips
+        raises :class:`FleetCapacityError`."""
         n = max(int(min_workers), int(n))
         with self._lock:
             if self._stopping:
@@ -254,6 +330,8 @@ class FleetSupervisor:
             old = self.n
             if n == old:
                 return old
+            if n > old:
+                self._check_capacity(n)
             to_drain = list(range(n, old))
             for wid in range(old, n):
                 # A re-grown slot starts with a fresh restart budget —

@@ -4,7 +4,7 @@ A miniature device plane exercising every shape the trace-domain
 inference handles — decorator-form jit (bare and
 ``functools.partial(jit, static_argnames=...)``), call-form jit inside
 lru_cache factories, a shard_map body, Pallas kernel bodies, zero-copy
-staging, and two kernel fallback ladders — with exactly four planted
+staging, and two kernel eligibility ladders — with exactly four planted
 violations, one per rule:
 
 - HSL023: ``traced._total`` (reached from ``@jit leaky_norm``) bumps a
@@ -15,8 +15,8 @@ violations, one per rule:
   declared ``reps`` domain.
 - HSL025: ``staging.read_aliased`` mutates a zero-copy staged view in
   place; ``read_owned`` goes through ``own_arrays()`` first.
-- HSL026: ``device.rowmax``'s ladder has no permanent per-shape
-  fallback set; ``tile_reduce``'s ladder is complete (the proven one).
+- HSL026: ``device.rowmax`` swallows its lowering errors in a broad
+  ``except``; ``tile_reduce``'s ladder is complete (the proven one).
 
 Like every analysis fixture, this package is parsed by the engine and
 never imported — ``shims.py`` stands in for compat/stats so the code

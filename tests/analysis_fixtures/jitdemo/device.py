@@ -1,10 +1,9 @@
-"""Two Pallas engagements with fallback ladders: ``tile_reduce`` is
-complete (gate + permanent per-shape fallback + both counters — the
-proven ladder), ``rowmax`` has no *bad* set, so a retryable lowering
-failure re-engages Pallas forever (planted HSL026)."""
+"""Two Pallas engagements with eligibility ladders: ``tile_reduce`` is
+complete (explicit shape rule + both counters, lowering errors raise —
+the proven ladder), ``rowmax`` swallows its lowering errors in a broad
+``except`` and reroutes to the lax path unseen (planted HSL026)."""
 
 import functools
-import threading
 
 import jax.numpy as jnp
 
@@ -23,10 +22,6 @@ KNOWN_COUNTERS = (
 
 _TILE = 128
 _MAX_TILE = 4096
-
-# (n,) shapes whose lowering failed: permanent fallback, lock-guarded.
-_bad_shapes: set = set()
-_bad_lock = threading.Lock()
 
 
 def _next_mult(n, m):
@@ -49,17 +44,12 @@ def _make_tile_reduce(n):
 def tile_reduce(x):
     n = x.shape[1]
     m = _next_mult(n, _TILE)
-    if n <= _MAX_TILE and (n,) not in _bad_shapes:
-        try:
-            run = _make_tile_reduce(m)
-            out = run(jnp.pad(x, ((0, 0), (0, m - n))))
-            stats.increment("device.kernel.fused")
-            return out
-        except Exception:
-            with _bad_lock:
-                _bad_shapes.add((n,))
-            stats.increment("device.kernel.fallbacks")
-    return jnp.sum(x, axis=1)
+    if n > _MAX_TILE:
+        stats.increment("device.kernel.fallbacks")
+        return jnp.sum(x, axis=1)
+    out = _make_tile_reduce(m)(jnp.pad(x, ((0, 0), (0, m - n))))
+    stats.increment("device.kernel.fused")
+    return out
 
 
 @functools.lru_cache(maxsize=8)

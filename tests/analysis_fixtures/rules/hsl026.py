@@ -1,10 +1,9 @@
-"""HSL026 kernel fallback-ladder completeness: a complete (clean)
-ladder, a ladder with no permanent per-shape fallback, an undeclared
-engagement with an empty ladder, a stale registry entry, and a counter
-missing from KNOWN_COUNTERS."""
+"""HSL026 kernel eligibility-ladder completeness: a complete (clean)
+ladder, a ladder that swallows its lowering errors in a broad except,
+an undeclared engagement with an empty ladder, a stale registry entry,
+and a counter missing from KNOWN_COUNTERS."""
 
 import functools
-import threading
 
 import jax.numpy as jnp
 
@@ -23,9 +22,6 @@ KNOWN_COUNTERS = ("device.kernel.fused",)
 _TILE = 128
 _MAX_LANES = 1024
 
-_bad_shapes: set = set()
-_bad_lock = threading.Lock()
-
 
 @functools.lru_cache(maxsize=8)
 def _make_reduce(n):
@@ -42,18 +38,12 @@ def _make_reduce(n):
 
 def reduce_rows(x):
     n = x.shape[1]
-    if n <= _MAX_LANES:
-        try:
-            run = _make_reduce(n)
-            out = run(x)
-            stats.increment("device.kernel.fused")
-            return out
-        except Exception:
-            with _bad_lock:
-                if (n,) not in _bad_shapes:
-                    _bad_shapes.add((n,))
-            stats.increment("device.kernel.fallbacks")  # expect: HSL026
-    return jnp.sum(x, axis=1)
+    if n > _MAX_LANES:
+        stats.increment("device.kernel.fallbacks")  # expect: HSL026
+        return jnp.sum(x, axis=1)
+    out = _make_reduce(n)(x)
+    stats.increment("device.kernel.fused")
+    return out
 
 
 @functools.lru_cache(maxsize=8)
@@ -64,8 +54,8 @@ def _make_rowmax(n):
         o_ref[...] = jnp.max(x_ref[...], axis=1)
 
     def run(x):
-        # Ladder has a gate and both counters but no *bad* set: a
-        # lowering failure re-engages Pallas on the same shape forever.
+        # Ladder has a gate and both counters, but its broad except
+        # turns a lowering failure into a silent lax-path reroute.
         return pl.pallas_call(kernel, grid=(n // _TILE,))(x)  # expect: HSL026
 
     return jit(run, key="corpus.rowmax")

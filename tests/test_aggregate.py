@@ -718,9 +718,16 @@ def test_partial_agg_pushdown_dim_case_matches_pandas(tmp_path, join_tables):
     np.testing.assert_allclose(got.lo.to_numpy(), exp.lo.to_numpy(), rtol=1e-12)
 
 
-def test_top_n_matches_full_sort(tmp_path):
-    """ORDER BY + LIMIT takes the partition-select path and must equal
-    the full sort exactly, incl. duplicate first keys and DESC order."""
+@pytest.mark.parametrize("venue,kernel", [
+    (None, "host-partition-select"),
+    ("host", "host-partition-select"),
+    ("device", "device-select"),
+])
+def test_top_n_matches_full_sort(tmp_path, venue, kernel):
+    """ORDER BY + LIMIT takes the venue's select path and must equal the
+    full sort exactly, incl. duplicate first keys and DESC order. The
+    default (auto, no mesh) keeps the host partition select; only an
+    explicit device venue selects on the one device."""
     rng = np.random.default_rng(8)
     n = 60_000
     df_ = pd.DataFrame(
@@ -733,10 +740,12 @@ def test_top_n_matches_full_sort(tmp_path):
     root.mkdir()
     pq.write_table(pa.Table.from_pandas(df_, preserve_index=False), root / "p.parquet")
     session = _session(tmp_path)
+    if venue is not None:
+        session.conf.set("hyperspace.sort.venue", venue)
     scan = session.parquet(root)
     got = session.to_pandas(scan.sort([("r", False), ("id", True)]).limit(25))
     node = next(n_ for n_ in session.last_physical_plan.walk() if n_.op == "TopN")
-    assert "partition-select" in node.detail["kernel"]
+    assert node.detail["kernel"].startswith(kernel)
     exp = df_.sort_values(["r", "id"], ascending=[False, True]).head(25).reset_index(drop=True)
     np.testing.assert_allclose(got["r"], exp["r"])
     np.testing.assert_array_equal(got["id"], exp["id"])

@@ -539,13 +539,13 @@ class TestJitdemo:
         assert "read_owned" not in f.message
 
     def test_hsl026_flags_only_the_ladder_hole(self, jitdemo):
-        # rowmax is missing exactly the permanent fallback; everything
-        # else on its ladder (gate, both counters) is present, and
-        # tile_reduce's complete ladder is proven.
+        # rowmax swallows its lowering errors; everything else on its
+        # ladder (gate, both counters) is present, and tile_reduce's
+        # complete ladder is proven.
         _, _, tdomains = jitdemo
         (f,) = [f for f in tdomains.findings() if f.rule == "HSL026"]
         assert "'jitdemo.rowmax'" in f.message
-        assert "permanent per-shape fallback" in f.message
+        assert "broad except in jitdemo.device.rowmax" in f.message
         assert "gate" not in f.message.split("missing", 1)[1]
         by_kernel = {lad["kernel"]: lad for lad in tdomains._kernel_ladders}
         assert by_kernel["jitdemo.tile_reduce"]["proven"] is True
@@ -1071,10 +1071,10 @@ class TestRepoProcessDomains:
         assert chain[0].startswith("hyperspace_tpu.ops.filter.")
 
     def test_every_pallas_ladder_is_proven(self, repo_tdomains):
-        """All three Pallas kernels carry the complete fallback ladder:
-        eligibility gate, permanent per-shape *bad* set, and both
-        device.kernel.* counters, with the engagement chain from the
-        public op down to the factory."""
+        """All three Pallas kernels carry the complete eligibility
+        ladder: an explicit rule, both device.kernel.* counters, and no
+        broad except swallowing a lowering error, with the engagement
+        chain from the public op down to the factory."""
         ladders = {lad["kernel"]: lad for lad in repo_tdomains._kernel_ladders}
         assert set(ladders) == {
             "ops.aggregate.pallas_segment_reduce",
@@ -1083,7 +1083,7 @@ class TestRepoProcessDomains:
         }
         for name, lad in ladders.items():
             assert lad["proven"], name
-            assert lad["gate"] and lad["bad_set"], name
+            assert lad["gate"] and lad["swallow"] is None, name
             assert set(lad["counters"]) == {
                 "device.kernel.fused", "device.kernel.fallbacks",
             }, name

@@ -69,3 +69,30 @@ def test_index_config_builder_double_set():
     b = IndexConfig.builder().index_name("i")
     with pytest.raises(HyperspaceError):
         b.index_name("j")
+
+
+@pytest.mark.parametrize("env,preset,root,expect", [
+    ("/elsewhere", None, "repo", None),  # the variable wins; nothing set in code
+    (None, "/caller", "repo", None),  # a caller's jax.config setting stays
+    (None, None, "site-packages", None),  # installed package: no cache
+    (None, None, "repo", ".jax_cache"),  # a checkout: one fixed path inside it
+])
+def test_compile_cache_setter_only_fills_an_unset_cache(tmp_path, monkeypatch, env, preset, root, expect):
+    import types
+
+    from hyperspace_tpu.parallel import mesh as mesh_mod
+
+    updates = {}
+    fake_jax = types.SimpleNamespace(config=types.SimpleNamespace(
+        jax_compilation_cache_dir=preset, update=lambda k, v: updates.__setitem__(k, v),
+    ))
+    monkeypatch.setattr(mesh_mod, "jax", fake_jax)
+    monkeypatch.setattr(mesh_mod, "_cache_enabled", False)
+    monkeypatch.setattr(mesh_mod, "_REPO_ROOT", tmp_path / root)
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    mesh_mod.enable_compile_cache()
+    got = updates.get("jax_compilation_cache_dir")
+    assert got == (None if expect is None else str(tmp_path / root / expect))

@@ -608,6 +608,49 @@ class TestMultiProcessFleet:
         finally:
             sup.stop(timeout=30)
 
+    @pytest.mark.parametrize("held,n,refused", [
+        (False, 2, False), (False, 3, True), (True, 1, True),
+    ])
+    def test_supervisor_refuses_more_device_members_than_free_chips(
+        self, tmp_path, monkeypatch, held, n, refused
+    ):
+        """A chip serves one process at a time: on a two-chip host the
+        supervisor starts at most two device members, and none while it
+        holds the chips itself — a typed error, never a hung member."""
+        from hyperspace_tpu.exceptions import FleetCapacityError
+        from hyperspace_tpu.serve.fleet import supervisor as sup_mod
+
+        # A fake host: two v5e chips (VFIO groups 0 and 1), a NIC passed
+        # through VFIO (group 5, Google's vendor id, not a TPU device id),
+        # and this process's fds — holding both chips' nodes when `held`.
+        root = tmp_path / "host"
+        for group, device in (("0", "0x0063"), ("1", "0x0063"), ("5", "0x0042")):
+            (root / "dev/vfio").mkdir(parents=True, exist_ok=True)
+            (root / "dev/vfio" / group).touch()
+            fn = root / "sys/kernel/iommu_groups" / group / "devices" / f"0000:00:0{group}.0"
+            fn.mkdir(parents=True)
+            (fn / "vendor").write_text("0x1ae0\n")
+            (fn / "device").write_text(device + "\n")
+        fds = root / "proc/self/fd"
+        fds.mkdir(parents=True)
+        (fds / "0").symlink_to(root / "dev/null")
+        if held:
+            (fds / "7").symlink_to(root / "dev/vfio/0")
+            (fds / "8").symlink_to(root / "dev/vfio/1")
+        monkeypatch.setattr(sup_mod, "_ROOT", str(root))
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert sup_mod.tpu_chip_nodes() == [str(root / "dev/vfio/0"), str(root / "dev/vfio/1")]
+        assert sup_mod.device_member_slots() == (0 if held else 2)
+        sup = fleet.FleetSupervisor(_crasher, fleet_dir=str(tmp_path / "fleet"), n=n)
+        if refused:
+            with pytest.raises(FleetCapacityError) as ei:
+                sup._check_capacity(n)
+            assert ei.value.requested == n and ei.value.slots == (0 if held else 2)
+        else:
+            sup._check_capacity(n)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # CPU members: unbounded
+        assert sup_mod.device_member_slots() is None
+
     def test_crash_loop_backs_off_instead_of_burning_budget(self, tmp_path):
         """A crash-looping member must not spend its whole maxRestarts
         budget in milliseconds: the first respawn is immediate, repeat

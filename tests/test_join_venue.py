@@ -1,9 +1,9 @@
 """Host-native join venue: the C++ bucket-parallel merge join must be
 result-identical to the device kernel, and the venue choice must obey
-the config override. On tunneled TPU deployments the device→host
-readback of the match pairs dominates a materialized join, so the
-executor picks the host kernel when measured bandwidth is low
-(parallel/bandwidth.py); both venues share every other stage."""
+the config override. Where the device→host link is slow the readback
+of the match pairs dominates a materialized join, so the executor picks
+the host kernel when measured bandwidth is low (parallel/bandwidth.py);
+both venues share every other stage."""
 
 import numpy as np
 import pandas as pd

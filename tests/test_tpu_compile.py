@@ -1,0 +1,110 @@
+"""Compile the main path's device programs for a described TPU v5e chip.
+
+No chip is attached: the TPU compiler that ships with libtpu compiles for
+a topology that is only described (on-chip-measurement guide, section 2).
+A kernel that passes every interpret-mode test can still be refused
+here: a block shape not aligned to the (8, 128) tiling, a 64-bit
+element type inside a Mosaic kernel, an index map that returns int64
+under x64. Each test compiles one program at the shapes and dtypes the
+SF1 main path gives it; a refusal raises, so the test fails.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load libtpu, and
+pytest-xdist workers all import this file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A TPU executable written to the persistent cache cannot be read
+    # back without a chip: keep these compiles out of it.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_segment_reduce_compiles_as_run_x64_calls_it(one_chip):
+    from hyperspace_tpu.ops.aggregate import (
+        _PALLAS_MAX_SEGMENTS,
+        _PALLAS_SEG_TILE,
+        _make_pallas_segment_reduce,
+    )
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    n = 1 << 23  # SF1 lineitem padded to a power of two
+    run = _make_pallas_segment_reduce(
+        ("sum", "sum", "max"), _PALLAS_MAX_SEGMENTS, _PALLAS_SEG_TILE, False
+    )
+    compiled = run_x64(
+        lambda: run.lower(
+            _shape(one_chip, (1, n), jnp.int32), _shape(one_chip, (3, n), jnp.float32)
+        ).compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_run_bounds_compiles_for_every_bucket_row(one_chip):
+    from hyperspace_tpu.ops.sortkeys import _RB_MAX_SECONDARY, _RB_TILE, _make_run_bounds_kernel
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    b, ls = 200, _RB_MAX_SECONDARY
+    run = _make_run_bounds_kernel(_RB_TILE, ls, False)
+    compiled = run_x64(
+        lambda: run.lower(
+            _shape(one_chip, (b, 8192), jnp.int32), _shape(one_chip, (b, ls), jnp.int32)
+        ).compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_topk_tile_kernel_compiles(one_chip):
+    from hyperspace_tpu.ops.topk import _QBLOCK, _TILE, _make_tile_kernel
+
+    run, _ = _make_tile_kernel(10, _TILE, False)
+    compiled = run.lower(_shape(one_chip, (_QBLOCK, 1 << 20), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_build_batch_sort_compiles_at_sf1_bucket_size(one_chip):
+    """The streaming build's per-batch device sort: is_pad, the int64
+    key's validity/hi/lo lanes and the row iota, `_SORT_BATCH` buckets
+    of an SF1 lineitem bucket's padded length (~30k rows at 200)."""
+    from hyperspace_tpu.ops.sortkeys import _SORT_BATCH, _make_batch_sort
+
+    shape = (_SORT_BATCH, 1 << 15)
+    dtypes = (np.int32, np.int32, np.int32, np.uint32, np.int32)
+    fn = _make_batch_sort(len(dtypes), len(dtypes) - 1)
+    compiled = fn.lower(*[_shape(one_chip, shape, d) for d in dtypes]).compile()
+    assert compiled.memory_analysis() is not None
