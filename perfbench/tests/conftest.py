@@ -1,0 +1,9 @@
+"""The benchmark's own tests run on the CPU: JAX is held there before
+anything imports it, and every run writes under pytest's tmp_path."""
+
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
