@@ -17,6 +17,11 @@ move) is resolved HERE and nowhere else:
   detect recompile storms while the process runs — the dynamic mirror
   of lint rule HSL015, and the observable form of the XLA:CPU
   map-count segfault ``utils/jit_memory.py`` guards against.
+- ``to_host``: the package's one blocking device→host fetch, timed as
+  the ``device.sync`` span (the host's wait on the device).
+- The tracer's profiler bridge: importing this module installs
+  ``jax.profiler.TraceAnnotation`` into ``obs/trace.py``, which stays
+  stdlib-only, so recorded spans show in a profile beside the device.
 
 The trace-safety linter (``analysis/lint.py``, rule HSL001) makes this
 arrangement permanent: any ``from jax import shard_map`` or
@@ -86,6 +91,29 @@ def jit(fn=None, *, key: "str | None" = None, **jit_kwargs):
         qual = getattr(fn, "__qualname__", None) or getattr(fn, "__name__", "<fn>")
         key = f"{module}.{qual}"
     return obs_runtime.instrument(jax.jit(fn, **jit_kwargs), key)
+
+
+def to_host(x):
+    """``jax.device_get(x)`` inside a ``device.sync`` span: its wall is the
+    time the host waited for the device to finish ``x``, plus the
+    transfer. Every blocking fetch of a device result goes through here."""
+    import jax
+
+    from hyperspace_tpu.obs import trace as obs_trace
+
+    with obs_trace.span("device.sync"):
+        return jax.device_get(x)
+
+
+def _bridge_spans() -> None:
+    import jax
+
+    from hyperspace_tpu.obs import trace as obs_trace
+
+    obs_trace.bridge(jax.profiler.TraceAnnotation)
+
+
+_bridge_spans()
 
 
 def enable_x64(new_val: bool = True):

@@ -355,10 +355,11 @@ class JoinSidesMixin:
         venue = self._join_venue()
         kernel = None
         if venue == "device":
-            import jax
             import jax.numpy as jnp
 
-            order = np.asarray(jax.device_get(jnp.argsort(jnp.asarray(bucket))))
+            from hyperspace_tpu.compat import to_host
+
+            order = np.asarray(to_host(jnp.argsort(jnp.asarray(bucket))))
             counts = np.bincount(bucket, minlength=num_buckets).astype(np.int64)
             kernel = "device-sort-exchange"
         else:

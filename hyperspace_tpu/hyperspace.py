@@ -322,7 +322,8 @@ class HyperspaceSession:
                         # execution/prefetch.py).
                         from hyperspace_tpu.execution import prefetch as _prefetch
 
-                        _prefetch.prefetch_plan(optimized)
+                        with obs_trace.span("plan.prefetch"):
+                            _prefetch.prefetch_plan(optimized)
                 try:
                     if profile_dir is not None:
                         import jax

@@ -22,6 +22,12 @@ Design constraints:
   enclosing :func:`trace` established a root (``session.run`` and
   ``Action.run`` do). Instrumented library code can therefore call
   ``span()`` unconditionally; outside a traced request nothing records.
+- **One clock with the device.** While a profiler session records, a
+  recorded span also enters a profiler annotation of its bare name on
+  the thread it runs on, so the span lands in the profile beside the
+  device's ops. This module stays stdlib-only: the package's jax shim
+  (compat.py) installs the annotation class through :func:`bridge`.
+  With no session recording, the cost is one static check per span.
 
 Finished root traces go to the JSON-lines sink when one is configured
 (``hyperspace.obs.sink``), and the last root is kept in-process for
@@ -82,6 +88,9 @@ RECENT_ROOTS_MAX = 32
 _recent_lock = threading.Lock()
 _recent_roots: collections.deque = collections.deque(maxlen=RECENT_ROOTS_MAX)
 _trace_seq = itertools.count(1)  # itertools.count is GIL-atomic
+# Profiler annotation class (jax.profiler.TraceAnnotation once compat.py
+# has run :func:`bridge`); None leaves spans off the profiler's trace.
+_annotation = None
 
 
 class Span:
@@ -90,7 +99,7 @@ class Span:
 
     __slots__ = (
         "name", "attrs", "children", "events", "start_s", "wall_s",
-        "error", "tid", "trace_id", "_token",
+        "error", "tid", "trace_id", "_token", "_ann",
     )
 
     def __init__(self, name: str, attrs: dict):
@@ -104,6 +113,7 @@ class Span:
         self.tid: int | None = None  # OS thread the span ran on
         self.trace_id: str | None = None  # set on ROOT spans only
         self._token = None
+        self._ann = None  # the open profiler annotation, if one records
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -125,6 +135,12 @@ class Span:
             parent.children.append(self)
         self._token = _current.set(self)
         self.tid = threading.get_ident()
+        ann = _annotation
+        if ann is not None and ann.is_enabled():
+            # The bare name: attrs stay on the span tree, and readers of
+            # the profile match names exactly.
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self.start_s = time.perf_counter()
         return self
 
@@ -132,6 +148,9 @@ class Span:
         # BaseException included: a CrashPoint flying through still
         # closes (and error-tags) every open span on its way out.
         self.wall_s = time.perf_counter() - (self.start_s or 0.0)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if exc is not None and self.error is None:
             self.error = f"{exc_type.__name__}: {exc}"
         _current.reset(self._token)
@@ -265,6 +284,16 @@ def configure(sink: str | None = ...) -> None:
 
 def sink_path() -> str | None:
     return _sink_path
+
+
+def bridge(annotation) -> None:
+    """Put recorded spans on the profiler's clock: `annotation` is a
+    context-manager class built from a span's name, with a static
+    ``is_enabled()`` that is true only while a profiler session records
+    (``jax.profiler.TraceAnnotation``; compat.py installs it). None
+    removes the bridge."""
+    global _annotation
+    _annotation = annotation
 
 
 def trace(name: str, **attrs):

@@ -43,10 +43,9 @@ import jax
 import jax.numpy as jnp
 
 from hyperspace_tpu import stats
-from hyperspace_tpu.compat import jit
+from hyperspace_tpu.compat import jit, to_host
 from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.execution.table import ColumnTable
-from hyperspace_tpu.obs import trace as obs_trace
 from hyperspace_tpu.plan.expr import Col, evaluate
 from hyperspace_tpu.schema import Schema
 
@@ -555,7 +554,7 @@ def aggregate_arrays(
             reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, tuple(fns))
             out = np.asarray(
                 run_x64(
-                    lambda: jax.device_get(reduce_fn(jnp.asarray(stacked), jnp.asarray(gid_p)))
+                    lambda: to_host(reduce_fn(jnp.asarray(stacked), jnp.asarray(gid_p)))
                 )
             )
         else:
@@ -566,7 +565,7 @@ def aggregate_arrays(
             # repeat queries — the staging tax is paid once per version.
             out = np.asarray(
                 run_x64(
-                    lambda: jax.device_get(
+                    lambda: to_host(
                         reduce_fn(
                             dcache.device_put_cached(stacked),
                             dcache.device_put_cached(gid_p),
@@ -617,15 +616,9 @@ def _try_pallas_reduce(
     else:
         vals32 = build_vals32()
     run = _make_pallas_segment_reduce(fns, k_pad, tile, interpret)
-    with obs_trace.span(
-        "device.kernel", kernel="pallas-segment-reduce",
-        channels=len(fns), segments=k_pad,
-    ):
-        out = run_x64(
-            lambda: jax.device_get(
-                run(dcache.device_put_cached(gid2d), dcache.device_put_cached(vals32))
-            )
-        )
+    out = run_x64(
+        lambda: to_host(run(dcache.device_put_cached(gid2d), dcache.device_put_cached(vals32)))
+    )
     stats.increment("device.kernel.fused")
     return np.asarray(out, np.float64)
 

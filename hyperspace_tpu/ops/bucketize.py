@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from hyperspace_tpu.compat import jit, shard_map
+from hyperspace_tpu.compat import jit, shard_map, to_host
 
 AXIS = "x"
 
@@ -59,51 +59,54 @@ def _exchange_one_device(
     the per-row bucket id; `valid` marks real rows. Returns
     (recv_cols, recv_bucket, recv_valid, overflowed) with received rows
     lex-sorted by (bucket, key cols) — the exchange AND the local sort run
-    in one fused device program."""
-    r = bucket.shape[0]
-    dest = jnp.where(valid, bucket // buckets_per_device, num_devices)  # invalid → sentinel D
+    in one fused device program, under the named scopes ``build.bucketize``
+    and ``build.sort`` that label their ops in a profile."""
+    with jax.named_scope("build.bucketize"):
+        r = bucket.shape[0]
+        dest = jnp.where(valid, bucket // buckets_per_device, num_devices)  # invalid → sentinel D
 
-    # Stable sort rows by dest so each destination's rows are contiguous.
-    order = lax.sort((dest.astype(jnp.int32), jnp.arange(r, dtype=jnp.int32)), num_keys=1, is_stable=True)[1]
-    dest_sorted = dest[order]
-    bucket_sorted = bucket[order]
+        # Stable sort rows by dest so each destination's rows are contiguous.
+        order = lax.sort((dest.astype(jnp.int32), jnp.arange(r, dtype=jnp.int32)), num_keys=1, is_stable=True)[1]
+        dest_sorted = dest[order]
+        bucket_sorted = bucket[order]
 
-    # Per-destination group extents.
-    counts = jnp.bincount(dest_sorted, length=num_devices + 1)
-    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-    overflowed = jnp.max(counts[:num_devices]) > capacity
+        # Per-destination group extents.
+        counts = jnp.bincount(dest_sorted, length=num_devices + 1)
+        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)])
+        overflowed = jnp.max(counts[:num_devices]) > capacity
 
-    # Build the [D, C] send buffer by GATHER (TPU-friendly; scatters
-    # serialize): slot (d, c) reads sorted row offsets[d] + c when real.
-    slot_dst = jnp.repeat(jnp.arange(num_devices, dtype=jnp.int32), capacity)
-    slot_within = jnp.tile(jnp.arange(capacity, dtype=jnp.int32), num_devices)
-    slot_ok = slot_within < counts[slot_dst]
-    src = jnp.where(slot_ok, offsets[slot_dst] + slot_within, 0)
+        # Build the [D, C] send buffer by GATHER (TPU-friendly; scatters
+        # serialize): slot (d, c) reads sorted row offsets[d] + c when real.
+        slot_dst = jnp.repeat(jnp.arange(num_devices, dtype=jnp.int32), capacity)
+        slot_within = jnp.tile(jnp.arange(capacity, dtype=jnp.int32), num_devices)
+        slot_ok = slot_within < counts[slot_dst]
+        src = jnp.where(slot_ok, offsets[slot_dst] + slot_within, 0)
 
-    def fill_slots(col_sorted, fill):
-        """Gather per-ROW values into the [D, C] slot layout."""
-        vals = jnp.where(slot_ok, col_sorted[src], fill)
-        return vals.reshape(num_devices, capacity)
+        def fill_slots(col_sorted, fill):
+            """Gather per-ROW values into the [D, C] slot layout."""
+            vals = jnp.where(slot_ok, col_sorted[src], fill)
+            return vals.reshape(num_devices, capacity)
 
-    send_valid = slot_ok.astype(jnp.int32).reshape(num_devices, capacity)
-    send_bucket = fill_slots(bucket_sorted, -1)
-    send_cols = [fill_slots(c[order], 0) for c in cols]
+        send_valid = slot_ok.astype(jnp.int32).reshape(num_devices, capacity)
+        send_bucket = fill_slots(bucket_sorted, -1)
+        send_cols = [fill_slots(c[order], 0) for c in cols]
 
-    # THE exchange: one all_to_all over the mesh axes (ICI within a
-    # slice; ICI+DCN on a multi-slice mesh).
-    recv_valid = lax.all_to_all(send_valid, axes, 0, 0, tiled=True)
-    recv_bucket = lax.all_to_all(send_bucket, axes, 0, 0, tiled=True)
-    recv_cols = [lax.all_to_all(c, axes, 0, 0, tiled=True) for c in send_cols]
+        # THE exchange: one all_to_all over the mesh axes (ICI within a
+        # slice; ICI+DCN on a multi-slice mesh).
+        recv_valid = lax.all_to_all(send_valid, axes, 0, 0, tiled=True)
+        recv_bucket = lax.all_to_all(send_bucket, axes, 0, 0, tiled=True)
+        recv_cols = [lax.all_to_all(c, axes, 0, 0, tiled=True) for c in send_cols]
 
-    # Flatten [D, C] → [D*C]; invalid rows get the sentinel bucket so they
-    # sink to the end, then ONE stable lex-sort by (bucket, key cols).
-    rv = recv_valid.reshape(-1)
-    rb = jnp.where(rv > 0, recv_bucket.reshape(-1), jnp.int32(2**30))
-    rc = [c.reshape(-1) for c in recv_cols]
-    sorted_arrays = lax.sort((rb, *rc, rv), num_keys=1 + num_key_cols, is_stable=True)
-    rb = sorted_arrays[0]
-    rc = list(sorted_arrays[1:-1])
-    rv = sorted_arrays[-1]
+    with jax.named_scope("build.sort"):
+        # Flatten [D, C] → [D*C]; invalid rows get the sentinel bucket so they
+        # sink to the end, then ONE stable lex-sort by (bucket, key cols).
+        rv = recv_valid.reshape(-1)
+        rb = jnp.where(rv > 0, recv_bucket.reshape(-1), jnp.int32(2**30))
+        rc = [c.reshape(-1) for c in recv_cols]
+        sorted_arrays = lax.sort((rb, *rc, rv), num_keys=1 + num_key_cols, is_stable=True)
+        rb = sorted_arrays[0]
+        rc = list(sorted_arrays[1:-1])
+        rv = sorted_arrays[-1]
     return rc, rb, rv, overflowed
 
 
@@ -241,7 +244,7 @@ def bucketize_perm(
         # round-trip pays the link's latency, and overflow is rare enough
         # that optimistically downloading perm alongside it wins on
         # average.
-        perm_h, counts_h, overflow_h = jax.device_get((perm, counts, overflow))
+        perm_h, counts_h, overflow_h = to_host((perm, counts, overflow))
         if not bool(_np.asarray(overflow_h).max()):
             break
         if capacity >= per_dev:
@@ -294,7 +297,7 @@ def bucketize(
         capacity = min(capacity, per_dev)  # no point exceeding local rows
         fn = make_bucketize_fn(mesh, len(cols), num_buckets, capacity, num_key_cols)
         out_cols, out_bucket, out_valid, overflow = fn(tuple(cols), bucket, valid)
-        if not bool(jax.device_get(overflow).max()):
+        if not bool(to_host(overflow).max()):
             return list(out_cols), out_bucket, out_valid
         if capacity >= per_dev:
             # Typed (not assert): the invariant breaking would cross the

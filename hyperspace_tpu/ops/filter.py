@@ -26,6 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from hyperspace_tpu.compat import jit, to_host
 from hyperspace_tpu.execution.table import ColumnTable
 from hyperspace_tpu.plan.expr import (
     And,
@@ -863,14 +864,12 @@ def eval_predicate_mask(
             t, _f = _eval3(expr, cols, iter(lits_tuple))
             return jnp.broadcast_to(t, (n_pad,))
 
-        from hyperspace_tpu.compat import jit
-
         fn = jit(raw, key="ops.filter.mask")
         with _MASK_FN_LOCK:
             _MASK_FN_CACHE[key] = fn
 
     mask = fn(tuple(arrays), tuple(jnp.asarray(v) for v in lit_args))
-    return np.asarray(jax.device_get(mask)).astype(bool)[:n]
+    return np.asarray(to_host(mask)).astype(bool)[:n]
 
 
 def apply_filter(

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from hyperspace_tpu.compat import jit, shard_map
+from hyperspace_tpu.compat import jit, shard_map, to_host
 
 SENTINEL = np.iinfo(np.int64).max
 
@@ -227,13 +227,13 @@ def merge_join(lkeys_np: np.ndarray, rkeys_np: np.ndarray):
         cap, m_pad = guess
         a, b, totals, overflow = _fused_join(lk, rk, cap, m_pad, shift)
         if shift is not None:
-            packed, totals_h, ov = jax.device_get((a, totals, overflow))
+            packed, totals_h, ov = to_host((a, totals, overflow))
             if not bool(ov):
                 total = int(np.asarray(totals_h).sum())
                 li_flat, ri_flat = _unpack_pairs(np.asarray(packed)[:total], shift)
                 return li_flat, ri_flat, np.asarray(totals_h)
         else:
-            lf, rf, totals_h, ov = jax.device_get((a, b, totals, overflow))
+            lf, rf, totals_h, ov = to_host((a, b, totals, overflow))
             if not bool(ov):
                 total = int(np.asarray(totals_h).sum())
                 return (
@@ -244,20 +244,20 @@ def merge_join(lkeys_np: np.ndarray, rkeys_np: np.ndarray):
 
     # Exact two-phase path (first run for this shape, or guess overflowed).
     start, cum, totals = join_counts(lk, rk)
-    totals_h = np.asarray(jax.device_get(totals))
+    totals_h = np.asarray(to_host(totals))
     cap = next_pow2(int(totals_h.max()) if totals_h.size else 1)
     li, ri, _valid = join_expand(start, cum, totals, cap)
     total = int(totals_h.sum())
     m_pad = next_pow2(max(total, 1))
     _cap_set(shape_key, (cap, m_pad))
     if shift is not None:
-        packed = np.asarray(jax.device_get(_compact_pairs(li, ri, totals, m_pad, shift)))[:total]
+        packed = np.asarray(to_host(_compact_pairs(li, ri, totals, m_pad, shift)))[:total]
         li_flat, ri_flat = _unpack_pairs(packed, shift)
         return li_flat, ri_flat, totals_h
     li_flat, ri_flat = _compact_pairs(li, ri, totals, m_pad, None)
     return (
-        np.asarray(jax.device_get(li_flat))[:total],
-        np.asarray(jax.device_get(ri_flat))[:total],
+        np.asarray(to_host(li_flat))[:total],
+        np.asarray(to_host(ri_flat))[:total],
         totals_h,
     )
 
@@ -335,14 +335,14 @@ def merge_join_sharded(lkeys_np: np.ndarray, rkeys_np: np.ndarray, mesh: Mesh):
     rk = device_put_cached(rkeys_np)
 
     totals = _make_sharded_count(mesh, axes)(lk, rk)
-    totals_h = np.asarray(jax.device_get(totals))
+    totals_h = np.asarray(to_host(totals))
     cap = next_pow2(int(totals_h.max()) if totals_h.size else 1)
     seg = totals_h.reshape(d, num_b // d).sum(axis=1)  # per-device match counts
     out_cap = next_pow2(int(seg.max()) if seg.size else 1)
     shift = pack_shift(lkeys_np.shape[1], rkeys_np.shape[1])
 
     out, _totals2 = _make_sharded_emit(mesh, axes, cap, out_cap, shift)(lk, rk)
-    out_h = np.asarray(jax.device_get(out))
+    out_h = np.asarray(to_host(out))
     if shift is not None:
         segs = [out_h[i * out_cap : i * out_cap + int(seg[i])] for i in range(d)]
         packed = np.concatenate(segs) if segs else out_h[:0]

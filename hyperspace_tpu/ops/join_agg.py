@@ -37,8 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from hyperspace_tpu.compat import jit
-from hyperspace_tpu.obs import trace as obs_trace
+from hyperspace_tpu.compat import jit, to_host
 
 
 def _seg_scan_extremum(vals, new_seg, op):
@@ -56,44 +55,57 @@ def _seg_scan_extremum(vals, new_seg, op):
 
 
 def _one_bucket(pkb, skb, pvb, svb, gidb, stb, enb, num_segments: int, channels: tuple):
-    """Per-bucket channel reduction given the run bounds [stb, enb)."""
-    real = pkb < jnp.iinfo(pkb.dtype).max
-    matched = real & (enb > stb)
-    runlen = jnp.where(real, enb - stb, 0).astype(jnp.float64)
+    """Per-bucket channel reduction given the run bounds [stb, enb). The
+    named scopes label each stage's device ops in a profile."""
+    bounds = functools.partial(jax.named_scope, "join_agg.bounds")
+    gather = functools.partial(jax.named_scope, "join_agg.gather")
+    segment = functools.partial(jax.named_scope, "join_agg.segment_sum")
+    with bounds():
+        real = pkb < jnp.iinfo(pkb.dtype).max
+        matched = real & (enb > stb)
+        runlen = jnp.where(real, enb - stb, 0).astype(jnp.float64)
     p_prefix = None
     if svb.shape[0] and any(ch[0] == "s" for ch in channels):
-        p_prefix = jnp.concatenate(
-            [jnp.zeros((svb.shape[0], 1), svb.dtype), jnp.cumsum(svb, axis=-1)],
-            axis=-1,
-        )
+        with gather():
+            p_prefix = jnp.concatenate(
+                [jnp.zeros((svb.shape[0], 1), svb.dtype), jnp.cumsum(svb, axis=-1)],
+                axis=-1,
+            )
     new_key = None
     if any(ch[0] in ("smin", "smax") for ch in channels):
-        new_key = jnp.concatenate(
-            [jnp.ones(1, bool), skb[1:] != skb[:-1]]
-        )
+        with gather():
+            new_key = jnp.concatenate(
+                [jnp.ones(1, bool), skb[1:] != skb[:-1]]
+            )
     outs = []
     for ch in channels:
         kind = ch[0]
         if kind == "star":
-            outs.append(jax.ops.segment_sum(runlen, gidb, num_segments))
+            with segment():
+                outs.append(jax.ops.segment_sum(runlen, gidb, num_segments))
         elif kind == "p":
-            outs.append(jax.ops.segment_sum(pvb[ch[1]] * runlen, gidb, num_segments))
+            with segment():
+                outs.append(jax.ops.segment_sum(pvb[ch[1]] * runlen, gidb, num_segments))
         elif kind == "s":
-            pj = p_prefix[ch[1]]
-            w = jnp.where(real, pj[enb] - pj[stb], 0.0)
-            outs.append(jax.ops.segment_sum(w, gidb, num_segments))
+            with gather():
+                pj = p_prefix[ch[1]]
+                w = jnp.where(real, pj[enb] - pj[stb], 0.0)
+            with segment():
+                outs.append(jax.ops.segment_sum(w, gidb, num_segments))
         else:
             is_min = kind.endswith("min")
             ident = jnp.inf if is_min else -jnp.inf
             seg_red = jax.ops.segment_min if is_min else jax.ops.segment_max
-            if kind[0] == "p":
-                w = jnp.where(matched, pvb[ch[1]], ident)
-            else:
-                m = _seg_scan_extremum(
-                    svb[ch[1]], new_key, jnp.minimum if is_min else jnp.maximum
-                )
-                w = jnp.where(matched, m[jnp.maximum(enb - 1, 0)], ident)
-            outs.append(seg_red(w, gidb, num_segments))
+            with gather():
+                if kind[0] == "p":
+                    w = jnp.where(matched, pvb[ch[1]], ident)
+                else:
+                    m = _seg_scan_extremum(
+                        svb[ch[1]], new_key, jnp.minimum if is_min else jnp.maximum
+                    )
+                    w = jnp.where(matched, m[jnp.maximum(enb - 1, 0)], ident)
+            with segment():
+                outs.append(seg_red(w, gidb, num_segments))
     return jnp.stack(outs)
 
 
@@ -125,12 +137,14 @@ def _fused_join_agg(pk, sk, pvals, svals, gid, num_segments: int, channels: tupl
     Returns [len(channels), num_segments] float64."""
 
     def one(pkb, skb, pvb, svb, gidb):
-        st = jnp.searchsorted(skb, pkb, side="left").astype(jnp.int32)
-        en = jnp.searchsorted(skb, pkb, side="right").astype(jnp.int32)
+        with jax.named_scope("join_agg.bounds"):
+            st = jnp.searchsorted(skb, pkb, side="left").astype(jnp.int32)
+            en = jnp.searchsorted(skb, pkb, side="right").astype(jnp.int32)
         return _one_bucket(pkb, skb, pvb, svb, gidb, st, en, num_segments, channels)
 
     per_bucket = jax.vmap(one)(pk, sk, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2), gid)
-    return _combine_buckets(per_bucket, channels)
+    with jax.named_scope("join_agg.segment_sum"):
+        return _combine_buckets(per_bucket, channels)
 
 
 @functools.partial(jit, static_argnames=("num_segments", "channels"))
@@ -146,7 +160,8 @@ def _fused_join_agg_bounds(
     per_bucket = jax.vmap(one)(
         pk, sk, st, en, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2), gid
     )
-    return _combine_buckets(per_bucket, channels)
+    with jax.named_scope("join_agg.segment_sum"):
+        return _combine_buckets(per_bucket, channels)
 
 
 def fused_join_aggregate(
@@ -179,11 +194,7 @@ def fused_join_aggregate(
         sk_dev = device_put_cached(sk)
         bounds = None
         if fused == "auto":
-            with obs_trace.span(
-                "device.kernel", kernel="pallas-run-bounds",
-                buckets=pk.shape[0], secondary=sk.shape[1],
-            ):
-                bounds = pallas_run_bounds(pk_dev, sk_dev)
+            bounds = pallas_run_bounds(pk_dev, sk_dev)
         if bounds is not None:
             out = _fused_join_agg_bounds(
                 pk_dev, sk_dev, bounds[0], bounds[1],
@@ -203,6 +214,6 @@ def fused_join_aggregate(
                 k_seg,
                 channels,
             )
-        return np.asarray(jax.device_get(out))
+        return np.asarray(to_host(out))
 
     return run_x64(call)[:, :num_groups]

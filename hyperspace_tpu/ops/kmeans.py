@@ -18,7 +18,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from hyperspace_tpu.compat import jit
+from hyperspace_tpu.compat import jit, to_host
 
 _TRAIN_SAMPLE = 131_072
 _ASSIGN_CHUNK = 262_144
@@ -58,7 +58,7 @@ def train_centroids(
         reps = -(-num_partitions // len(init))
         init = np.tile(init, (reps, 1))[:num_partitions]
     out = _lloyd(jnp.asarray(sample, dtype=jnp.float32), jnp.asarray(init), iters)
-    return np.asarray(jax.device_get(out))
+    return np.asarray(to_host(out))
 
 
 @jit
@@ -77,5 +77,5 @@ def assign_partitions(x: np.ndarray, centroids: np.ndarray) -> np.ndarray:
     out = []
     for lo in range(0, len(x), _ASSIGN_CHUNK):
         chunk = jnp.asarray(x[lo : lo + _ASSIGN_CHUNK], dtype=jnp.float32)
-        out.append(np.asarray(jax.device_get(_assign(chunk, c))))
+        out.append(np.asarray(to_host(_assign(chunk, c))))
     return np.concatenate(out) if out else np.zeros(0, np.int32)

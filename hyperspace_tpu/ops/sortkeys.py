@@ -160,8 +160,9 @@ def device_lanes_perm(lanes: list[np.ndarray]) -> np.ndarray:
     re-grouping uses instead of a separate host np.lexsort pass: callers
     stack e.g. [bucket lane, *key lanes] and get the grouped order in a
     single device dispatch."""
-    import jax
     import jax.numpy as jnp
+
+    from hyperspace_tpu.compat import to_host
 
     n = len(lanes[0]) if lanes else 0
     if n <= 1:
@@ -177,7 +178,7 @@ def device_lanes_perm(lanes: list[np.ndarray]) -> np.ndarray:
     iota = np.arange(l_pad, dtype=np.int32)[None, :]
     ops.append(jnp.asarray(iota))
     fn = _make_batch_sort(len(ops), 1 + len(lanes))
-    perm = np.asarray(jax.device_get(fn(*ops)))
+    perm = np.asarray(to_host(fn(*ops)))
     return perm[0, :n]
 
 
@@ -315,8 +316,9 @@ def _make_sharded_topn(mesh, axes, n: int):
         check_vma=False,
     )
     def fn(hi, lo, idx):
-        s = lax.sort((hi, lo, idx), num_keys=2, is_stable=True)
-        return s[0][:n], s[1][:n], s[2][:n]
+        with jax.named_scope("topk"):
+            s = lax.sort((hi, lo, idx), num_keys=2, is_stable=True)
+            return s[0][:n], s[1][:n], s[2][:n]
 
     from hyperspace_tpu.compat import jit
 
@@ -338,7 +340,8 @@ def _make_sharded_le(mesh, axes):
         check_vma=False,
     )
     def fn(hi, lo, thi, tlo):
-        return (hi < thi) | ((hi == thi) & (lo <= tlo))
+        with jax.named_scope("topk"):
+            return (hi < thi) | ((hi == thi) & (lo <= tlo))
 
     from hyperspace_tpu.compat import jit
 
@@ -355,8 +358,9 @@ def distributed_top_n_candidates(lanes_u32: np.ndarray, n: int, mesh) -> np.ndar
     row at or below it (prefix ties stay in — the exact candidate-set
     sort settles total order). On a one-device mesh this is the device
     venue's selection. Returns None when the mesh cannot help."""
-    import jax
     import jax.numpy as jnp
+
+    from hyperspace_tpu.compat import to_host
 
     from hyperspace_tpu.parallel.mesh import mesh_axes, mesh_size
 
@@ -381,7 +385,7 @@ def distributed_top_n_candidates(lanes_u32: np.ndarray, n: int, mesh) -> np.ndar
     hi_p = jnp.asarray(pad(hi, np.uint32(0xFFFFFFFF)))
     lo_p = jnp.asarray(pad(lo, np.uint32(0xFFFFFFFF)))
     idx = jnp.asarray(np.arange(n_pad, dtype=np.int32))
-    chi, clo, cidx = jax.device_get(_make_sharded_topn(mesh, axes, n)(hi_p, lo_p, idx))
+    chi, clo, cidx = to_host(_make_sharded_topn(mesh, axes, n)(hi_p, lo_p, idx))
     valid = cidx < n_rows
     chi, clo = chi[valid], clo[valid]
     if len(chi) < n:
@@ -389,7 +393,7 @@ def distributed_top_n_candidates(lanes_u32: np.ndarray, n: int, mesh) -> np.ndar
     order = np.lexsort((clo, chi))
     thr_hi, thr_lo = chi[order[n - 1]], clo[order[n - 1]]
     mask = np.asarray(
-        jax.device_get(
+        to_host(
             _make_sharded_le(mesh, axes)(
                 hi_p, lo_p, jnp.uint32(thr_hi), jnp.uint32(thr_lo)
             )
@@ -409,8 +413,9 @@ def device_sort_perms(tables, key_columns: list[str]) -> list[np.ndarray]:
     to a multiple of `_SORT_BATCH` all-pad rows: the streaming build
     hands over 1-8 buckets per call, and a multi-key sort takes about a
     minute to compile for TPU, so one program per length matters."""
-    import jax
     import jax.numpy as jnp
+
+    from hyperspace_tpu.compat import to_host
 
     if not tables:
         return []
@@ -433,5 +438,5 @@ def device_sort_perms(tables, key_columns: list[str]) -> list[np.ndarray]:
     iota = np.broadcast_to(np.arange(l_pad, dtype=np.int32), (b, l_pad))
     ops = [jnp.asarray(is_pad)] + [jnp.asarray(s) for s in stacked] + [jnp.asarray(np.ascontiguousarray(iota))]
     fn = _make_batch_sort(len(ops), 1 + num_lanes)
-    perm = np.asarray(jax.device_get(fn(*ops)))
+    perm = np.asarray(to_host(fn(*ops)))
     return [perm[i, : lens[i]] for i in range(len(tables))]

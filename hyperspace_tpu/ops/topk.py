@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from hyperspace_tpu import stats
+from hyperspace_tpu.compat import to_host
 
 _TILE = 2048
 _MAX_PALLAS_K = 64
@@ -59,20 +60,21 @@ def _make_tile_kernel(k: int, tile: int, interpret: bool):
     def run(scores):  # [q_pad, n_pad], q_pad % QBLOCK == n_pad % tile == 0
         q, n_pad = scores.shape
         tiles = n_pad // tile
-        return pl.pallas_call(
-            kernel,
-            grid=(q // _QBLOCK, tiles),
-            in_specs=[pl.BlockSpec((_QBLOCK, tile), lambda i, j: (i, j))],
-            out_specs=[
-                pl.BlockSpec((_QBLOCK, out_lanes), lambda i, j: (i, j)),
-                pl.BlockSpec((_QBLOCK, out_lanes), lambda i, j: (i, j)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((q, tiles * out_lanes), jnp.float32),
-                jax.ShapeDtypeStruct((q, tiles * out_lanes), jnp.int32),
-            ],
-            interpret=interpret,
-        )(scores)
+        with jax.named_scope("topk"):
+            return pl.pallas_call(
+                kernel,
+                grid=(q // _QBLOCK, tiles),
+                in_specs=[pl.BlockSpec((_QBLOCK, tile), lambda i, j: (i, j))],
+                out_specs=[
+                    pl.BlockSpec((_QBLOCK, out_lanes), lambda i, j: (i, j)),
+                    pl.BlockSpec((_QBLOCK, out_lanes), lambda i, j: (i, j)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((q, tiles * out_lanes), jnp.float32),
+                    jax.ShapeDtypeStruct((q, tiles * out_lanes), jnp.int32),
+                ],
+                interpret=interpret,
+            )(scores)
 
     # jit so repeated calls with the same shape hit the executable cache
     # instead of re-lowering the pallas_call every invocation.
@@ -121,8 +123,9 @@ def topk(scores, k: int, impl: str = "auto") -> tuple[np.ndarray, np.ndarray]:
     if impl == "pallas" or (impl == "auto" and k <= _MAX_PALLAS_K and n >= _MIN_PALLAS_N):
         v, i = _pallas_topk(scores, k)
         stats.increment("device.kernel.fused")
+        v, i = to_host((v, i))
         return np.asarray(v), np.asarray(i)
     if impl == "auto":
         stats.increment("device.kernel.fallbacks")
-    v, i = jax.lax.top_k(scores, k)
+    v, i = to_host(jax.lax.top_k(scores, k))
     return np.asarray(v), np.asarray(i)

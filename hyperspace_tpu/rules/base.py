@@ -17,6 +17,7 @@ from pathlib import Path
 from hyperspace_tpu.dataset import list_data_files
 from hyperspace_tpu.execution import io as hio
 from hyperspace_tpu.metadata.log_entry import IndexLogEntry
+from hyperspace_tpu.obs import trace as obs_trace
 from hyperspace_tpu.plan.nodes import LogicalPlan, Scan
 from hyperspace_tpu.schema import Schema
 from hyperspace_tpu.signature import create_signature_provider
@@ -36,8 +37,6 @@ class Rule:
 
 
 def apply_rules(plan: LogicalPlan, indexes: list[IndexLogEntry], rules=None, conf=None) -> LogicalPlan:
-    from hyperspace_tpu.obs import trace as obs_trace
-
     if rules is None:
         from hyperspace_tpu.rules.filter_index_rule import FilterIndexRule
         from hyperspace_tpu.rules.join_index_rule import JoinIndexRule
@@ -60,14 +59,16 @@ def index_scan_for(entry: IndexLogEntry) -> Scan:
     analog of constructing the index-backed HadoopFsRelation with a
     BucketSpec (JoinIndexRule.scala:124-153). All version dirs listed in
     `content.directories` participate: bucket b's data is the union of the
-    bucket-b files across dirs (base + incremental-refresh deltas)."""
+    bucket-b files across dirs (base + incremental-refresh deltas). The
+    listing, stat and manifest read are the ``plan.index_files`` span."""
     root = Path(entry.content.root)
     schema = Schema.from_json(entry.derived_dataset.schema)
     files: list[str] = []
-    for d in entry.content.directories:
-        files.extend(fi.path for fi in list_data_files(root / d))
-    first_dir = root / entry.content.directories[0]
-    manifest = hio.read_manifest(first_dir)
+    with obs_trace.span("plan.index_files", index=entry.name):
+        for d in entry.content.directories:
+            files.extend(fi.path for fi in list_data_files(root / d))
+        first_dir = root / entry.content.directories[0]
+        manifest = hio.read_manifest(first_dir)
     num_buckets = manifest["numBuckets"] if manifest else entry.derived_dataset.num_buckets
     return Scan(
         str(root),
@@ -138,9 +139,12 @@ class SignatureMatcher:
         )
 
     def match(self, entry: IndexLogEntry, source: LogicalPlan) -> IndexMatch | None:
+        # The source's fingerprint and the hybrid listing are the
+        # ``plan.fingerprint`` span, once per source plan.
         key = id(source)
         if key not in self._cache:
-            fp = self._provider.signature(source)
+            with obs_trace.span("plan.fingerprint"):
+                fp = self._provider.signature(source)
             self._cache[key] = None if fp is None else fp.value
         value = self._cache[key]
         if value is not None and value == entry.signature.value:
@@ -152,8 +156,9 @@ class SignatureMatcher:
         # One live listing per source plan, reused across candidate entries.
         if key not in self._files_cache:
             current = []
-            for leaf in source.leaves():
-                current.extend(collect_leaf_files(leaf))
+            with obs_trace.span("plan.fingerprint", hybrid=True):
+                for leaf in source.leaves():
+                    current.extend(collect_leaf_files(leaf))
             self._files_cache[key] = current
         appended, deleted = diff_source_files(entry, source, current=self._files_cache[key])
         if deleted or not appended:

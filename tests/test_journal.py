@@ -225,8 +225,11 @@ def test_sigkill_mid_rotation_leaves_mergeable_segments(tmp_path):
         child_dir = root / str(proc.pid)
         deadline = time.monotonic() + 60.0
         # Wait until the child has sealed at least two segments AND has
-        # an active tmp tail — then SIGKILL it mid-segment.
+        # an active tmp tail — then SIGKILL it mid-segment. The child is
+        # stopped while its directory is read, so the tail seen is the
+        # tail the kill tears.
         while time.monotonic() < deadline:
+            proc.send_signal(signal.SIGSTOP)
             sealed = journal.segment_paths(child_dir)
             tmps = (
                 [p for p in child_dir.iterdir()
@@ -235,6 +238,7 @@ def test_sigkill_mid_rotation_leaves_mergeable_segments(tmp_path):
             )
             if len(sealed) >= 2 and tmps:
                 break
+            proc.send_signal(signal.SIGCONT)
             time.sleep(0.05)
         else:
             raise AssertionError("child never sealed two segments")
