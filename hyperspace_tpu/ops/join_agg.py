@@ -26,6 +26,20 @@ epilogue; bounds are integers, so results are byte-identical to the
 all-lax path by construction. Ineligible shapes take the lax
 searchsorted (`device.kernel.fused`/`device.kernel.fallbacks` count the
 split); a lowering failure of an eligible shape raises.
+
+Group reduction: each channel's per-row weights fold into [C, K] group
+results in one of two ways, chosen by the padded group count K alone.
+Up to ``_DENSE_MAX_SEGMENTS`` every channel reduces densely over all
+primary rows at once, ``out[c, g] = sum of where(gid == g, w[c], 0)``
+(the +-inf identity for extrema): K compare-select-adds a row, which
+XLA fuses into the reduction on a TPU. Above it each channel keeps its
+per-bucket segment scatter, whose cost on a v5e (about 110 ns a row in
+float64) does not depend on K. The bound comes from timing both
+programs on a v5e at the SF1 report's shape (PERF.md).
+``device.kernel.dense_reduce`` / ``device.kernel.scatter_reduce`` count
+the split. Extrema, and integral sums whose partial sums the float64
+holds exactly, agree bit for bit on both paths; other float sums differ
+only in summation order.
 """
 
 from __future__ import annotations
@@ -37,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from hyperspace_tpu import stats
 from hyperspace_tpu.compat import jit, to_host
 
 
@@ -54,12 +69,33 @@ def _seg_scan_extremum(vals, new_seg, op):
     return out
 
 
-def _one_bucket(pkb, skb, pvb, svb, gidb, stb, enb, num_segments: int, channels: tuple):
-    """Per-bucket channel reduction given the run bounds [stb, enb). The
-    named scopes label each stage's device ops in a profile."""
+#: Largest padded group count (``num_segments``) whose channels reduce
+#: densely. On a v5e at the SF1 report's shape (200 x 31,104 rows, five
+#: channels) the dense program beats the scatters 7.2x at K = 64, 2.5x
+#: at 4,096 and only 1.07x at 16,384, so the crossover lies just above.
+_DENSE_MAX_SEGMENTS = 4096
+#: (group, row) pairs one step of the dense reduction compares: a bound
+#: on the [K, rows] mask that XLA:CPU materializes (the TPU fuses it).
+_DENSE_TILE_ELEMS = 1 << 22
+
+
+def _reduce_op(kind: str) -> str:
+    """The reduction a channel kind folds its per-row weights with."""
+    if kind.endswith("min"):
+        return "min"
+    if kind.endswith("max"):
+        return "max"
+    return "sum"
+
+
+def _bucket_weights(pkb, skb, pvb, svb, stb, enb, channels: tuple):
+    """The C per-row weights [Lp] of one bucket given the run bounds
+    [stb, enb): each primary row's contribution to every channel, with
+    pads and rows that matched nothing at their reduction's identity (0
+    for sums, +-inf for extrema). The named scopes label each stage's
+    device ops in a profile."""
     bounds = functools.partial(jax.named_scope, "join_agg.bounds")
     gather = functools.partial(jax.named_scope, "join_agg.gather")
-    segment = functools.partial(jax.named_scope, "join_agg.segment_sum")
     with bounds():
         real = pkb < jnp.iinfo(pkb.dtype).max
         matched = real & (enb > stb)
@@ -77,55 +113,95 @@ def _one_bucket(pkb, skb, pvb, svb, gidb, stb, enb, num_segments: int, channels:
             new_key = jnp.concatenate(
                 [jnp.ones(1, bool), skb[1:] != skb[:-1]]
             )
-    outs = []
+    ws = []
     for ch in channels:
         kind = ch[0]
         if kind == "star":
-            with segment():
-                outs.append(jax.ops.segment_sum(runlen, gidb, num_segments))
+            ws.append(runlen)
         elif kind == "p":
-            with segment():
-                outs.append(jax.ops.segment_sum(pvb[ch[1]] * runlen, gidb, num_segments))
+            ws.append(pvb[ch[1]] * runlen)
         elif kind == "s":
             with gather():
                 pj = p_prefix[ch[1]]
-                w = jnp.where(real, pj[enb] - pj[stb], 0.0)
-            with segment():
-                outs.append(jax.ops.segment_sum(w, gidb, num_segments))
+                ws.append(jnp.where(real, pj[enb] - pj[stb], 0.0))
         else:
             is_min = kind.endswith("min")
             ident = jnp.inf if is_min else -jnp.inf
-            seg_red = jax.ops.segment_min if is_min else jax.ops.segment_max
             with gather():
                 if kind[0] == "p":
-                    w = jnp.where(matched, pvb[ch[1]], ident)
+                    ws.append(jnp.where(matched, pvb[ch[1]], ident))
                 else:
                     m = _seg_scan_extremum(
                         svb[ch[1]], new_key, jnp.minimum if is_min else jnp.maximum
                     )
-                    w = jnp.where(matched, m[jnp.maximum(enb - 1, 0)], ident)
-            with segment():
-                outs.append(seg_red(w, gidb, num_segments))
-    return jnp.stack(outs)
+                    ws.append(jnp.where(matched, m[jnp.maximum(enb - 1, 0)], ident))
+    return tuple(ws)
 
 
-def _combine_buckets(per_bucket, channels: tuple):
-    """Fold the vmapped [B, C, K] per-bucket partials across buckets (a
-    group's rows can span buckets only via the primary side's bucketing;
-    sums add, extrema fold with their own op)."""
-    combined = []
-    for c, ch in enumerate(channels):
-        if ch[0] == "pmin" or ch[0] == "smin":
-            combined.append(jnp.min(per_bucket[:, c], axis=0))
-        elif ch[0] == "pmax" or ch[0] == "smax":
-            combined.append(jnp.max(per_bucket[:, c], axis=0))
-        else:
-            combined.append(jnp.sum(per_bucket[:, c], axis=0))
-    return jnp.stack(combined)  # [C, num_segments]
+#: Per reduction: (fold along axes, combine two partials, identity).
+_FOLD = {
+    "sum": (jnp.sum, jnp.add, 0.0),
+    "min": (jnp.min, jnp.minimum, jnp.inf),
+    "max": (jnp.max, jnp.maximum, -jnp.inf),
+}
+_SEGMENT_REDUCE = {
+    "sum": jax.ops.segment_sum,
+    "min": jax.ops.segment_min,
+    "max": jax.ops.segment_max,
+}
 
 
-@functools.partial(jit, static_argnames=("num_segments", "channels"))
-def _fused_join_agg(pk, sk, pvals, svals, gid, num_segments: int, channels: tuple):
+def _dense_reduce(ws, gid, num_segments: int, channels: tuple):
+    """[C, K] from the channel weights ws (each [B, Lp]) and group ids
+    gid [B, Lp]: ``op over rows of where(gid == g, w[c], identity)``, a
+    fori_loop over blocks of whole buckets (``blk`` divides B) that keeps
+    each block's mask within ``_DENSE_TILE_ELEMS`` pairs. Ids outside
+    [0, K) fall in no group, as with the scatter."""
+    n_b, n_rows = gid.shape
+    cap = max(1, _DENSE_TILE_ELEMS // max(n_rows * num_segments, 1))
+    blk = max((d for d in range(1, min(n_b, cap) + 1) if n_b % d == 0), default=1)
+    seg = jnp.arange(num_segments, dtype=gid.dtype)[:, None, None]
+    folds = [_FOLD[_reduce_op(ch[0])] for ch in channels]
+
+    def step(i, acc):
+        def block(a):
+            return jax.lax.dynamic_slice_in_dim(a, i * blk, blk)
+
+        hit = block(gid) == seg  # [K, blk, Lp]
+        return tuple(
+            combine(a, fold(jnp.where(hit, block(w), ident), axis=(1, 2)))
+            for a, w, (fold, combine, ident) in zip(acc, ws, folds)
+        )
+
+    init = tuple(jnp.full(num_segments, ident, w.dtype) for w, (_, _, ident) in zip(ws, folds))
+    return jnp.stack(jax.lax.fori_loop(0, n_b // blk, step, init))
+
+
+def _scatter_reduce(ws, gid, num_segments: int, channels: tuple):
+    """[C, K] by one vmapped segment scatter per channel and bucket,
+    folded across buckets (a group's rows span buckets)."""
+    ops = [_reduce_op(ch[0]) for ch in channels]
+
+    def one(wb, gidb):
+        return [_SEGMENT_REDUCE[op](w, gidb, num_segments) for w, op in zip(wb, ops)]
+
+    per_bucket = jax.vmap(one)(ws, gid)
+    return jnp.stack([_FOLD[op][0](p, axis=0) for p, op in zip(per_bucket, ops)])
+
+
+def _reduce(ws, gid, num_segments: int, channels: tuple, reduce: str):
+    """Fold every bucket's channel weights into [C, num_segments]:
+    ``reduce`` 'dense' (:func:`_dense_reduce`) or 'scatter'."""
+    with jax.named_scope("join_agg.segment_sum"):
+        if reduce == "dense":
+            return _dense_reduce(ws, gid, num_segments, channels)
+        return _scatter_reduce(ws, gid, num_segments, channels)
+
+
+@functools.partial(jit, static_argnames=("num_segments", "channels", "reduce"))
+def _fused_join_agg(
+    pk, sk, pvals, svals, gid, num_segments: int, channels: tuple, reduce: str
+):
     """pk/sk: [B, Lp]/[B, Ls] per-bucket sorted int32 codes (pads carry
     the dtype max). pvals [Ap, B, Lp] / svals [As, B, Ls]: float64
     per-row channel values (nulls and pads pre-zeroed for sum channels,
@@ -134,34 +210,31 @@ def _fused_join_agg(pk, sk, pvals, svals, gid, num_segments: int, channels: tupl
     sum channels | ('pmin'|'pmax'|'smin'|'smax', j) run-extremum channels
     (an equi-join match run IS one key segment of the sorted secondary,
     so its extremum is the segmented prefix scan value at the run end).
-    Returns [len(channels), num_segments] float64."""
+    reduce: 'dense' | 'scatter' (:func:`_reduce`). Returns
+    [len(channels), num_segments] float64."""
 
-    def one(pkb, skb, pvb, svb, gidb):
+    def one(pkb, skb, pvb, svb):
         with jax.named_scope("join_agg.bounds"):
             st = jnp.searchsorted(skb, pkb, side="left").astype(jnp.int32)
             en = jnp.searchsorted(skb, pkb, side="right").astype(jnp.int32)
-        return _one_bucket(pkb, skb, pvb, svb, gidb, st, en, num_segments, channels)
+        return _bucket_weights(pkb, skb, pvb, svb, st, en, channels)
 
-    per_bucket = jax.vmap(one)(pk, sk, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2), gid)
-    with jax.named_scope("join_agg.segment_sum"):
-        return _combine_buckets(per_bucket, channels)
+    ws = jax.vmap(one)(pk, sk, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2))
+    return _reduce(ws, gid, num_segments, channels, reduce)
 
 
-@functools.partial(jit, static_argnames=("num_segments", "channels"))
+@functools.partial(jit, static_argnames=("num_segments", "channels", "reduce"))
 def _fused_join_agg_bounds(
-    pk, sk, st, en, pvals, svals, gid, num_segments: int, channels: tuple
+    pk, sk, st, en, pvals, svals, gid, num_segments: int, channels: tuple, reduce: str
 ):
     """Same program as :func:`_fused_join_agg` with the run bounds
     precomputed (the Pallas run-bounds kernel feeds this variant)."""
 
-    def one(pkb, skb, stb, enb, pvb, svb, gidb):
-        return _one_bucket(pkb, skb, pvb, svb, gidb, stb, enb, num_segments, channels)
+    def one(pkb, skb, stb, enb, pvb, svb):
+        return _bucket_weights(pkb, skb, pvb, svb, stb, enb, channels)
 
-    per_bucket = jax.vmap(one)(
-        pk, sk, st, en, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2), gid
-    )
-    with jax.named_scope("join_agg.segment_sum"):
-        return _combine_buckets(per_bucket, channels)
+    ws = jax.vmap(one)(pk, sk, st, en, pvals.transpose(1, 0, 2), svals.transpose(1, 0, 2))
+    return _reduce(ws, gid, num_segments, channels, reduce)
 
 
 def fused_join_aggregate(
@@ -185,6 +258,12 @@ def fused_join_aggregate(
     from hyperspace_tpu.parallel.x64 import run_x64
 
     k_seg = 1 << max(int(num_groups).bit_length(), 1)  # >= num_groups+1
+    if k_seg <= _DENSE_MAX_SEGMENTS:
+        reduce = "dense"
+        stats.increment("device.kernel.dense_reduce")
+    else:
+        reduce = "scatter"
+        stats.increment("device.kernel.scatter_reduce")
 
     def call():
         # Stable (frozen, identity-cached) inputs serve from the HBM
@@ -203,6 +282,7 @@ def fused_join_aggregate(
                 device_put_cached(gid),
                 k_seg,
                 channels,
+                reduce,
             )
         else:
             out = _fused_join_agg(
@@ -213,6 +293,7 @@ def fused_join_aggregate(
                 device_put_cached(gid),
                 k_seg,
                 channels,
+                reduce,
             )
         return np.asarray(to_host(out))
 

@@ -77,6 +77,11 @@ Counter names in use:
 - ``device.kernel.fallbacks``  device-venue reduces that took the
   always-available jitted lax path while fused kernels were enabled
   (ineligible shape, unprovable exactness, or a failed Pallas lowering)
+- ``device.kernel.dense_reduce``  device join-aggregates whose padded
+  group count let every channel reduce in one dense masked reduction
+  over all rows (ops/join_agg.py)
+- ``device.kernel.scatter_reduce``  device join-aggregates with too many
+  groups for that, which reduce each channel by a segment scatter
 - ``controller.ticks``  reconciliation steps the self-driving operations
   controller ran while armed (serve/controller.py,
   docs/fault_tolerance.md "self-driving operations")
@@ -181,6 +186,8 @@ KNOWN_COUNTERS = (
     "device.stage.bytes_copied",
     "device.kernel.fused",
     "device.kernel.fallbacks",
+    "device.kernel.dense_reduce",
+    "device.kernel.scatter_reduce",
     "controller.ticks",
     "controller.actuations",
     "controller.actuation_failures",

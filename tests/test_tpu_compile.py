@@ -89,6 +89,34 @@ def test_run_bounds_compiles_for_every_bucket_row(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_join_agg_dense_reduce_compiles_at_sf1_shape(one_chip):
+    """The fused join-aggregate with its dense float64 group reduction, as
+    the SF1 report runs it: 200 buckets of 31,104 padded lineitem rows
+    against 7,552 orders rows, the count and two sums of each side, 64
+    padded groups."""
+    from hyperspace_tpu.ops.join_agg import _DENSE_MAX_SEGMENTS, _fused_join_agg_bounds
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    b, lp, ls, k = 200, 31104, 7552, 64
+    assert k <= _DENSE_MAX_SEGMENTS
+    channels = (("star",), ("s", 0), ("s", 1), ("p", 0), ("p", 1))
+    i32 = [_shape(one_chip, s, jnp.int32) for s in ((b, lp), (b, ls), (b, lp), (b, lp))]
+    compiled = run_x64(
+        lambda: _fused_join_agg_bounds.lower(
+            *i32,
+            _shape(one_chip, (2, b, lp), jnp.float64),
+            _shape(one_chip, (2, b, ls), jnp.float64),
+            _shape(one_chip, (b, lp), jnp.int32),
+            num_segments=k,
+            channels=channels,
+            reduce="dense",
+        ).compile()
+    )
+    assert "scatter" not in compiled.as_text()
+    # No [K, rows] mask or masked-weight buffer is held in HBM.
+    assert compiled.memory_analysis().temp_size_in_bytes < k * b * lp
+
+
 def test_topk_tile_kernel_compiles(one_chip):
     from hyperspace_tpu.ops.topk import _QBLOCK, _TILE, _make_tile_kernel
 
