@@ -55,7 +55,7 @@ KNOWN_STATIC_DOMAINS = {
     "venue": ("auto", "device", "host"),
     "fused": ("auto", "off"),
     "impl": ("auto", "pallas", "lax"),
-    "reduce": ("dense", "scatter"),
+    "reduce": ("dense", "scatter", "bucket_dense"),
 }
 
 

@@ -174,6 +174,7 @@ class AggregateMixin:
                 pkeys.append(c)
                 pk_low.add(c.lower())
 
+        probe_at = len(self._cur_phys.children) if self._cur_phys is not None else 0
         lt = self._execute(child.left)
         gid, k, rep = _group_ids_cached(lt, pkeys)
         if k > max(64, lt.num_rows // 8):
@@ -186,6 +187,11 @@ class AggregateMixin:
             # (and its DPP pruning), which beats the re-execution it
             # avoids (the scan is cache-served anyway).
             if self._aligned_side(child.left) is not None:
+                # The aligned join reads the side again itself: the probe
+                # leaves the operator tree and stays as evidence here.
+                if self._cur_phys is not None:
+                    del self._cur_phys.children[probe_at:]
+                self._phys(pushdown_probe_rows=lt.num_rows, pushdown_probe_groups=k)
                 return None
             return Aggregate(
                 Join(_TableLeaf(lt), child.right, child.left_on, child.right_on,

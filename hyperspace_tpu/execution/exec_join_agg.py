@@ -131,9 +131,14 @@ class FusedJoinAggMixin:
             out, spec_layout = host_res
         else:
             self.stats["join_kernel"] = "device-run-prefix"
+            # Group keys that hold the primary side's join keys put every
+            # group in one bucket.
+            pkeys = join.left_on if primary == "left" else join.right_on
+            bucket_local = {c.lower() for c in pkeys} <= {c.lower() for c in plan.group_by}
             out, spec_layout = self._device_fused_channels(
                 plan, data, codes, perms, primary, secondary, spec_sides,
                 gid_orig, k, spec_input, fused=self._fused_kernels(),
+                bucket_local=bucket_local,
             )
         star = out[0]
 
@@ -173,7 +178,7 @@ class FusedJoinAggMixin:
 
     def _device_fused_channels(
         self, plan, data, codes, perms, primary, secondary, spec_sides, gid_orig, k,
-        spec_input, fused: str = "off",
+        spec_input, fused: str = "off", bucket_local: bool = False,
     ):
         """Device venue: the run-prefix kernel over bucket-major padded
         channels (ops/join_agg.py). Pads, the channel stacks, and the
@@ -181,7 +186,10 @@ class FusedJoinAggMixin:
         over a stable index version serve from HBM. With `fused` = auto
         the pad widths round up to the 128-lane tile so the Pallas
         run-bounds kernel can engage (extra pads are sentinels/dead
-        rows — results are unchanged by construction)."""
+        rows — results are unchanged by construction), and further to a
+        power of two: a filtered side whose largest bucket moves by a few
+        rows from one query's literals to the next keeps one compiled
+        program (a finer ladder still splits such a side at a step)."""
         from hyperspace_tpu.execution import device_cache as dcache
         from hyperspace_tpu.ops.join_agg import fused_join_aggregate
 
@@ -189,8 +197,8 @@ class FusedJoinAggMixin:
             if fused != "auto":
                 return None  # natural Lmax width
             counts = np.diff(offsets)
-            lm = max(int(counts.max()) if counts.size else 1, 1)
-            return ((lm + 127) // 128) * 128
+            lm = max(int(counts.max()) if counts.size else 1, 128)
+            return 1 << (lm - 1).bit_length()
 
         pk = _pad_bucket_major_cached(
             codes[primary], data[primary].offsets, width=width_of(data[primary].offsets)
@@ -280,7 +288,8 @@ class FusedJoinAggMixin:
         pvals = _stack_cached(p_arrays, (0, b, lp))
         svals = _stack_cached(s_arrays, (0, b, ls))
         out = fused_join_aggregate(
-            pk, sk, pvals, svals, gid_pad, k, tuple(channels), fused=fused
+            pk, sk, pvals, svals, gid_pad, k, tuple(channels), fused=fused,
+            bucket_local=bucket_local,
         )
         return out, spec_layout
 

@@ -187,10 +187,15 @@ class ScanFilterMixin:
                     pruned, child.scan_schema.names, child.scan_schema, index_root=child.root
                 )
                 return apply_filter(table, plan.predicate, mesh=self.mesh, venue=mask_venue)
-            ranged = self._range_read(child, plan.predicate)
+            key_only = predicate_all_key_bounds(plan.predicate, child.bucket_spec[1][0])
+            # When the slice is the whole predicate, it is the operator's
+            # only compute: the device venue finds it on the device.
+            ranged = self._range_read(
+                child, plan.predicate, on_device=key_only and mask_venue != "host"
+            )
             if ranged is not None:
-                table, exact = ranged
-                if exact and predicate_all_key_bounds(plan.predicate, child.bucket_spec[1][0]):
+                table, exact, slicer = ranged
+                if exact and key_only:
                     # The slice IS the predicate: every conjunct bounds the
                     # sorted key, so the residual mask would be all-true —
                     # skip its evaluation (and the device round-trip).
@@ -198,14 +203,14 @@ class ScanFilterMixin:
                         "IndexRangeScan",
                         files_pruned=self.stats["files_pruned"] - fp0,
                         rows_pruned=self.stats["rows_pruned"] - rp0,
-                        kernel="minmax-prune + searchsorted-slice (exact, mask skipped)",
+                        kernel=f"minmax-prune + {slicer} (exact, mask skipped)",
                     )
                     return table
                 self._phys(
                     "IndexRangeScan",
                     files_pruned=self.stats["files_pruned"] - fp0,
                     rows_pruned=self.stats["rows_pruned"] - rp0,
-                    kernel=f"minmax-prune + searchsorted-slice + {mask_kernel}",
+                    kernel=f"minmax-prune + {slicer} + {mask_kernel}",
                 )
                 return apply_filter(table, plan.predicate, mesh=self.mesh, venue=mask_venue)
         if isinstance(child, Union):
@@ -307,15 +312,21 @@ class ScanFilterMixin:
         self.stats["files_pruned"] += len(files) - len(kept)
         return kept, (bounds if stat_conv is not None else None), stats
 
-    def _range_read(self, scan: Scan, predicate: Expr) -> tuple[ColumnTable, bool] | None:
+    def _range_read(
+        self, scan: Scan, predicate: Expr, on_device: bool = False
+    ) -> tuple[ColumnTable, bool, str] | None:
         """File-level range pruning + within-file searchsorted slicing
         (each surviving file is key-sorted by construction, so qualifying
         rows form one contiguous run). Dictionary codes are not
         value-ordered across files and null prefixes break sortedness —
         both fall back to reading the file whole (mask handles the rest).
-        Returns (table, exact): exact ⇔ every row returned provably
+        Returns (table, exact, slicer): exact ⇔ every row returned provably
         satisfies the key bounds (all parts sliced on a sorted, null-free,
-        stats-backed key)."""
+        stats-backed key); slicer names where the run bounds were found.
+        With `on_device`, an integer key's bounds for every file come
+        from one device call (ops/sortkeys.device_slice_bounds)."""
+        from hyperspace_tpu.ops.sortkeys import device_slice_bounds
+
         from concurrent.futures import ThreadPoolExecutor
 
         pruned = self._range_prune_list(scan, predicate)
@@ -324,8 +335,9 @@ class ScanFilterMixin:
         kept, bounds, stats_files = pruned
         schema = scan.scan_schema
         field = schema.field(scan.bucket_spec[1][0])
+        slicer = "searchsorted-slice"
         if not kept:
-            return ColumnTable.empty(schema), True
+            return ColumnTable.empty(schema), True, slicer
         before = hio.table_cache_stats()
         try:
             with ThreadPoolExecutor(max_workers=min(8, len(kept))) as pool:
@@ -349,22 +361,30 @@ class ScanFilterMixin:
         # them — never claim exactness for float key columns. bounds is
         # None when only included-column stats pruned: no key slicing.
         exact = bounds is not None and field.device_dtype.kind != "f"
-        for fp, t in zip(kept, tables):
-            if t.num_rows == 0:
-                continue
-            sliceable = (
-                bounds is not None
-                and not field.is_string
-                and t.valid_mask(field.name) is None
-                and fp in stats_files  # stats-backed ⇒ written key-sorted
-            )
-            if sliceable:
+        live = [(fp, t) for fp, t in zip(kept, tables) if t.num_rows]
+        sliceable = [
+            bounds is not None
+            and not field.is_string
+            and t.valid_mask(field.name) is None
+            and fp in stats_files  # stats-backed ⇒ written key-sorted
+            for fp, t in live
+        ]
+        runs = None
+        if on_device and live and all(sliceable) and field.device_dtype.kind in "iu":
+            runs = device_slice_bounds([t.columns[field.name] for _, t in live], bounds)
+            if runs is not None:
+                slicer = "device-searchsorted-slice"
+        for i, ((_fp, t), cut) in enumerate(zip(live, sliceable)):
+            if cut:
                 colv = t.columns[field.name]
                 lo_i, hi_i = 0, t.num_rows
-                if bounds.lo is not None:
-                    lo_i = int(np.searchsorted(colv, bounds.lo, side="right" if bounds.lo_strict else "left"))
-                if bounds.hi is not None:
-                    hi_i = int(np.searchsorted(colv, bounds.hi, side="left" if bounds.hi_strict else "right"))
+                if runs is not None:
+                    lo_i, hi_i = int(runs[i, 0]), int(runs[i, 1])
+                else:
+                    if bounds.lo is not None:
+                        lo_i = int(np.searchsorted(colv, bounds.lo, side="right" if bounds.lo_strict else "left"))
+                    if bounds.hi is not None:
+                        hi_i = int(np.searchsorted(colv, bounds.hi, side="left" if bounds.hi_strict else "right"))
                 if hi_i <= lo_i:
                     self.stats["rows_pruned"] += t.num_rows
                     continue
@@ -375,8 +395,8 @@ class ScanFilterMixin:
                 exact = False
             parts.append(t)
         if not parts:
-            return ColumnTable.empty(schema), True
+            return ColumnTable.empty(schema), True, slicer
         out = ColumnTable.concat(parts) if len(parts) > 1 else parts[0]
-        return out, exact
+        return out, exact, slicer
 
     # -- join ------------------------------------------------------------

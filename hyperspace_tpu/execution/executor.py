@@ -474,7 +474,7 @@ class Executor(
     def _sort(self, plan: "Sort") -> ColumnTable:
         table = self._execute(plan.child)
         venue = self._venue("sort_venue", "hyperspace.sort.venue", False, needs_native=False)
-        self._phys(f"{venue.capitalize()}Sort", keys=[c for c, _ in plan.by])
+        self._phys(f"{venue.capitalize()}Sort", keys=[c for c, _ in plan.by], venue=venue)
         return self._sorted_table(table, plan, venue)
 
     def _sorted_table(self, table: ColumnTable, plan: "Sort", venue: str | None = None) -> ColumnTable:

@@ -36,6 +36,7 @@ count(*) counts rows; a group whose inputs are all null yields NULL
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 
@@ -46,6 +47,8 @@ from hyperspace_tpu import stats
 from hyperspace_tpu.compat import jit, to_host
 from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.execution.table import ColumnTable
+from hyperspace_tpu.obs import trace as obs_trace
+from hyperspace_tpu.ops.join_agg import _DENSE_MAX_SEGMENTS, _dense_reduce
 from hyperspace_tpu.plan.expr import Col, evaluate
 from hyperspace_tpu.schema import Schema
 
@@ -116,19 +119,24 @@ def _make_pallas_segment_reduce(fns: tuple, k_pad: int, tile: int, interpret: bo
 
     def run(gid2d, vals):  # gid2d [1, n_pad] int32, vals [C, n_pad] float32
         n_pad = vals.shape[1]
-        return pl.pallas_call(
-            kernel,
-            grid=(n_pad // tile,),
-            in_specs=[
-                pl.BlockSpec((1, tile), lambda i: (zero, i)),
-                pl.BlockSpec((c_num, tile), lambda i: (zero, i)),
-            ],
-            out_specs=pl.BlockSpec((c_num, k_pad), lambda i: (zero, zero)),
-            out_shape=jax.ShapeDtypeStruct((c_num, k_pad), jnp.float32),
-            interpret=interpret,
-        )(gid2d, vals)
+        with jax.named_scope("agg.segment_reduce"):
+            return pl.pallas_call(
+                kernel,
+                grid=(n_pad // tile,),
+                in_specs=[
+                    pl.BlockSpec((1, tile), lambda i: (zero, i)),
+                    pl.BlockSpec((c_num, tile), lambda i: (zero, i)),
+                ],
+                out_specs=pl.BlockSpec((c_num, k_pad), lambda i: (zero, zero)),
+                out_shape=jax.ShapeDtypeStruct((c_num, k_pad), jnp.float32),
+                interpret=interpret,
+            )(gid2d, vals)
 
     return jit(run, key="ops.aggregate.pallas_segment_reduce")
+
+
+#: Row width of the [n / w, w] blocks the dense reduction walks.
+_DENSE_ROWS = 4096
 
 
 @functools.partial(jit, static_argnames=("num_segments", "fns"))
@@ -136,17 +144,32 @@ def _segment_reduce_many(vals, gid, num_segments: int, fns: tuple):
     """One device program reducing several (value, fn) pairs over shared
     segment ids. vals: [A, n_pad]; returns [A, num_segments]."""
     outs = []
-    for i, fn in enumerate(fns):
-        v = vals[i]
-        if fn == "sum":
-            outs.append(jax.ops.segment_sum(v, gid, num_segments))
-        elif fn == "min":
-            outs.append(jax.ops.segment_min(v, gid, num_segments))
-        elif fn == "max":
-            outs.append(jax.ops.segment_max(v, gid, num_segments))
-        else:
-            raise ValueError(fn)
-    return jnp.stack(outs)
+    with jax.named_scope("agg.segment_reduce"):
+        for i, fn in enumerate(fns):
+            v = vals[i]
+            if fn == "sum":
+                outs.append(jax.ops.segment_sum(v, gid, num_segments))
+            elif fn == "min":
+                outs.append(jax.ops.segment_min(v, gid, num_segments))
+            elif fn == "max":
+                outs.append(jax.ops.segment_max(v, gid, num_segments))
+            else:
+                raise ValueError(fn)
+        return jnp.stack(outs)
+
+
+@functools.partial(jit, static_argnames=("num_segments", "fns"))
+def _dense_segment_reduce(vals, gid, num_segments: int, fns: tuple):
+    """:func:`_segment_reduce_many` by one dense masked reduction per
+    channel, as the fused join-aggregate reduces its groups
+    (ops/join_agg._dense_reduce: K compare-select-adds a row, which a TPU
+    fuses into the reduction)."""
+    with jax.named_scope("agg.segment_reduce"):
+        w = math.gcd(gid.shape[0], _DENSE_ROWS)
+        return _dense_reduce(
+            tuple(v.reshape(-1, w) for v in vals), gid.reshape(-1, w),
+            num_segments, tuple((fn,) for fn in fns),
+        )
 
 
 @functools.lru_cache(maxsize=32)
@@ -176,16 +199,17 @@ def _make_sharded_segment_reduce(mesh, axes: tuple, num_segments: int, fns: tupl
     def fn(vals, gid):
         local = _segment_reduce_many.__wrapped__(vals, gid, num_segments, fns)
         outs = []
-        for i, f in enumerate(fns):
-            if f == "sum":
-                outs.append(jax.lax.psum(local[i], axes))
-            elif f == "min":
-                outs.append(jax.lax.pmin(local[i], axes))
-            elif f == "max":
-                outs.append(jax.lax.pmax(local[i], axes))
-            else:
-                raise ValueError(f)
-        return jnp.stack(outs)
+        with jax.named_scope("agg.segment_reduce"):
+            for i, f in enumerate(fns):
+                if f == "sum":
+                    outs.append(jax.lax.psum(local[i], axes))
+                elif f == "min":
+                    outs.append(jax.lax.pmin(local[i], axes))
+                elif f == "max":
+                    outs.append(jax.lax.pmax(local[i], axes))
+                else:
+                    raise ValueError(f)
+            return jnp.stack(outs)
 
     return jit(fn, key="ops.aggregate.sharded_reduce")
 
@@ -494,13 +518,6 @@ def aggregate_arrays(
         g[:n] = gid
         return g
 
-    if dcache.is_stable(gid):
-        gid_p = dcache.derived(
-            ("gidpad1", id(gid), n_pad, num_groups), (gid,), build_gid_pad
-        )
-    else:
-        gid_p = build_gid_pad()
-
     fns: list[str] = []
     chan_exact: list[bool] = []
     for i, (_vals, _valid, fn) in enumerate(inputs):
@@ -530,16 +547,23 @@ def aggregate_arrays(
         dcache.is_stable(v) and (m is None or dcache.is_stable(m))
         for v, m, _fn in inputs
     )
-    if stable:
-        ids = tuple((id(v), id(m) if m is not None else None) for v, m, _fn in inputs)
-        refs = tuple(
-            a for v, m, _fn in inputs for a in ((v, m) if m is not None else (v,))
-        )
-        stacked = dcache.derived(
-            ("aggstack", ids, tuple(fns), n_pad), refs, build_channels
-        )
-    else:
-        stacked = build_channels()
+    with obs_trace.span("agg.channels"):
+        if dcache.is_stable(gid):
+            gid_p = dcache.derived(
+                ("gidpad1", id(gid), n_pad, num_groups), (gid,), build_gid_pad
+            )
+        else:
+            gid_p = build_gid_pad()
+        if stable:
+            ids = tuple((id(v), id(m) if m is not None else None) for v, m, _fn in inputs)
+            refs = tuple(
+                a for v, m, _fn in inputs for a in ((v, m) if m is not None else (v,))
+            )
+            stacked = dcache.derived(
+                ("aggstack", ids, tuple(fns), n_pad), refs, build_channels
+            )
+        else:
+            stacked = build_channels()
     # 53-bit accumulation on the persistent x64 worker thread — the
     # process-wide flag is never touched (round 1 weakness #8).
     from hyperspace_tpu.parallel.x64 import run_x64
@@ -551,28 +575,32 @@ def aggregate_arrays(
         if fused == "auto":
             stats.increment("device.kernel.fallbacks")
         if d > 1:
+            stats.increment("device.kernel.segment_reduce_sharded")
+            path = "sharded"
             reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, tuple(fns))
-            out = np.asarray(
-                run_x64(
-                    lambda: to_host(reduce_fn(jnp.asarray(stacked), jnp.asarray(gid_p)))
-                )
-            )
+            with obs_trace.span("agg.channels"):
+                args = run_x64(lambda: (jnp.asarray(stacked), jnp.asarray(gid_p)))
         else:
+            stats.increment("device.kernel.segment_reduce_lax")
+            path = "lax"
+            # An accelerator's float64 segment scatter costs about 110 ns
+            # a row whatever K (ops/join_agg.py); the dense reduction K
+            # fused compare-select-adds. XLA:CPU's scatter adds rows in
+            # order, the host venue's bincount order bit for bit: the CPU
+            # keeps it.
+            dense = k_seg <= _DENSE_MAX_SEGMENTS and jax.default_backend() != "cpu"
             reduce_fn = functools.partial(
-                _segment_reduce_many, num_segments=k_seg, fns=tuple(fns)
+                _dense_segment_reduce if dense else _segment_reduce_many,
+                num_segments=k_seg, fns=tuple(fns),
             )
             # Stable stacks/pads serve the upload from the HBM cache on
             # repeat queries — the staging tax is paid once per version.
-            out = np.asarray(
-                run_x64(
-                    lambda: to_host(
-                        reduce_fn(
-                            dcache.device_put_cached(stacked),
-                            dcache.device_put_cached(gid_p),
-                        )
-                    )
+            with obs_trace.span("agg.channels"):
+                args = run_x64(
+                    lambda: (dcache.device_put_cached(stacked), dcache.device_put_cached(gid_p))
                 )
-            )
+        with obs_trace.span("agg.reduce", path=path):
+            out = np.asarray(run_x64(lambda: to_host(reduce_fn(*args))))
     out = out[:, :num_groups]
     results = out[0::2]
     counts = out[1::2]
@@ -607,19 +635,23 @@ def _try_pallas_reduce(
     def build_vals32() -> np.ndarray:
         return stacked.astype(np.float32)
 
-    if dcache.is_stable(gid_p):
-        gid2d = dcache.derived(("gid2d", id(gid_p)), (gid_p,), build_gid2d)
-    else:
-        gid2d = build_gid2d()
-    if dcache.is_stable(stacked):
-        vals32 = dcache.derived(("aggstack32", id(stacked)), (stacked,), build_vals32)
-    else:
-        vals32 = build_vals32()
+    with obs_trace.span("agg.channels"):
+        if dcache.is_stable(gid_p):
+            gid2d = dcache.derived(("gid2d", id(gid_p)), (gid_p,), build_gid2d)
+        else:
+            gid2d = build_gid2d()
+        if dcache.is_stable(stacked):
+            vals32 = dcache.derived(("aggstack32", id(stacked)), (stacked,), build_vals32)
+        else:
+            vals32 = build_vals32()
+        args = run_x64(
+            lambda: (dcache.device_put_cached(gid2d), dcache.device_put_cached(vals32))
+        )
     run = _make_pallas_segment_reduce(fns, k_pad, tile, interpret)
-    out = run_x64(
-        lambda: to_host(run(dcache.device_put_cached(gid2d), dcache.device_put_cached(vals32)))
-    )
+    with obs_trace.span("agg.reduce", path="fused"):
+        out = run_x64(lambda: to_host(run(*args)))
     stats.increment("device.kernel.fused")
+    stats.increment("device.kernel.segment_reduce_fused")
     return np.asarray(out, np.float64)
 
 

@@ -285,11 +285,15 @@ def translate_predicate(table: ColumnTable, e: Expr) -> Expr:
                 d = table.dictionaries[table.schema.field(child.name).name]
                 for v in e.values:
                     pos = int(np.searchsorted(d, v))
-                    if pos < len(d) and d[pos] == v:
-                        codes.append(pos)
-                return _codes_runs_expr(
-                    child, np.sort(np.unique(codes)) if codes else np.array([]), len(d)
-                )
+                    codes.append(pos if pos < len(d) and d[pos] == v else -1)
+                if 0 < len(codes) <= _MAX_CODE_RUNS:
+                    # One equality per listed value (-1, never a real code,
+                    # for a value the dictionary lacks): the compiled mask
+                    # depends on the list's length, not on which values it
+                    # holds or whether their codes are adjacent.
+                    return _or_chain([BinOp("eq", child, Lit(np.int32(c))) for c in codes])
+                hit = np.unique([c for c in codes if c >= 0])
+                return _codes_runs_expr(child, hit, len(d))
             return _or_chain([BinOp("eq", child, Lit(v)) for v in e.values])
         return e  # DatePart / arithmetic probes: host evaluation
     if isinstance(e, Like):

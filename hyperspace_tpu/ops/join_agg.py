@@ -151,12 +151,13 @@ _SEGMENT_REDUCE = {
 }
 
 
-def _dense_reduce(ws, gid, num_segments: int, channels: tuple):
+def _dense_reduce(ws, gid, num_segments: int, channels: tuple, per_bucket: bool = False):
     """[C, K] from the channel weights ws (each [B, Lp]) and group ids
     gid [B, Lp]: ``op over rows of where(gid == g, w[c], identity)``, a
     fori_loop over blocks of whole buckets (``blk`` divides B) that keeps
     each block's mask within ``_DENSE_TILE_ELEMS`` pairs. Ids outside
-    [0, K) fall in no group, as with the scatter."""
+    [0, K) fall in no group, as with the scatter. With `per_bucket`,
+    [C, B, K]: each bucket's rows reduced into its own K segments."""
     n_b, n_rows = gid.shape
     cap = max(1, _DENSE_TILE_ELEMS // max(n_rows * num_segments, 1))
     blk = max((d for d in range(1, min(n_b, cap) + 1) if n_b % d == 0), default=1)
@@ -168,12 +169,20 @@ def _dense_reduce(ws, gid, num_segments: int, channels: tuple):
             return jax.lax.dynamic_slice_in_dim(a, i * blk, blk)
 
         hit = block(gid) == seg  # [K, blk, Lp]
+        if per_bucket:
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    a, fold(jnp.where(hit, block(w), ident), axis=2).T, i * blk, 0
+                )
+                for a, w, (fold, _, ident) in zip(acc, ws, folds)
+            )
         return tuple(
             combine(a, fold(jnp.where(hit, block(w), ident), axis=(1, 2)))
             for a, w, (fold, combine, ident) in zip(acc, ws, folds)
         )
 
-    init = tuple(jnp.full(num_segments, ident, w.dtype) for w, (_, _, ident) in zip(ws, folds))
+    shape = (n_b, num_segments) if per_bucket else num_segments
+    init = tuple(jnp.full(shape, ident, w.dtype) for w, (_, _, ident) in zip(ws, folds))
     return jnp.stack(jax.lax.fori_loop(0, n_b // blk, step, init))
 
 
@@ -189,13 +198,20 @@ def _scatter_reduce(ws, gid, num_segments: int, channels: tuple):
     return jnp.stack([_FOLD[op][0](p, axis=0) for p, op in zip(per_bucket, ops)])
 
 
+_REDUCE = {
+    "dense": _dense_reduce,
+    "scatter": _scatter_reduce,
+    "bucket_dense": functools.partial(_dense_reduce, per_bucket=True),
+}
+
+
 def _reduce(ws, gid, num_segments: int, channels: tuple, reduce: str):
-    """Fold every bucket's channel weights into [C, num_segments]:
-    ``reduce`` 'dense' (:func:`_dense_reduce`) or 'scatter'."""
+    """Fold every bucket's channel weights: ``reduce`` 'dense' or
+    'scatter' into [C, num_segments] global groups, 'bucket_dense' into
+    [C, B, num_segments] groups local to each bucket (``gid`` then holds
+    bucket-local ids)."""
     with jax.named_scope("join_agg.segment_sum"):
-        if reduce == "dense":
-            return _dense_reduce(ws, gid, num_segments, channels)
-        return _scatter_reduce(ws, gid, num_segments, channels)
+        return _REDUCE[reduce](ws, gid, num_segments, channels)
 
 
 @functools.partial(jit, static_argnames=("num_segments", "channels", "reduce"))
@@ -237,6 +253,31 @@ def _fused_join_agg_bounds(
     return _reduce(ws, gid, num_segments, channels, reduce)
 
 
+def bucket_local_ids(gid: np.ndarray, num_groups: int):
+    """(local ids [B, Lp] int32, K_local, bucket [num_groups], slot
+    [num_groups]) when every group's rows lie in one bucket of the
+    padded group ids `gid` [B, Lp] (pads hold `num_groups`), else None.
+    Each group gets the rank of its id among its bucket's groups; pads
+    get the dead segment K_local - 1, past every bucket's groups."""
+    n_b = gid.shape[0]
+    real = gid < num_groups
+    rows_bucket = np.broadcast_to(np.arange(n_b, dtype=np.int64)[:, None], gid.shape)[real]
+    g = gid[real].astype(np.int64)
+    bucket = np.full(num_groups, -1, np.int64)
+    bucket[g] = rows_bucket
+    if (bucket < 0).any() or not np.array_equal(bucket[g], rows_bucket):
+        return None
+    per_bucket = np.bincount(bucket, minlength=n_b)
+    # Groups in (bucket, id) order are counted off from each bucket's start.
+    start = np.concatenate([[0], np.cumsum(per_bucket)[:-1]])
+    slot = np.empty(num_groups, np.int64)
+    slot[np.argsort(bucket, kind="stable")] = np.arange(num_groups) - np.repeat(start, per_bucket)
+    k_local = 1 << max(int(per_bucket.max(initial=0)).bit_length(), 1)  # >= max + 1
+    lid = np.full(gid.shape, k_local - 1, np.int32)
+    lid[real] = slot[g]
+    return lid, k_local, bucket, slot
+
+
 def fused_join_aggregate(
     pk: np.ndarray,
     sk: np.ndarray,
@@ -246,19 +287,34 @@ def fused_join_aggregate(
     num_groups: int,
     channels: tuple,
     fused: str = "off",
+    bucket_local: bool = False,
 ) -> np.ndarray:
     """Host wrapper: pads the group dimension (+1 dead segment for pads)
     and runs the fused device program on the persistent x64 worker thread
     (parallel/x64.py). Returns [C, num_groups] float64. `fused` = "auto"
     takes the Pallas run-bounds kernel where its shape rule admits the
     call (identical integer bounds, so identical results), and the lax
-    searchsorted otherwise."""
+    searchsorted otherwise. `bucket_local` says the group keys hold the
+    join key, so every group lies in one bucket: past the dense bound the
+    channels then reduce densely into each bucket's own groups (:func:
+    `bucket_local_ids`, checked on the ids) where no bucket holds more
+    than the bound, instead of a [B, K] scatter per channel over every
+    group."""
     from hyperspace_tpu.execution.device_cache import device_put_cached
     from hyperspace_tpu.ops.sortkeys import pallas_run_bounds
     from hyperspace_tpu.parallel.x64 import run_x64
 
     k_seg = 1 << max(int(num_groups).bit_length(), 1)  # >= num_groups+1
-    if k_seg <= _DENSE_MAX_SEGMENTS:
+    local = None
+    if k_seg > _DENSE_MAX_SEGMENTS and bucket_local:
+        local = bucket_local_ids(np.asarray(gid), num_groups)
+        if local is not None and local[1] > _DENSE_MAX_SEGMENTS:
+            local = None
+    if local is not None:
+        gid, k_seg, bucket, slot = local
+        reduce = "bucket_dense"
+        stats.increment("device.kernel.bucket_reduce")
+    elif k_seg <= _DENSE_MAX_SEGMENTS:
         reduce = "dense"
         stats.increment("device.kernel.dense_reduce")
     else:
@@ -297,4 +353,7 @@ def fused_join_aggregate(
             )
         return np.asarray(to_host(out))
 
-    return run_x64(call)[:, :num_groups]
+    out = run_x64(call)
+    if local is not None:
+        return out[:, bucket, slot]
+    return out[:, :num_groups]

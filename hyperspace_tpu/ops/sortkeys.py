@@ -282,6 +282,64 @@ def pallas_run_bounds(pk, sk):
     return out
 
 
+@functools.lru_cache(maxsize=8)
+def _make_slice_bounds(lo_side: str, hi_side: str):
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.compat import jit
+
+    def run(keys, rows, lo, hi):
+        with jax.named_scope("scan.slice_bounds"):
+            st = jax.vmap(lambda k: jnp.searchsorted(k, lo, side=lo_side))(keys)
+            en = jax.vmap(lambda k: jnp.searchsorted(k, hi, side=hi_side))(keys)
+            # Pads hold the dtype's largest value: clamp to the real rows.
+            return jnp.stack([jnp.minimum(st, rows), jnp.minimum(en, rows)], axis=1)
+
+    return jit(run, key="ops.sortkeys.slice_bounds")
+
+
+def device_slice_bounds(keys: list, bounds) -> np.ndarray | None:
+    """[F, 2] (start, end) of the rows of each sorted, null-free integer
+    key column `keys[f]` that lie within `bounds` (a KeyBounds: lo/hi
+    literals, None unbounded), as np.searchsorted would find them,
+    computed on the device in one call over a [F, L] padded key matrix
+    (identity-cached, so repeat scans of one index version upload it
+    once). None when a bound is not an integer literal."""
+    from hyperspace_tpu.compat import to_host
+    from hyperspace_tpu.execution import device_cache as dc
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    dtype = keys[0].dtype
+    info = np.iinfo(dtype)
+    lo, hi = bounds.lo, bounds.hi
+    if any(b is not None and not isinstance(b, (int, np.integer)) for b in (lo, hi)):
+        return None
+    if (lo is not None and lo > info.max) or (hi is not None and hi < info.min):
+        return np.zeros((len(keys), 2), np.int64)
+    lo_side = "right" if lo is not None and lo >= info.min and bounds.lo_strict else "left"
+    hi_side = "left" if hi is not None and hi <= info.max and bounds.hi_strict else "right"
+    lo = info.min if lo is None or lo < info.min else lo
+    hi = info.max if hi is None or hi > info.max else hi
+
+    def build() -> np.ndarray:
+        mat = np.full((len(keys), max(len(k) for k in keys)), info.max, dtype)
+        for i, k in enumerate(keys):
+            mat[i, : len(k)] = k
+        return mat
+
+    if all(dc.is_stable(k) for k in keys):
+        mat = dc.derived(("slicekeys", tuple(id(k) for k in keys)), tuple(keys), build)
+    else:
+        mat = build()
+    rows = np.array([len(k) for k in keys], np.int32)
+    run = _make_slice_bounds(lo_side, hi_side)
+    out = run_x64(lambda: to_host(run(
+        dc.device_put_cached(mat), rows, np.asarray(lo, dtype), np.asarray(hi, dtype)
+    )))
+    return np.asarray(out, np.int64)
+
+
 _SORT_BATCH = 8
 
 

@@ -82,6 +82,14 @@ Counter names in use:
   over all rows (ops/join_agg.py)
 - ``device.kernel.scatter_reduce``  device join-aggregates with too many
   groups for that, which reduce each channel by a segment scatter
+- ``device.kernel.bucket_reduce``  device join-aggregates with too many
+  groups for the dense reduction whose group keys hold the join key, so
+  each bucket's channels reduce into that bucket's own groups
+- ``device.kernel.segment_reduce_fused`` / ``_lax`` / ``_sharded``
+  device grouped aggregates (ops/aggregate.py) by the reduction they
+  took: the fused Pallas kernel, the jitted float64 lax segment reduce
+  on one device, or the mesh-sharded reduce with one collective per
+  channel
 - ``controller.ticks``  reconciliation steps the self-driving operations
   controller ran while armed (serve/controller.py,
   docs/fault_tolerance.md "self-driving operations")
@@ -188,6 +196,10 @@ KNOWN_COUNTERS = (
     "device.kernel.fallbacks",
     "device.kernel.dense_reduce",
     "device.kernel.scatter_reduce",
+    "device.kernel.bucket_reduce",
+    "device.kernel.segment_reduce_fused",
+    "device.kernel.segment_reduce_lax",
+    "device.kernel.segment_reduce_sharded",
     "controller.ticks",
     "controller.actuations",
     "controller.actuation_failures",

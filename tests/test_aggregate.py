@@ -1027,3 +1027,46 @@ def test_count_distinct_empty_input_counts_are_zero(tmp_path):
     ]))
     assert int(got.loc[0, "na"]) == 0
     assert got.loc[0, "rows"] is not None and int(got.loc[0, "rows"]) == 0
+
+
+@pytest.mark.parametrize(
+    "n,num_groups",
+    [(1, 1), (5_000, 7), (3 * 4096, 300), (40_000, 4095)],
+)
+def test_dense_segment_reduce_matches_scatter_and_numpy(n, num_groups):
+    """The accelerators' dense grouped reduction (sums, extrema, pads in
+    the dead segment) against the segment scatter and numpy."""
+    from hyperspace_tpu.ops.aggregate import (
+        _dense_segment_reduce,
+        _pad_const,
+        _pow2,
+        _segment_reduce_many,
+    )
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    rng = np.random.default_rng(n + num_groups)
+    n_pad, k_seg = _pow2(n), _pow2(num_groups + 1)
+    gid = np.full(n_pad, num_groups, np.int32)
+    gid[:n] = rng.integers(0, num_groups, n)
+    v = rng.normal(size=n) * 1e3
+    fns = ("sum", "sum", "min", "max")
+    vals = np.stack([
+        np.pad(v, (0, n_pad - n)),
+        np.pad(np.round(v), (0, n_pad - n)),
+        _pad_const(v, n_pad, "min"),
+        _pad_const(v, n_pad, "max"),
+    ])
+    dense, scatter = (
+        np.asarray(run_x64(lambda f=f: f(vals, gid, num_segments=k_seg, fns=fns)))
+        for f in (_dense_segment_reduce, _segment_reduce_many)
+    )
+    g = gid[:n]
+    # Integral sums and extrema agree bit for bit; float sums by order.
+    np.testing.assert_array_equal(dense[1:], scatter[1:])
+    np.testing.assert_allclose(dense[0], scatter[0], rtol=1e-12, atol=1e-9)
+    np.testing.assert_allclose(dense[0, :num_groups], np.bincount(g, v, num_groups),
+                               rtol=1e-12, atol=1e-9)
+    np.testing.assert_array_equal(dense[1, :num_groups], np.bincount(g, np.round(v), num_groups))
+    mins = np.full(num_groups, np.inf)
+    np.minimum.at(mins, g, v)
+    np.testing.assert_array_equal(dense[2, :num_groups], mins)

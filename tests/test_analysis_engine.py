@@ -1081,12 +1081,20 @@ class TestRepoProcessDomains:
             "ops.sortkeys.pallas_run_bounds",
             "ops.topk.pallas_tile",
         }
+        # The grouped aggregate also counts which reduction each call took.
+        path_counters = {
+            "ops.aggregate.pallas_segment_reduce": {
+                "device.kernel.segment_reduce_fused",
+                "device.kernel.segment_reduce_lax",
+                "device.kernel.segment_reduce_sharded",
+            },
+        }
         for name, lad in ladders.items():
             assert lad["proven"], name
             assert lad["gate"] and lad["swallow"] is None, name
             assert set(lad["counters"]) == {
                 "device.kernel.fused", "device.kernel.fallbacks",
-            }, name
+            } | path_counters.get(name, set()), name
         assert ladders["ops.topk.pallas_tile"]["witness"] == [
             "hyperspace_tpu.ops.topk.topk",
             "hyperspace_tpu.ops.topk._pallas_topk",

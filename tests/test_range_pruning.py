@@ -281,3 +281,46 @@ def test_float_key_with_nan_values_not_overincluded(tmp_path):
     session.enable_hyperspace()
     got = session.to_pandas(scan.filter(col("k") >= lit(2.0)))
     assert sorted(got["k"]) == [2.0, 3.0]  # NaN rows dropped by the mask
+
+
+@pytest.mark.parametrize(
+    "lo,lo_strict,hi,hi_strict",
+    [
+        (100, False, 150, True),
+        (100, True, 150, False),
+        (None, False, 120, False),
+        (120, True, None, False),
+        (-(2**40), False, 2**40, False),  # both beyond the key's int32 range
+        (2**40, False, None, False),  # above every key: empty runs
+        (None, False, -(2**40), True),  # below every key: empty runs
+    ],
+)
+def test_device_slice_bounds_match_searchsorted(lo, lo_strict, hi, hi_strict):
+    """The device venue's slice bounds equal the host's np.searchsorted
+    runs for every file, pads and out-of-range literals included."""
+    from hyperspace_tpu.execution.exec_common import KeyBounds
+    from hyperspace_tpu.ops.sortkeys import device_slice_bounds
+
+    rng = np.random.default_rng(5)
+    keys = [np.sort(rng.integers(50, 200, n)).astype(np.int32) for n in (1, 37, 200, 64)]
+    runs = device_slice_bounds(keys, KeyBounds(lo, lo_strict, hi, hi_strict))
+    for k, (st, en) in zip(keys, runs):
+        want_st = 0 if lo is None else np.searchsorted(k, lo, side="right" if lo_strict else "left")
+        want_en = len(k) if hi is None else np.searchsorted(k, hi, side="left" if hi_strict else "right")
+        if want_en <= want_st:  # no row in range: any empty run will do
+            assert en <= st
+        else:
+            assert (st, en) == (want_st, want_en)
+
+
+def test_exact_slice_on_the_device_venue_finds_its_runs_on_the_device(indexed):
+    session, scan, df = indexed
+    session.conf.set("hyperspace.filter.venue", "device")
+    lo, hi = 30_000, 31_000
+    got = session.to_pandas(scan.filter((col("k") >= lit(lo)) & (col("k") < lit(hi))))
+    node = next(n for n in session.last_physical_plan.walk() if n.op == "IndexRangeScan")
+    assert node.detail["kernel"] == (
+        "minmax-prune + device-searchsorted-slice (exact, mask skipped)"
+    )
+    exp = df[(df.k >= lo) & (df.k < hi)]
+    assert sorted(got["k"]) == sorted(exp["k"])

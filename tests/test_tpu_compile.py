@@ -117,6 +117,58 @@ def test_join_agg_dense_reduce_compiles_at_sf1_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < k * b * lp
 
 
+def test_join_agg_bucket_reduce_compiles_at_sf1_q3_shape(one_chip):
+    """TPC-H Q3's join-aggregate at SF1: about 0.7M orders grouped by
+    order key, 200 buckets of 4,096 padded orders rows against 32,768
+    lineitem rows, each bucket's groups reduced densely into 4,096 local
+    segments. The [B, K] accumulators stay far below one [B, 2^20]
+    float64 scatter accumulator of the global path (1.68 GB)."""
+    from hyperspace_tpu.ops.join_agg import _DENSE_MAX_SEGMENTS, _fused_join_agg_bounds
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    b, lp, ls, k = 200, 4096, 32768, 4096
+    assert k <= _DENSE_MAX_SEGMENTS
+    channels = (("star",), ("s", 0), ("s", 1))
+    i32 = [_shape(one_chip, s, jnp.int32) for s in ((b, lp), (b, ls), (b, lp), (b, lp))]
+    compiled = run_x64(
+        lambda: _fused_join_agg_bounds.lower(
+            *i32,
+            _shape(one_chip, (0, b, lp), jnp.float64),
+            _shape(one_chip, (2, b, ls), jnp.float64),
+            _shape(one_chip, (b, lp), jnp.int32),
+            num_segments=k,
+            channels=channels,
+            reduce="bucket_dense",
+        ).compile()
+    )
+    # No scatter instruction (source names in the metadata may say it).
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < b * (1 << 20) * 8
+
+
+def test_aggregate_dense_reduce_compiles_at_sf1_q1_shape(one_chip):
+    """TPC-H Q1's grouped aggregate at SF1: eight inputs and their
+    non-null counts over 8,388,608 padded lineitem rows into 8 segments,
+    by the dense reduction: no scatter, and temporaries under twice the
+    1.07 GB channel stack, where a [K, rows] float64 buffer per channel
+    would take 8.6 GB."""
+    from hyperspace_tpu.ops.aggregate import _dense_segment_reduce
+    from hyperspace_tpu.parallel.x64 import run_x64
+
+    n, k = 1 << 23, 8
+    fns = ("sum",) * 16
+    compiled = run_x64(
+        lambda: _dense_segment_reduce.lower(
+            _shape(one_chip, (len(fns), n), jnp.float64),
+            _shape(one_chip, (n,), jnp.int32),
+            num_segments=k,
+            fns=fns,
+        ).compile()
+    )
+    assert " scatter(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * len(fns) * n * 8
+
+
 def test_topk_tile_kernel_compiles(one_chip):
     from hyperspace_tpu.ops.topk import _QBLOCK, _TILE, _make_tile_kernel
 
