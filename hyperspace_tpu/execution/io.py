@@ -10,17 +10,26 @@ every footer.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import json
+import os
+import stat
+import time
+from collections.abc import Mapping
 from pathlib import Path
+from types import MappingProxyType
 
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
 
 from hyperspace_tpu import stats
+from hyperspace_tpu.dataset import list_data_files
 from hyperspace_tpu.exceptions import HyperspaceError, IndexCorruptionError
 from hyperspace_tpu.execution.table import ColumnTable
 from hyperspace_tpu.faults import fault_point
+from hyperspace_tpu.metadata.log_entry import FileInfo
 from hyperspace_tpu.obs import metrics as obs_metrics
 from hyperspace_tpu.obs import trace as obs_trace
 from hyperspace_tpu.schema import Schema
@@ -621,28 +630,130 @@ def read_manifest(version_dir: Path) -> dict | None:
         ) from e
 
 
-_manifest_cache: "dict[str, tuple[int, dict | None]]" = {}
+_manifest_cache: "dict[str, tuple[tuple[int, int, int], dict | None]]" = {}
 _manifest_lock = threading.Lock()
 
 
 def read_manifest_cached(version_dir: Path) -> dict | None:
-    """read_manifest through an mtime-validated cache (manifests are
+    """read_manifest through a stat-validated cache (manifests are
     immutable per version, but refresh can rewrite a dir's manifest)."""
-    import os
-
     mp = Path(version_dir) / MANIFEST_NAME
     try:
-        mt = os.stat(mp).st_mtime_ns
+        st = os.stat(mp)
     except OSError:
         return None
+    stamp = (st.st_ino, st.st_size, st.st_mtime_ns)
     with _manifest_lock:
         cached = _manifest_cache.get(str(mp))
-    if cached is not None and cached[0] == mt:
+    if cached is not None and cached[0] == stamp:
         return cached[1]
     m = read_manifest(version_dir)
     with _manifest_lock:
-        _manifest_cache[str(mp)] = (mt, m)
+        _manifest_cache[str(mp)] = (stamp, m)
     return m
+
+
+# -- version-directory listing cache ------------------------------------------
+# A committed index version directory never changes: refresh, optimize
+# and ingest write a new v__=N, builds write flat bucket files before
+# the commit. So each rewrite's listing of it is served from here, the
+# entry checked by one stat of the directory: a file added, removed or
+# renamed moves the directory's mtime and ctime. Keyed by the
+# directory's absolute path; guarded by _listing_lock (HSL008/HSL013),
+# the listing itself runs unlocked.
+_listing_cache: "collections.OrderedDict[str, _Listing]" = collections.OrderedDict()
+_listing_lock = threading.Lock()
+_LISTING_MAX = 256
+# A listing made within this long of its directory's mtime is not
+# cached: a change in the same timestamp tick would leave the times as
+# they were (git's racy-index rule).
+_RACY_NS = 1_000_000_000
+
+
+@dataclasses.dataclass(frozen=True)
+class _Listing:
+    stamp: tuple[int, int, int]  # the directory's (ino, mtime_ns, ctime_ns)
+    dir: str  # the path as the caller spelt it; each row's path starts with it
+    rows: tuple[tuple[str, int, int], ...]  # list_data_files' (path, size, mtime_ns)
+    mtimes: Mapping[str, int]
+
+
+def _stamp(st: os.stat_result) -> tuple[int, int, int]:
+    return (st.st_ino, st.st_mtime_ns, st.st_ctime_ns)
+
+
+def _valid_listing(d: Path, st: os.stat_result) -> _Listing | None:
+    """The cached listing of `d` if the directory's stat `st` still
+    validates it."""
+    key = os.path.abspath(d)
+    with _listing_lock:
+        ent = _listing_cache.get(key)
+        if ent is not None:
+            _listing_cache.move_to_end(key)
+    if ent is None or ent.stamp != _stamp(st) or ent.dir != str(d):
+        return None
+    return ent
+
+
+def _list_flat(d: Path) -> list[tuple[str, int, int]] | None:
+    """list_data_files(d) rows for a directory with no subdirectory;
+    None for any other layout (rglob would recurse, and one stat of `d`
+    cannot see changes below it)."""
+    rows = []
+    with os.scandir(d) as it:
+        for e in it:
+            if e.is_dir():
+                return None
+            if not e.name.endswith(".parquet") or e.name.startswith((".", "_")):
+                continue
+            st = e.stat()
+            rows.append((str(d / e.name), st.st_size, st.st_mtime_ns))
+    rows.sort()
+    return rows
+
+
+def list_version_dir(version_dir: Path) -> tuple[list[FileInfo], bool]:
+    """``list_data_files(version_dir)`` through the listing cache, and
+    whether the cache served it. A missing or unreadable directory, or
+    one with subdirectories, is listed as list_data_files lists it and
+    never cached."""
+    d = Path(version_dir)
+    try:
+        st = os.stat(d)
+    except OSError:
+        return list_data_files(d), False
+    ent = _valid_listing(d, st)
+    if ent is not None:
+        return [FileInfo(*r) for r in ent.rows], True
+    listed_at = time.time_ns()
+    try:
+        rows = _list_flat(d) if stat.S_ISDIR(st.st_mode) else None
+    except OSError:
+        rows = None
+    if rows is None:
+        return list_data_files(d), False
+    if st.st_mtime_ns <= listed_at - _RACY_NS:
+        key = os.path.abspath(d)
+        ent = _Listing(_stamp(st), str(d), tuple(rows), MappingProxyType({p: m for p, _, m in rows}))
+        with _listing_lock:
+            _listing_cache[key] = ent
+            _listing_cache.move_to_end(key)
+            while len(_listing_cache) > _LISTING_MAX:
+                _listing_cache.popitem(last=False)
+    return [FileInfo(*r) for r in rows], False
+
+
+def cached_file_mtimes(version_dir: Path) -> Mapping[str, int] | None:
+    """{path: mtime_ns} of the cached listing of `version_dir` when one
+    stat of the directory still validates it; None otherwise. Never
+    lists the directory."""
+    d = Path(version_dir)
+    try:
+        st = os.stat(d)
+    except OSError:
+        return None
+    ent = _valid_listing(d, st)
+    return None if ent is None else ent.mtimes
 
 
 def file_key_stats(files: list[str]) -> dict[str, list | None]:

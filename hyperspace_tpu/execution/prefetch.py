@@ -124,6 +124,14 @@ def prefetch_plan(plan) -> int:
         return 0
     import os
 
+    # A file's mtime comes from its version directory's cached listing
+    # when one stat of the directory still validates it (the rewrite
+    # has just listed it); any other file is stat'ed on its own.
+    listed = {}
+    for path, _, _ in jobs:
+        d = os.path.dirname(path)
+        if d not in listed:
+            listed[d] = hio.cached_file_mtimes(d)
     submitted = 0
     pool = _get_pool()
     with _lock:
@@ -135,7 +143,10 @@ def prefetch_plan(plan) -> int:
                 # this file's job — the advisory contract: the query
                 # path re-reads with full retry/typed handling anyway.
                 fault_point("prefetch.issue", path)
-                mt = os.stat(path).st_mtime_ns
+                known = listed[os.path.dirname(path)]
+                mt = known.get(path) if known is not None else None
+                if mt is None:
+                    mt = os.stat(path).st_mtime_ns
             except OSError:
                 _MET_ERRORS.inc()
                 continue

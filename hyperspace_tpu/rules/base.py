@@ -14,15 +14,22 @@ import dataclasses
 import logging
 from pathlib import Path
 
-from hyperspace_tpu.dataset import list_data_files
 from hyperspace_tpu.execution import io as hio
 from hyperspace_tpu.metadata.log_entry import IndexLogEntry
+from hyperspace_tpu.obs import metrics as obs_metrics
 from hyperspace_tpu.obs import trace as obs_trace
 from hyperspace_tpu.plan.nodes import LogicalPlan, Scan
 from hyperspace_tpu.schema import Schema
 from hyperspace_tpu.signature import create_signature_provider
 
 logger = logging.getLogger("hyperspace_tpu")
+
+_MET_LISTING_HITS = obs_metrics.counter(
+    "plan.index_files.cache_hits", "index version-directory listings served from the listing cache"
+)
+_MET_LISTING_MISSES = obs_metrics.counter(
+    "plan.index_files.cache_misses", "index version-directory listings read from the directory"
+)
 
 
 class Rule:
@@ -60,15 +67,21 @@ def index_scan_for(entry: IndexLogEntry) -> Scan:
     BucketSpec (JoinIndexRule.scala:124-153). All version dirs listed in
     `content.directories` participate: bucket b's data is the union of the
     bucket-b files across dirs (base + incremental-refresh deltas). The
-    listing, stat and manifest read are the ``plan.index_files`` span."""
+    listing and manifest read are the ``plan.index_files`` span; both come
+    from stat-validated caches (``cached``: every directory's listing hit)."""
     root = Path(entry.content.root)
     schema = Schema.from_json(entry.derived_dataset.schema)
     files: list[str] = []
-    with obs_trace.span("plan.index_files", index=entry.name):
+    with obs_trace.span("plan.index_files", index=entry.name) as sp:
+        hits = 0
         for d in entry.content.directories:
-            files.extend(fi.path for fi in list_data_files(root / d))
-        first_dir = root / entry.content.directories[0]
-        manifest = hio.read_manifest(first_dir)
+            listing, hit = hio.list_version_dir(root / d)
+            files.extend(fi.path for fi in listing)
+            hits += hit
+        _MET_LISTING_HITS.inc(hits)
+        _MET_LISTING_MISSES.inc(len(entry.content.directories) - hits)
+        sp.set(cached=hits == len(entry.content.directories))
+        manifest = hio.read_manifest_cached(root / entry.content.directories[0])
     num_buckets = manifest["numBuckets"] if manifest else entry.derived_dataset.num_buckets
     return Scan(
         str(root),

@@ -154,6 +154,33 @@ def test_lookup_profile_holds_the_plan_spans(tpch):
     assert "device.sync" in names  # the filter mask's fetch
 
 
+def test_plan_spans_keep_their_names_when_the_listing_cache_hits(tpch):
+    """A committed version directory's listing is served from the cache
+    once out of the racy window; the rewrite's spans stay as named, and
+    ``plan.index_files`` says it hit."""
+    import os
+    import time
+
+    session, o, li = tpch
+    old = time.time_ns() - 10_000_000_000
+    for entry in session.manager.get_indexes():
+        for d in entry.content.directories:
+            os.utime(Path(entry.content.root) / d, ns=(old, old))
+    session.run(_lookup(li))
+    session.run(_lookup(li, 23))
+    prof = session.last_profile().trace
+    (opt,) = [c for c in prof["children"] if c["name"] == "plan.optimize"]
+    inside = _names(opt)
+    assert all(name in inside for name in PLAN_SPANS)
+    stack, cached = [opt], []
+    while stack:
+        node = stack.pop()
+        if node["name"] == "plan.index_files":
+            cached.append(node["attrs"]["cached"])
+        stack.extend(node.get("children", ()))
+    assert cached == [True]
+
+
 def test_fused_join_aggregate_waits_in_device_sync(tpch, tmp_path):
     session, o, li = tpch
     for key in (FILTER_VENUE, JOIN_VENUE, AGG_VENUE, SORT_VENUE):
