@@ -196,10 +196,10 @@ def smoke(out_path: str = "BENCH_PIPELINE.json") -> int:
             )
         ds = Dataset.parquet(root)
         mesh = make_mesh()
-        # Pin the host sort venue when the native kernel is available so
-        # the run is deterministic across probe outcomes (identical
-        # permutations either venue — the comparison is venue-neutral).
-        venue = "host" if native.available() else "auto"
+        # The host sort venue when the native kernel is available
+        # (identical permutations either venue — the comparison is
+        # venue-neutral).
+        venue = "host" if native.available() else "device"
         kw = dict(
             mesh=mesh, memory_budget_bytes=400_000, chunk_bytes=600_000, venue=venue
         )

@@ -52,7 +52,7 @@ KNOWN_STATIC_DOMAINS = {
     "fns": "reduction-kind tuple drawn from the AggSpec vocabulary",
     "iters": "Lloyd iteration count — a config-bounded small int",
     # enum parameters that select a compiled variant
-    "venue": ("auto", "device", "host"),
+    "venue": ("device", "host"),
     "fused": ("auto", "off"),
     "impl": ("auto", "pallas", "lax"),
     "reduce": ("dense", "scatter", "bucket_dense"),

@@ -14,7 +14,7 @@ import difflib
 import os
 from typing import Any
 
-from hyperspace_tpu.exceptions import UnknownConfigKeyError
+from hyperspace_tpu.exceptions import HyperspaceError, UnknownConfigKeyError
 
 # String keys (kept spiritually compatible with spark.hyperspace.* keys,
 # reference index/IndexConstants.scala:21-49).
@@ -30,14 +30,10 @@ INDEX_HYBRID_SCAN_MAX_APPENDED_RATIO = "hyperspace.index.hybridscan.maxAppendedR
 # from the budget).
 INDEX_BUILD_MEMORY_BUDGET = "hyperspace.index.build.memoryBudgetBytes"
 INDEX_BUILD_CHUNK_BYTES = "hyperspace.index.build.chunkBytes"
-# Materialized-join execution venue: "auto" picks the host-native merge
-# kernel when measured device->host bandwidth is below joinVenueMinMbps
-# (the match pairs land on host either way; over a slow link the
-# readback dominates), else the device kernel. "device"/"host" force it.
+# Operator venues (join, build, filter, agg, sort): "device" (the
+# default) or "host" — the host kernels are the parity reference and the
+# explicit choice; any other value raises at set().
 JOIN_VENUE = "hyperspace.join.venue"
-JOIN_VENUE_MIN_MBPS = "hyperspace.join.venueMinMbps"
-# Build sort venue: same auto/device/host scheme for the bucketize+sort
-# permutation (its only output lands on host).
 BUILD_VENUE = "hyperspace.build.venue"
 # Streaming-build pipeline (docs/architecture.md "build pipeline"): when
 # enabled, p1 overlaps decode/hash with pooled spill encode and spilled
@@ -61,16 +57,16 @@ SCAN_PREFETCH_ENABLED = "hyperspace.scan.prefetch.enabled"
 AGG_VENUE = "hyperspace.agg.venue"
 SORT_VENUE = "hyperspace.sort.venue"
 FILTER_VENUE = "hyperspace.filter.venue"
+VENUE_KEYS = (JOIN_VENUE, BUILD_VENUE, FILTER_VENUE, AGG_VENUE, SORT_VENUE)
 # Device data path (docs/architecture.md "device data path").
 # staging.enabled gates the Arrow→device zero-copy staging layer
 # (execution/staging.py): eligible fixed-width columns stay read-only
 # views over the Arrow buffers on the cache-destined read path instead
 # of owned host copies (process-global, like the faults/obs switches —
 # the decode path has no session handle). fusedKernels gates the Pallas
-# fused kernels (segment reduce, join-agg run bounds): "auto" engages
-# them on the device venue when the shape is eligible AND exactness is
-# provable, with the jitted lax path as the always-available fallback;
-# "off" keeps the lax path everywhere.
+# run-bounds kernel of the device join-aggregate: "auto" engages it when
+# the shape is eligible, with the jitted lax searchsorted as the
+# always-available fallback; "off" keeps the lax path everywhere.
 DEVICE_STAGING_ENABLED = "hyperspace.device.staging.enabled"
 DEVICE_FUSED_KERNELS = "hyperspace.device.fusedKernels"
 # Broadcast hash join: a non-aligned join whose smaller side has at most
@@ -290,8 +286,7 @@ DEFAULT_NUM_BUCKETS = 8
 DEFAULT_CACHE_EXPIRY_SECONDS = 300.0
 DEFAULT_HYBRID_SCAN_MAX_APPENDED_RATIO = 0.3
 DEFAULT_BUILD_MEMORY_BUDGET = 4 << 30
-DEFAULT_JOIN_VENUE = "auto"
-DEFAULT_JOIN_VENUE_MIN_MBPS = 200.0
+DEFAULT_VENUE = "device"
 DEFAULT_JOIN_BROADCAST_MAX_ROWS = 4_000_000
 DEFAULT_JOIN_REBUCKETIZE = "auto"
 # Lazy recovery leaves a transient log alone until it is at least this
@@ -387,19 +382,14 @@ KNOWN_KEYS: dict[str, ConfKey] = {
         "Row-group chunk size of the streaming build; 0 derives it from the "
         "budget."),
     JOIN_VENUE: ConfKey(
-        "`auto`",
-        "Where the materialized join's merge runs: `auto` probes device→host "
-        "bandwidth once and picks `host` (threaded C++ kernel) below the floor, "
-        "else `device`; `host`/`device` force it (unknown values raise)."),
-    JOIN_VENUE_MIN_MBPS: ConfKey(
-        "200",
-        "The link-speed floor shared by every `auto` venue choice (join, build, "
-        "aggregation, sort): below it, host paths win."),
+        "`device`",
+        "Where the materialized join's merge runs: `device` (the device "
+        "kernel) or `host` (threaded C++ kernel); other values raise."),
     BUILD_VENUE: ConfKey(
-        "`auto`",
-        "Where the build's bucketize+sort permutation is computed: threaded C++ "
-        "counting/key sort on host vs the device all_to_all exchange (a real "
-        "multi-device mesh keeps device in `auto`)."),
+        "`device`",
+        "Where the build's bucketize+sort permutation is computed: `device` "
+        "(all_to_all exchange + device sort) or `host` (threaded C++ "
+        "counting/key sort)."),
     BUILD_PIPELINE_ENABLED: ConfKey(
         "true",
         "Streaming-build pipeline: overlap p1 decode/hash with pooled spill "
@@ -433,18 +423,18 @@ KNOWN_KEYS: dict[str, ConfKey] = {
         "a background pool so the executor's cold reads start warm. Advisory "
         "— prefetch failures are counted, never surfaced."),
     AGG_VENUE: ConfKey(
-        "`auto`",
-        "Where the grouped segment-reduce runs: numpy bincount/reduceat on host "
-        "vs the device (mesh-sharded with psum/pmin/pmax collectives) segment "
-        "reduce."),
+        "`device`",
+        "Where the grouped segment-reduce runs: `device` (mesh-sharded with "
+        "psum/pmin/pmax collectives) or `host` (numpy bincount/reduceat)."),
     SORT_VENUE: ConfKey(
-        "`auto`",
-        "Where ORDER BY runs: numpy lexsort on host vs one device lax.sort over "
-        "32-bit lanes."),
+        "`device`",
+        "Where ORDER BY (and ORDER BY ... LIMIT's selection) runs: `device` "
+        "(lax.sort over 32-bit lanes) or `host` (numpy lexsort / partition "
+        "select)."),
     FILTER_VENUE: ConfKey(
-        "`auto`",
-        "Where predicate masks evaluate: exact numpy on host vs the fused XLA "
-        "computation (mesh-sharded rows on device)."),
+        "`device`",
+        "Where predicate masks evaluate: `device` (the fused XLA computation, "
+        "mesh-sharded rows) or `host` (exact numpy), CASE/IF masks included."),
     DEVICE_STAGING_ENABLED: ConfKey(
         "true",
         "Arrow→device zero-copy staging (execution/staging.py): fixed-width "
@@ -454,11 +444,11 @@ KNOWN_KEYS: dict[str, ConfKey] = {
         "Process-global; `false` restores the always-copy decode."),
     DEVICE_FUSED_KERNELS: ConfKey(
         "`auto`",
-        "Fused Pallas kernels on the device venue (segment reduce, join-agg "
-        "run bounds): `auto` engages them when the shape is eligible and "
-        "byte-identical results are provable, falling back to the jitted lax "
-        "path otherwise (`device.kernel.fused`/`device.kernel.fallbacks` "
-        "count the split); `off` keeps the lax path everywhere."),
+        "The Pallas run-bounds kernel of the device join-aggregate: `auto` "
+        "engages it when the shape is eligible, falling back to the jitted "
+        "lax searchsorted otherwise (`device.kernel.fused`/"
+        "`device.kernel.fallbacks` count the split); `off` keeps the lax "
+        "path everywhere. Results are byte-identical either way."),
     JOIN_BROADCAST_MAX_ROWS: ConfKey(
         "4,000,000",
         "A non-aligned join whose smaller side is under this row count (and ≥4x "
@@ -912,17 +902,16 @@ class HyperspaceConf:
     hybrid_scan_max_appended_ratio: float = DEFAULT_HYBRID_SCAN_MAX_APPENDED_RATIO
     build_memory_budget_bytes: int = DEFAULT_BUILD_MEMORY_BUDGET
     build_chunk_bytes: int = 0  # 0 = derived from the budget
-    join_venue: str = DEFAULT_JOIN_VENUE
-    join_venue_min_mbps: float = DEFAULT_JOIN_VENUE_MIN_MBPS
-    build_venue: str = DEFAULT_JOIN_VENUE
+    join_venue: str = DEFAULT_VENUE
+    build_venue: str = DEFAULT_VENUE
     build_pipeline_enabled: bool = True
     build_pipeline_max_inflight_bytes: int = 0  # 0 = derived from chunkBytes
     build_workers: int = 0  # 0 = in-process build (no worker pool)
     build_exchange_dir: str = ""  # "" = <dest>.exchange next to the version dir
     scan_prefetch_enabled: bool = True
-    agg_venue: str = DEFAULT_JOIN_VENUE
-    sort_venue: str = DEFAULT_JOIN_VENUE
-    filter_venue: str = DEFAULT_JOIN_VENUE
+    agg_venue: str = DEFAULT_VENUE
+    sort_venue: str = DEFAULT_VENUE
+    filter_venue: str = DEFAULT_VENUE
     device_fused_kernels: str = "auto"
     join_broadcast_max_rows: int = DEFAULT_JOIN_BROADCAST_MAX_ROWS
     join_rebucketize: str = DEFAULT_JOIN_REBUCKETIZE
@@ -997,6 +986,8 @@ class HyperspaceConf:
 
     def set(self, key: str, value: Any) -> None:
         check_known_key(key)
+        if key in VENUE_KEYS and value not in ("device", "host"):
+            raise HyperspaceError(f"unknown {key}={value!r} (device|host)")
         self.overrides[key] = value
         if key == INDEX_SYSTEM_PATH:
             self.system_path = str(value)
@@ -1014,8 +1005,6 @@ class HyperspaceConf:
             self.build_chunk_bytes = int(value)
         elif key == JOIN_VENUE:
             self.join_venue = str(value)
-        elif key == JOIN_VENUE_MIN_MBPS:
-            self.join_venue_min_mbps = float(value)
         elif key == BUILD_VENUE:
             self.build_venue = str(value)
         elif key == BUILD_PIPELINE_ENABLED:
@@ -1263,8 +1252,6 @@ class HyperspaceConf:
             return self.build_chunk_bytes
         if key == JOIN_VENUE:
             return self.join_venue
-        if key == JOIN_VENUE_MIN_MBPS:
-            return self.join_venue_min_mbps
         if key == BUILD_VENUE:
             return self.build_venue
         if key == BUILD_PIPELINE_ENABLED:

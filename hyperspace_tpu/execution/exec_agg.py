@@ -47,7 +47,7 @@ class AggregateMixin:
                         out.columns[f.name] = np.where(v, out.columns[f.name], 0)
                 return out
             return self._distinct_aggregate(plan, sorted(dcols))
-        venue = self._agg_venue()
+        venue = self._venue("agg")
         pushed = self._try_partial_agg_pushdown(plan)
         if isinstance(pushed, ColumnTable):
             return pushed
@@ -86,7 +86,6 @@ class AggregateMixin:
             # Identity-cached factorization: repeat aggregations over a
             # stable index version skip re-factorizing the keys.
             groups=_group_ids_cached(table, plan.group_by),
-            fused=self._fused_kernels(),
         )
 
     def _try_partial_agg_pushdown(self, plan: "Aggregate") -> "ColumnTable | Aggregate | None":
@@ -203,10 +202,9 @@ class AggregateMixin:
         from hyperspace_tpu.plan.nodes import Aggregate as _Agg
 
         pschema = _Agg(_TableLeaf(lt), pkeys, partial_specs).schema
-        venue = self._agg_venue()
+        venue = self._venue("agg")
         partial = aggregate_table(
             lt, pkeys, partial_specs, pschema, venue=venue, groups=(gid, k, rep),
-            fused=self._fused_kernels(),
         )
         self._phys(
             "PartialAggPushdown",
@@ -260,7 +258,7 @@ class AggregateMixin:
         from hyperspace_tpu.schema import Schema
 
         ct = self._execute(plan.child)
-        venue = self._agg_venue()
+        venue = self._venue("agg")
         gid, k, rep = _group_ids_cached(ct, plan.group_by)
         self._phys(
             "DistinctExpandAggregate",
@@ -276,7 +274,7 @@ class AggregateMixin:
         reg_fields += [out_schema.field(a.alias) for a in regular]
         base = aggregate_table(
             ct, plan.group_by, regular, Schema(tuple(reg_fields)),
-            venue=venue, groups=(gid, k, rep), fused=self._fused_kernels(),
+            venue=venue, groups=(gid, k, rep),
         )
         cols = dict(base.columns)
         dicts = dict(base.dictionaries)
@@ -330,7 +328,7 @@ class AggregateMixin:
         bt = self._execute(base)
 
         out_schema = plan.schema
-        venue = self._agg_venue()
+        venue = self._venue("agg")
         self._phys(
             "GroupingSetsReaggregate",
             sets=[list(s) for s in plan.grouping_sets],
@@ -366,7 +364,6 @@ class AggregateMixin:
             sub = aggregate_table(
                 bt, list(s), specs2, Schema(tuple(fields)), venue=venue,
                 groups=None if prefix_groups is None else prefix_groups.get(len(s)),
-                fused=self._fused_kernels(),
             )
 
             def agg_col(f, spec, cols, dicts, validity, sub=sub):

@@ -79,7 +79,7 @@ def _hash_fields_compatible(a, b) -> bool:
     return True
 
 
-def _filter_side(side: SideData, predicate, mesh, venue: str = "auto") -> SideData:
+def _filter_side(side: SideData, predicate, mesh, venue: str = "device") -> SideData:
     """Apply a side-local filter to bucket-grouped data, recomputing the
     bucket offsets over the surviving rows (grouping and within-bucket
     order are preserved — a filtered subsequence stays sorted)."""

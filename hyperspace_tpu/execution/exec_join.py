@@ -47,7 +47,7 @@ class JoinMixin:
             # grouping (per-bucket counts changed).
             before = out.num_rows
             mask = eval_predicate_mask(
-                out, plan.condition, mesh=self.mesh, venue=self._filter_venue()
+                out, plan.condition, mesh=self.mesh, venue=self._venue("filter")
             )
             out = out.filter_mask(mask)
             self._phys(residual_condition=True, residual_rows_dropped=before - out.num_rows)
@@ -112,7 +112,7 @@ class JoinMixin:
                 plan, lt.select(lkeep), rt.select(rkeep), lidx, ridx, schema=sub_schema
             )
             pmask = eval_predicate_mask(
-                pairs, plan.condition, mesh=self.mesh, venue=self._filter_venue()
+                pairs, plan.condition, mesh=self.mesh, venue=self._venue("filter")
             )
             matched = np.zeros(lt.num_rows, dtype=bool)
             matched[lidx[pmask]] = True
@@ -126,7 +126,7 @@ class JoinMixin:
             # is no match, so its rows fall through to the null-extended
             # unmatched parts below (computed from the SURVIVING pairs).
             pmask = eval_predicate_mask(
-                inner, plan.condition, mesh=self.mesh, venue=self._filter_venue()
+                inner, plan.condition, mesh=self.mesh, venue=self._venue("filter")
             )
             inner = inner.filter_mask(pmask)
             lidx, ridx = lidx[pmask], ridx[pmask]
@@ -220,9 +220,7 @@ class JoinMixin:
         # Non-aligned sides re-group through the fused bucket+key device
         # sort when the sort venue allows (host np.lexsort otherwise —
         # identical stable permutation either way).
-        regroup_venue = self._venue(
-            "sort_venue", "hyperspace.sort.venue", False, needs_native=False
-        )
+        regroup_venue = self._venue("sort")
         lcodes, lperm = _bucket_sorted_codes(lcodes, lside, venue=regroup_venue)
         rcodes, rperm = _bucket_sorted_codes(rcodes, rside, venue=regroup_venue)
         b = len(lside.offsets) - 1
@@ -232,7 +230,7 @@ class JoinMixin:
         if (
             lcodes.dtype == np.int32
             and rcodes.dtype == np.int32
-            and self._join_venue() == "host"
+            and self._venue("join") == "host"
         ):
             from hyperspace_tpu import native
 
@@ -241,8 +239,7 @@ class JoinMixin:
             )
         if host_res is not None:
             # Host venue: exact bucket-parallel C++ merge over the already
-            # host-resident sorted runs — no device round-trip (the match
-            # pairs land on host either way; see parallel/bandwidth.py).
+            # host-resident sorted runs — no device round-trip.
             lidx, ridx, totals = host_res
             self.stats["join_kernel"] = "host-native-merge"
         else:

@@ -95,9 +95,7 @@ class FusedJoinAggMixin:
         )
         codes = {}
         perms = {}
-        regroup_venue = self._venue(
-            "sort_venue", "hyperspace.sort.venue", False, needs_native=False
-        )
+        regroup_venue = self._venue("sort")
         codes["left"], perms["left"] = _bucket_sorted_codes(lc0, data["left"], venue=regroup_venue)
         codes["right"], perms["right"] = _bucket_sorted_codes(rc0, data["right"], venue=regroup_venue)
         secondary = "right" if primary == "left" else "left"
@@ -118,7 +116,7 @@ class FusedJoinAggMixin:
 
         host_res = None
         if (
-            self._join_venue() == "host"
+            self._venue("join") == "host"
             and codes[primary].dtype == np.int32
             and codes[secondary].dtype == np.int32
         ):

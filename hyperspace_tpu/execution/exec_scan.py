@@ -173,7 +173,7 @@ class ScanFilterMixin:
         # Per-OPERATOR pruning evidence: deltas of the query-cumulative
         # counters from this frame's start.
         fp0, rp0 = self.stats["files_pruned"], self.stats["rows_pruned"]
-        mask_venue = self._filter_venue()
+        mask_venue = self._venue("filter")
         mask_kernel = "host-mask" if mask_venue == "host" else "fused-xla-mask"
         if isinstance(child, Scan) and child.bucket_spec is not None:
             pruned = self._prune_bucket_files(child, plan.predicate)

@@ -352,7 +352,7 @@ class JoinSidesMixin:
                 h = np.where(valid, h, NULL_HASH)
             hs.append(h)
         bucket = np.asarray(bucket_ids(combine_hashes(hs, np), num_buckets, np), dtype=np.int32)
-        venue = self._join_venue()
+        venue = self._venue("join")
         kernel = None
         if venue == "device":
             import jax.numpy as jnp
@@ -459,7 +459,7 @@ class JoinSidesMixin:
         else:
             out = SideData(base, offsets, sorted_within, hash_fields=hf)
         if side.predicate is not None:
-            out = _filter_side(out, side.predicate, self.mesh, self._filter_venue())
+            out = _filter_side(out, side.predicate, self.mesh, self._venue("filter"))
         return out
 
     def _aligned_join(

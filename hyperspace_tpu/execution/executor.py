@@ -30,6 +30,8 @@ import numpy as np
 
 from hyperspace_tpu.obs import trace as obs_trace
 
+from hyperspace_tpu import native
+from hyperspace_tpu.config import DEFAULT_VENUE
 from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.execution import io as hio
 from hyperspace_tpu.execution.build_exchange import compute_row_hashes, hash_scalar_key
@@ -334,17 +336,6 @@ class Executor(
         out = ColumnTable.concat(parts) if len(parts) > 1 else parts[0]
         return out.take(np.arange(min(n, out.num_rows)))
 
-    def _join_venue(self) -> str:
-        """auto: host when the measured device→host link is slower than
-        the configured floor (a slow link) AND the native library
-        built; the pairs land on host either way."""
-        # Auto with a mesh keeps the distributed device kernel (the
-        # query-plane sharding is the point); a forced "host" wins — the
-        # host kernel is bucket-parallel too.
-        return self._venue(
-            "join_venue", "hyperspace.join.venue", self.mesh is not None, needs_native=True
-        )
-
     def _phys(self, op: str | None = None, **detail) -> None:
         """Annotate the operator currently executing."""
         if self._cur_phys is None:
@@ -355,37 +346,20 @@ class Executor(
 
     # -- aggregate / sort -------------------------------------------------
 
-    def _venue(self, conf_attr: str, what: str, prefer_device: bool, needs_native: bool) -> str:
-        """One pick_venue wrapper: conf defaults and the shared link floor
-        live here instead of at every venue-choosing call site."""
-        from hyperspace_tpu.parallel.bandwidth import pick_venue
-
-        return pick_venue(
-            getattr(self.conf, conf_attr) if self.conf is not None else "auto",
-            self.conf.join_venue_min_mbps if self.conf is not None else 200.0,
-            prefer_device=prefer_device,
-            what=what,
-            needs_native=needs_native,
-        )
-
-    def _filter_venue(self) -> str:
-        """Mask venue: host numpy below the link floor (the mask and the
-        columns are host-resident); device (mesh-sharded) otherwise."""
-        return self._venue("filter_venue", "hyperspace.filter.venue",
-                           self.mesh is not None, needs_native=False)
-
-    def _agg_venue(self) -> str:
-        """Where the segment reduce runs. The inputs are host-resident and
-        the [A, K] result is tiny, so below the link floor the numpy
-        bincount/reduceat path beats uploading every channel (and avoids
-        emulated f64 on chips without native double support)."""
-        return self._venue("agg_venue", "hyperspace.agg.venue", False, needs_native=False)
+    def _venue(self, op: str) -> str:
+        """The session's `hyperspace.<op>.venue` for op in join, filter,
+        agg, sort: "device", or "host" — the parity reference and the
+        explicit choice. The host join merge is the native library's, so
+        asking for it without the library raises."""
+        venue = getattr(self.conf, f"{op}_venue") if self.conf is not None else DEFAULT_VENUE
+        if venue == "host" and op == "join":
+            native.require("hyperspace.join.venue")
+        return venue
 
     def _fused_kernels(self) -> str:
-        """Fused Pallas kernel gate for the device venue ("auto"/"off",
-        `hyperspace.device.fusedKernels`): auto engages the fused
-        segment-reduce / run-bounds kernels when the shape is eligible
-        and byte-identity is provable; the jitted lax path is the
+        """Gate of the join-aggregate's Pallas run-bounds kernel
+        ("auto"/"off", `hyperspace.device.fusedKernels`): auto engages it
+        when the shape is eligible; the jitted lax searchsorted is the
         always-available fallback (docs/architecture.md "device data
         path")."""
         return self.conf.device_fused_kernels if self.conf is not None else "auto"
@@ -419,27 +393,11 @@ class Executor(
         lu = lanes_as_unsigned(lanes[:2])
         from hyperspace_tpu.parallel.mesh import make_mesh, mesh_size
 
-        from hyperspace_tpu.parallel.bandwidth import pick_venue
-
         sharded = self.mesh is not None and mesh_size(self.mesh) > 1
-        # Venue-gated like every other operator: auto prefers the
-        # distributed kernel on a real mesh (the query-plane sharding is
-        # the point); the host venue keeps the partition select. On one
-        # device auto stays on the host select whatever the link reads
-        # (the device select's full-length sort costs a ~50 s compile on
-        # v5e for a sub-second query); only an explicit device venue
-        # (conf or HYPERSPACE_VENUE) selects on that device.
-        if sharded:
-            venue = self._venue("sort_venue", "hyperspace.sort.venue", True, needs_native=False)
-        else:
-            venue = pick_venue(
-                self.conf.sort_venue if self.conf is not None else "auto",
-                float("inf"),
-                prefer_device=False,
-                what="hyperspace.sort.venue",
-                needs_native=False,
-            )
-        if venue == "device":
+        # The sort venue decides, as for every other operator: the device
+        # selects across a real mesh or on its one device; the host
+        # venue keeps the partition select.
+        if self._venue("sort") == "device":
             # Per-device first-n + one threshold broadcast; on a mesh
             # the ORDER BY participates in every device.
             from hyperspace_tpu.ops.sortkeys import distributed_top_n_candidates
@@ -473,7 +431,7 @@ class Executor(
 
     def _sort(self, plan: "Sort") -> ColumnTable:
         table = self._execute(plan.child)
-        venue = self._venue("sort_venue", "hyperspace.sort.venue", False, needs_native=False)
+        venue = self._venue("sort")
         self._phys(f"{venue.capitalize()}Sort", keys=[c for c, _ in plan.by], venue=venue)
         return self._sorted_table(table, plan, venue)
 
@@ -488,11 +446,8 @@ class Executor(
         if table.num_rows <= 1:
             return table
         if venue is None:
-            venue = self._venue("sort_venue", "hyperspace.sort.venue", False, needs_native=False)
+            venue = self._venue("sort")
         if venue == "host":
-            # ORDER BY output must land on host; below the link floor a
-            # numpy lexsort beats the device round-trip (latency-bound
-            # for the typical small post-aggregation result).
             return table.take(lexsort_lanes(order_lanes(table, plan.by)))
         return table.take(device_order_perm(table, plan.by))
 

@@ -121,7 +121,6 @@ class HyperspaceSession:
                             memory_budget_bytes=self.conf.build_memory_budget_bytes,
                             chunk_bytes=self.conf.build_chunk_bytes or None,
                             venue=self.conf.build_venue,
-                            venue_min_mbps=self.conf.join_venue_min_mbps,
                             pipeline_enabled=self.conf.build_pipeline_enabled,
                             pipeline_max_inflight_bytes=self.conf.build_pipeline_max_inflight_bytes,
                             workers=self.conf.build_workers,

@@ -1,8 +1,8 @@
 """Loader for the native host-runtime kernels (hashing.cpp).
 
 Compiles the committed C++ on first use with g++ (cached as a .so keyed
-by source hash under <repo>/.native_cache, listed in .gitignore) and
-binds it via ctypes — no pybind11 dependency. Every caller falls back to the numpy implementation
+by source hash and host CPU under <repo>/.native_cache, listed in
+.gitignore) and binds it via ctypes — no pybind11 dependency. Every caller falls back to the numpy implementation
 when the toolchain or the build is unavailable, so this module is a pure
 accelerator: `available()` reports which path is active.
 """
@@ -12,10 +12,13 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 from pathlib import Path
 
 import numpy as np
+
+from hyperspace_tpu.exceptions import HyperspaceError
 
 _SRC = Path(__file__).with_name("hashing.cpp")
 
@@ -28,10 +31,25 @@ def _cache_dir() -> Path:
     return Path(root) if root else _SRC.parents[2] / ".native_cache"
 
 
+def _cpu_identity(cpuinfo: Path = Path("/proc/cpuinfo")) -> str:
+    """The machine and instruction-set flags of the host CPU. The library
+    is built with -march=native, so a copy built on another CPU can die
+    with SIGILL here. With cpuinfo unreadable the machine alone keys it."""
+    try:
+        text = cpuinfo.read_text()
+    except OSError:
+        text = ""
+    flags = next((ln for ln in text.splitlines() if ln.startswith("flags")), "")
+    return f"{platform.machine()}-{hashlib.sha256(flags.encode()).hexdigest()[:16]}"
+
+
+def _library_path(cpu: str) -> Path:
+    tag = hashlib.sha256(_SRC.read_bytes() + cpu.encode()).hexdigest()[:16]
+    return _cache_dir() / f"libhs_native_{tag}.so"
+
+
 def _build() -> Path | None:
-    src = _SRC.read_bytes()
-    tag = hashlib.sha256(src).hexdigest()[:16]
-    out = _cache_dir() / f"libhs_native_{tag}.so"
+    out = _library_path(_cpu_identity())
     if out.exists():
         return out
     out.parent.mkdir(parents=True, exist_ok=True)
@@ -95,6 +113,16 @@ def _load() -> ctypes.CDLL | None:
 
 def available() -> bool:
     return _load() is not None
+
+
+def require(what: str) -> None:
+    """Raise unless the library is loaded: `what`=host names a host
+    kernel that exists only here."""
+    if not available():
+        raise HyperspaceError(
+            f"{what}=host requires the native library (g++ build failed "
+            "or unavailable); use device"
+        )
 
 
 # ---- typed wrappers (None ⇒ caller uses the numpy path) --------------------

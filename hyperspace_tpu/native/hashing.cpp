@@ -275,9 +275,8 @@ void hs_sort_range(int64_t* perm, int64_t count, const uint32_t* lanes,
 // codes sorted within each bucket (the index file layout). Over a slow
 // device->host link the readback of the match pairs dominates the whole
 // join; the pairs land on host either way, and the sorted runs are already
-// host-resident, so an exact two-pass merge here beats the device round-trip
-// whenever the link is slow (executor._join_venue decides by measured
-// bandwidth).
+// host-resident, so an exact two-pass merge here skips the device round-trip
+// (hyperspace.join.venue=host selects it).
 
 // Pass 1: counts[b] = number of matches in bucket b.
 void hs_mj_count(const int32_t* lk, const int64_t* lofs, const int32_t* rk,

@@ -9,7 +9,6 @@ from hyperspace_tpu.ops.hashing import bucket_ids, combine_hashes, hash_int_colu
 #: stale entries are findings, so this tuple is provably the complete
 #: kernel inventory.
 KNOWN_KERNELS = (
-    "ops.aggregate.pallas_segment_reduce",
     "ops.sortkeys.pallas_run_bounds",
     "ops.topk.pallas_tile",
 )

@@ -15,19 +15,6 @@ DEVICE_CACHE — a repeat aggregation over the same index version costs
 one kernel launch plus a [A, K] readback, not a re-staging of every
 channel (BENCH_VENUES group_agg was 1.06x warm-over-cold before this).
 
-Fused kernel: when the group count is small enough for the whole [C, K]
-accumulator to live in VMEM, ALL channels reduce in ONE tiled Pallas
-program (generalizing the ops/topk.py tiling — grid over row tiles, the
-revisited output block accumulates across sequential grid steps). The
-kernel reduces in float32 (Mosaic has no 64-bit element types), so it
-only engages when byte-identical results are PROVABLE — extremum
-channels whose values are all float32-representable, sum channels only
-when every value is integral and the absolute sum is at most 2^24 —
-because its within-tile reduction order differs from the sequential
-host bincount. Everything else takes the always-available jitted lax
-path; `device.kernel.fused` / `device.kernel.fallbacks` count the
-split, `hyperspace.device.fusedKernels` = off disables it.
-
 SQL semantics: null inputs are ignored by sum/min/max/mean and count(col);
 count(*) counts rows; a group whose inputs are all null yields NULL
 (validity mask); null group keys form their own group.
@@ -55,84 +42,6 @@ from hyperspace_tpu.schema import Schema
 
 def _pow2(n: int) -> int:
     return 1 << max(int(n - 1).bit_length(), 0) if n > 1 else 1
-
-
-# -- fused Pallas segment reduce ---------------------------------------------
-# Row-tile size of the fused kernel (grid dimension), and the largest
-# padded segment count whose [C, K] accumulator stays comfortably in
-# VMEM alongside a (tile, K) one-hot block.
-_PALLAS_SEG_TILE = 256
-_PALLAS_MAX_SEGMENTS = 2048
-# Interpret mode (CPU tests) materializes every (tile, K) block in
-# numpy: bound the total work so the fused path never engages on shapes
-# where the python-level grid loop would dominate.
-_PALLAS_INTERPRET_WORK = 1 << 24
-# The kernel reduces in float32 (Mosaic has no 64-bit element types).
-# Every partial sum of integral values with |total| at most 2^24 is
-# exactly representable in float32, so ANY reduction order produces the
-# bits of the host's float64 bincount. The count channels sum 0/1
-# indicators, so the padded row count is held to the same bound.
-_EXACT_SUM_BOUND = float(2**24)
-
-
-@functools.lru_cache(maxsize=32)
-def _make_pallas_segment_reduce(fns: tuple, k_pad: int, tile: int, interpret: bool):
-    """Fused multi-channel segment reduce: grid streams row tiles, the
-    [C, k_pad] float32 output block (constant index map) accumulates
-    across the SEQUENTIAL grid steps — one program for every channel
-    instead of one dispatch per channel. Channel c reduces vals[c] by
-    `fns[c]` over the shared int32 group ids. Index maps return int32
-    constants so the kernel lowers under the scoped-x64 worker thread."""
-    from hyperspace_tpu.compat import resolve_pallas
-
-    pl = resolve_pallas()
-    c_num = len(fns)
-    zero = np.int32(0)
-
-    def kernel(gid_ref, vals_ref, out_ref):
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            for c, fn in enumerate(fns):
-                ident = 0.0 if fn == "sum" else (np.inf if fn == "min" else -np.inf)
-                out_ref[c, :] = jnp.full((k_pad,), ident, jnp.float32)
-
-        gid = gid_ref[0, :]
-        onehot = gid[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (tile, k_pad), 1
-        )
-        for c, fn in enumerate(fns):
-            v = vals_ref[c, :]
-            if fn == "sum":
-                out_ref[c, :] += jnp.sum(
-                    jnp.where(onehot, v[:, None], jnp.float32(0)), axis=0
-                )
-            elif fn == "min":
-                out_ref[c, :] = jnp.minimum(
-                    out_ref[c, :],
-                    jnp.min(jnp.where(onehot, v[:, None], jnp.float32(np.inf)), axis=0),
-                )
-            else:
-                out_ref[c, :] = jnp.maximum(
-                    out_ref[c, :],
-                    jnp.max(jnp.where(onehot, v[:, None], jnp.float32(-np.inf)), axis=0),
-                )
-
-    def run(gid2d, vals):  # gid2d [1, n_pad] int32, vals [C, n_pad] float32
-        n_pad = vals.shape[1]
-        with jax.named_scope("agg.segment_reduce"):
-            return pl.pallas_call(
-                kernel,
-                grid=(n_pad // tile,),
-                in_specs=[
-                    pl.BlockSpec((1, tile), lambda i: (zero, i)),
-                    pl.BlockSpec((c_num, tile), lambda i: (zero, i)),
-                ],
-                out_specs=pl.BlockSpec((c_num, k_pad), lambda i: (zero, zero)),
-                out_shape=jax.ShapeDtypeStruct((c_num, k_pad), jnp.float32),
-                interpret=interpret,
-            )(gid2d, vals)
-
-    return jit(run, key="ops.aggregate.pallas_segment_reduce")
 
 
 #: Row width of the [n / w, w] blocks the dense reduction walks.
@@ -484,21 +393,13 @@ def aggregate_arrays(
     num_groups: int,
     venue: str = "device",
     mesh=None,
-    fused: str = "off",
-    exact_sums: list | None = None,
 ):
     """Segment-reduce of (values, valid, fn) triples sharing group
     ids. fn ∈ sum/min/max (count/mean are composed by the caller).
     Returns (results [A, K] float64-ish np arrays, counts [A, K]).
     With a multi-device mesh the row dimension shards across devices
-    (partial reduce + one collective per channel).
-
-    `fused` = "auto" engages the fused Pallas segment reduce when the
-    shape is eligible AND byte-identity with the host reference is
-    provable; `exact_sums` carries the per-input float32-exactness proof
-    (computed once in the cached channel prep — None means unproven,
-    which keeps the lax path). Channel staging and uploads route
-    through the identity caches for stable inputs."""
+    (partial reduce + one collective per channel). Channel staging and
+    uploads route through the identity caches for stable inputs."""
     if not inputs:  # DISTINCT: group keys only, nothing to reduce
         return np.zeros((0, num_groups)), np.zeros((0, num_groups))
     if venue == "host":
@@ -519,12 +420,9 @@ def aggregate_arrays(
         return g
 
     fns: list[str] = []
-    chan_exact: list[bool] = []
-    for i, (_vals, _valid, fn) in enumerate(inputs):
+    for _vals, _valid, fn in inputs:
         fns.append(fn)
-        chan_exact.append(bool(exact_sums[i]) if exact_sums is not None else False)
         fns.append("sum")  # the per-input non-null count channel
-        chan_exact.append(True)  # 0/1 indicators: exact below the row bound
 
     def build_channels() -> np.ndarray:
         vals_list: list[np.ndarray] = []
@@ -568,91 +466,37 @@ def aggregate_arrays(
     # process-wide flag is never touched (round 1 weakness #8).
     from hyperspace_tpu.parallel.x64 import run_x64
 
-    out = None
-    if d == 1 and fused == "auto":
-        out = _try_pallas_reduce(stacked, gid_p, k_seg, tuple(fns), chan_exact, n_pad)
-    if out is None:
-        if fused == "auto":
-            stats.increment("device.kernel.fallbacks")
-        if d > 1:
-            stats.increment("device.kernel.segment_reduce_sharded")
-            path = "sharded"
-            reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, tuple(fns))
-            with obs_trace.span("agg.channels"):
-                args = run_x64(lambda: (jnp.asarray(stacked), jnp.asarray(gid_p)))
-        else:
-            stats.increment("device.kernel.segment_reduce_lax")
-            path = "lax"
-            # An accelerator's float64 segment scatter costs about 110 ns
-            # a row whatever K (ops/join_agg.py); the dense reduction K
-            # fused compare-select-adds. XLA:CPU's scatter adds rows in
-            # order, the host venue's bincount order bit for bit: the CPU
-            # keeps it.
-            dense = k_seg <= _DENSE_MAX_SEGMENTS and jax.default_backend() != "cpu"
-            reduce_fn = functools.partial(
-                _dense_segment_reduce if dense else _segment_reduce_many,
-                num_segments=k_seg, fns=tuple(fns),
+    if d > 1:
+        stats.increment("device.kernel.segment_reduce_sharded")
+        path = "sharded"
+        reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, tuple(fns))
+        with obs_trace.span("agg.channels"):
+            args = run_x64(lambda: (jnp.asarray(stacked), jnp.asarray(gid_p)))
+    else:
+        stats.increment("device.kernel.segment_reduce_lax")
+        path = "lax"
+        # An accelerator's float64 segment scatter costs about 110 ns
+        # a row whatever K (ops/join_agg.py); the dense reduction K
+        # fused compare-select-adds. XLA:CPU's scatter adds rows in
+        # order, the host venue's bincount order bit for bit: the CPU
+        # keeps it.
+        dense = k_seg <= _DENSE_MAX_SEGMENTS and jax.default_backend() != "cpu"
+        reduce_fn = functools.partial(
+            _dense_segment_reduce if dense else _segment_reduce_many,
+            num_segments=k_seg, fns=tuple(fns),
+        )
+        # Stable stacks/pads serve the upload from the HBM cache on
+        # repeat queries — the staging tax is paid once per version.
+        with obs_trace.span("agg.channels"):
+            args = run_x64(
+                lambda: (dcache.device_put_cached(stacked), dcache.device_put_cached(gid_p))
             )
-            # Stable stacks/pads serve the upload from the HBM cache on
-            # repeat queries — the staging tax is paid once per version.
-            with obs_trace.span("agg.channels"):
-                args = run_x64(
-                    lambda: (dcache.device_put_cached(stacked), dcache.device_put_cached(gid_p))
-                )
-        with obs_trace.span("agg.reduce", path=path):
-            out = np.asarray(run_x64(lambda: to_host(reduce_fn(*args))))
+    with obs_trace.span("agg.reduce", path=path):
+        out = np.asarray(run_x64(lambda: to_host(reduce_fn(*args))))
     out = out[:, :num_groups]
     results = out[0::2]
     counts = out[1::2]
     return results, counts
-
-
-def _try_pallas_reduce(
-    stacked: np.ndarray, gid_p: np.ndarray, k_seg: int, fns: tuple,
-    chan_exact: list, n_pad: int,
-):
-    """One fused Pallas launch for ALL channels, or None when the call is
-    ineligible. The rule is explicit and decided before any lowering:
-    at most `_PALLAS_MAX_SEGMENTS` padded segments, at most
-    `_EXACT_SUM_BOUND` padded rows (the count channels), every channel
-    proven float32-exact, and — in interpret mode only — a bounded
-    grid. A lowering or compile error of an eligible call raises: it is
-    a defect of the kernel, never a silent reroute."""
-    from hyperspace_tpu.execution import device_cache as dcache
-    from hyperspace_tpu.parallel.x64 import run_x64
-
-    k_pad = max(k_seg, 128)  # lane-width floor for the TPU lowering
-    if k_pad > _PALLAS_MAX_SEGMENTS or n_pad > _EXACT_SUM_BOUND or not all(chan_exact):
-        return None
-    tile = min(_PALLAS_SEG_TILE, n_pad)
-    interpret = jax.default_backend() == "cpu"
-    if interpret and n_pad * k_pad > _PALLAS_INTERPRET_WORK:
-        return None
-
-    def build_gid2d() -> np.ndarray:
-        return np.ascontiguousarray(gid_p.reshape(1, n_pad))
-
-    def build_vals32() -> np.ndarray:
-        return stacked.astype(np.float32)
-
-    with obs_trace.span("agg.channels"):
-        if dcache.is_stable(gid_p):
-            gid2d = dcache.derived(("gid2d", id(gid_p)), (gid_p,), build_gid2d)
-        else:
-            gid2d = build_gid2d()
-        if dcache.is_stable(stacked):
-            vals32 = dcache.derived(("aggstack32", id(stacked)), (stacked,), build_vals32)
-        else:
-            vals32 = build_vals32()
-        args = run_x64(
-            lambda: (dcache.device_put_cached(gid2d), dcache.device_put_cached(vals32))
-        )
-    run = _make_pallas_segment_reduce(fns, k_pad, tile, interpret)
-    with obs_trace.span("agg.reduce", path="fused"):
-        out = run_x64(lambda: to_host(run(*args)))
-    stats.increment("device.kernel.fused")
-    stats.increment("device.kernel.segment_reduce_fused")
-    return np.asarray(out, np.float64)
 
 
 def _pad_const(v: np.ndarray, n_pad: int, fn: str) -> np.ndarray:
@@ -698,38 +542,11 @@ def _spec_identity(table: ColumnTable, spec):
     return tuple(refs), tuple(parts)
 
 
-def _sum_exactness(vals) -> bool:
-    """True when a sum channel's values are provably order-independent
-    in the fused kernel's float32: finite, integral, absolute total
-    below 2^24 — every partial sum is then exactly representable, so
-    ANY reduction order (the kernel's tile sums included) yields the
-    host reference's float64 bits."""
-    v = np.asarray(vals, dtype=np.float64)
-    if not len(v):
-        return True
-    with np.errstate(all="ignore"):
-        if not bool(np.isfinite(v).all()):
-            return False
-        if not bool((v == np.trunc(v)).all()):
-            return False
-        return float(np.abs(v).sum()) < _EXACT_SUM_BOUND
-
-
-def _extremum_exactness(vals) -> bool:
-    """True when an extremum channel survives the fused kernel's float32
-    bit-exactly: every value (infinities included) is representable in
-    float32, and none is NaN (whose propagation through a tiled min/max
-    is not pinned to the host's reduceat)."""
-    v = np.asarray(vals, dtype=np.float64)
-    with np.errstate(all="ignore"):
-        return bool((v.astype(np.float32).astype(np.float64) == v).all())
-
-
 def prepared_agg_input(table: ColumnTable, spec):
-    """(vals, valid, fn, exact) channels for one AggSpec — the masked
-    value array, its validity, the reduce fn, and the fused-kernel
-    exactness proof — memoized per (expression, input identity) for
-    stable tables so repeat queries skip the channel prep entirely."""
+    """(vals, valid, fn) channels for one AggSpec — the masked value
+    array, its validity and the reduce fn — memoized per (expression,
+    input identity) for stable tables so repeat queries skip the channel
+    prep entirely."""
     import json
 
     from hyperspace_tpu.execution import device_cache as dc
@@ -740,12 +557,7 @@ def prepared_agg_input(table: ColumnTable, spec):
         if spec.fn == "count":
             vals = np.ones(table.num_rows, np.float64) if valid is None else valid.astype(np.float64)
             valid = None
-            exact = True  # 0/1 indicators sum exactly in any order
-        elif fn == "sum":
-            exact = _sum_exactness(vals)
-        else:
-            exact = _extremum_exactness(vals)
-        return vals, valid, fn, exact
+        return vals, valid, fn
 
     refs, parts = _spec_identity(table, spec)
     if refs is None:
@@ -763,12 +575,12 @@ def prepared_agg_input(table: ColumnTable, spec):
         )
 
     def build():
-        vals, valid, fn, exact = build_raw()
+        vals, valid, fn = build_raw()
         vals = dc.freeze(np.asarray(vals))
         if valid is not None:
             valid = dc.freeze(np.asarray(valid))
         nbytes = int(vals.nbytes) + (int(valid.nbytes) if valid is not None else 0)
-        return (vals, valid, fn, exact), nbytes
+        return (vals, valid, fn), nbytes
 
     return dc.HOST_DERIVED.get_or_build(key, refs, build)
 
@@ -778,32 +590,25 @@ def aggregate_table(
     venue: str = "device",
     mesh=None,
     groups: tuple | None = None,
-    fused: str = "off",
 ) -> ColumnTable:
     """Execute a grouped aggregation over a materialized table.
     `groups` optionally passes a precomputed (gid, K, first_idx)
     factorization so callers sharing one key layout across several
-    aggregations (distinct expansion, grouping sets) don't re-factorize.
-    `fused` gates the fused Pallas segment reduce (see aggregate_arrays)."""
+    aggregations (distinct expansion, grouping sets) don't re-factorize."""
     gid, k, first_idx = groups if groups is not None else group_ids(table, group_by)
 
     inputs = []
-    exact_sums: list[bool] = []
     string_dicts: dict[int, np.ndarray] = {}
     for i, spec in enumerate(aggs):
         if isinstance(spec.expr, Col):
             f = table.schema.field(spec.expr.name)
             if f.is_string:
                 string_dicts[i] = table.dictionaries[f.name]
-        vals, valid, fn, exact = prepared_agg_input(table, spec)
-        inputs.append((vals, valid, fn))
-        exact_sums.append(exact)
+        inputs.append(prepared_agg_input(table, spec))
 
     if k == 0:
         return ColumnTable.empty(out_schema)
-    results, counts = aggregate_arrays(
-        inputs, gid, k, venue=venue, mesh=mesh, fused=fused, exact_sums=exact_sums
-    )
+    results, counts = aggregate_arrays(inputs, gid, k, venue=venue, mesh=mesh)
 
     cols: dict[str, np.ndarray] = {}
     dicts: dict[str, np.ndarray] = {}

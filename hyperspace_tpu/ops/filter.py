@@ -800,30 +800,15 @@ def _host_mask(table: ColumnTable, predicate: Expr) -> np.ndarray:
 
 
 def eval_predicate_mask(
-    table: ColumnTable, predicate: Expr, mesh=None, venue: str = "auto"
+    table: ColumnTable, predicate: Expr, mesh=None, venue: str = "device"
 ) -> np.ndarray:
-    """Evaluate the predicate; returns a host bool mask. Venue-aware: the
-    mask must land on host and the columns start there, so below the link
-    floor the exact numpy evaluation (_host_mask — the same one
-    unliftable predicates already use) beats the device round-trip. On
-    device, with a mesh the row dimension is sharded across it (purely
-    elementwise — zero collectives; the analog of the reference keeping
-    full scan parallelism, FilterIndexRule.scala:114-120)."""
+    """Evaluate the predicate; returns a host bool mask. The host venue
+    is the exact numpy evaluation (_host_mask — the same one unliftable
+    predicates already use). On device, with a mesh the row dimension is
+    sharded across it (purely elementwise — zero collectives; the analog
+    of the reference keeping full scan parallelism,
+    FilterIndexRule.scala:114-120)."""
     predicate = translate_predicate(table, predicate)
-    if venue == "auto":
-        from hyperspace_tpu.parallel.bandwidth import pick_venue
-
-        prefer_device = False
-        if mesh is not None:
-            from hyperspace_tpu.parallel.mesh import mesh_size
-
-            prefer_device = mesh_size(mesh) > 1
-        venue = pick_venue(
-            "auto", 200.0,
-            prefer_device=prefer_device,
-            what="hyperspace.filter.venue",
-            needs_native=False,
-        )
     if venue == "host":
         return _host_mask(table, predicate)
     try:
@@ -877,7 +862,7 @@ def eval_predicate_mask(
 
 
 def apply_filter(
-    table: ColumnTable, predicate: Expr, mesh=None, venue: str = "auto"
+    table: ColumnTable, predicate: Expr, mesh=None, venue: str = "device"
 ) -> ColumnTable:
     if table.num_rows == 0:
         return table

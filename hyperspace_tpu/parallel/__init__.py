@@ -1,6 +1,5 @@
-"""Parallel execution utilities: device meshes, bandwidth-aware venue
-choice, x64 worker pools, and the spawn-context worker-process
-lifecycle.
+"""Parallel execution utilities: device meshes, x64 worker pools, and
+the spawn-context worker-process lifecycle.
 
 This ``__init__`` must stay **jax-free at module load**: the pooled
 build's spawned workers import ``hyperspace_tpu.parallel.procpool``,

@@ -72,11 +72,11 @@ Counter names in use:
   staging (nulls, casts, multi-chunk concat, unaligned offset views,
   staging disabled, or the un-cached downgrade path)
 - ``device.kernel.fused``  fused Pallas kernel launches on the device
-  venue (segment reduce / join-agg run bounds) — each one replaced a
+  venue (join-agg run bounds, top-k tiles) — each one replaced a
   multi-dispatch lax composition
-- ``device.kernel.fallbacks``  device-venue reduces that took the
+- ``device.kernel.fallbacks``  device-venue calls that took the
   always-available jitted lax path while fused kernels were enabled
-  (ineligible shape, unprovable exactness, or a failed Pallas lowering)
+  (an ineligible shape)
 - ``device.kernel.dense_reduce``  device join-aggregates whose padded
   group count let every channel reduce in one dense masked reduction
   over all rows (ops/join_agg.py)
@@ -85,11 +85,10 @@ Counter names in use:
 - ``device.kernel.bucket_reduce``  device join-aggregates with too many
   groups for the dense reduction whose group keys hold the join key, so
   each bucket's channels reduce into that bucket's own groups
-- ``device.kernel.segment_reduce_fused`` / ``_lax`` / ``_sharded``
-  device grouped aggregates (ops/aggregate.py) by the reduction they
-  took: the fused Pallas kernel, the jitted float64 lax segment reduce
-  on one device, or the mesh-sharded reduce with one collective per
-  channel
+- ``device.kernel.segment_reduce_lax`` / ``_sharded``  device grouped
+  aggregates (ops/aggregate.py) by the reduction they took: the jitted
+  float64 lax reduce on one device, or the mesh-sharded reduce with one
+  collective per channel
 - ``controller.ticks``  reconciliation steps the self-driving operations
   controller ran while armed (serve/controller.py,
   docs/fault_tolerance.md "self-driving operations")
@@ -197,7 +196,6 @@ KNOWN_COUNTERS = (
     "device.kernel.dense_reduce",
     "device.kernel.scatter_reduce",
     "device.kernel.bucket_reduce",
-    "device.kernel.segment_reduce_fused",
     "device.kernel.segment_reduce_lax",
     "device.kernel.segment_reduce_sharded",
     "controller.ticks",

@@ -719,15 +719,15 @@ def test_partial_agg_pushdown_dim_case_matches_pandas(tmp_path, join_tables):
 
 
 @pytest.mark.parametrize("venue,kernel", [
-    (None, "host-partition-select"),
+    (None, "device-select"),
     ("host", "host-partition-select"),
     ("device", "device-select"),
 ])
 def test_top_n_matches_full_sort(tmp_path, venue, kernel):
     """ORDER BY + LIMIT takes the venue's select path and must equal the
     full sort exactly, incl. duplicate first keys and DESC order. The
-    default (auto, no mesh) keeps the host partition select; only an
-    explicit device venue selects on the one device."""
+    default venue (device, no mesh) selects on the one device; the host
+    venue keeps the partition select."""
     rng = np.random.default_rng(8)
     n = 60_000
     df_ = pd.DataFrame(

@@ -1071,38 +1071,26 @@ class TestRepoProcessDomains:
         assert chain[0].startswith("hyperspace_tpu.ops.filter.")
 
     def test_every_pallas_ladder_is_proven(self, repo_tdomains):
-        """All three Pallas kernels carry the complete eligibility
-        ladder: an explicit rule, both device.kernel.* counters, and no
-        broad except swallowing a lowering error, with the engagement
-        chain from the public op down to the factory."""
+        """Both Pallas kernels carry the complete eligibility ladder: an
+        explicit rule, both device.kernel.* counters, and no broad except
+        swallowing a lowering error, with the engagement chain from the
+        public op down to the factory."""
         ladders = {lad["kernel"]: lad for lad in repo_tdomains._kernel_ladders}
         assert set(ladders) == {
-            "ops.aggregate.pallas_segment_reduce",
             "ops.sortkeys.pallas_run_bounds",
             "ops.topk.pallas_tile",
-        }
-        # The grouped aggregate also counts which reduction each call took.
-        path_counters = {
-            "ops.aggregate.pallas_segment_reduce": {
-                "device.kernel.segment_reduce_fused",
-                "device.kernel.segment_reduce_lax",
-                "device.kernel.segment_reduce_sharded",
-            },
         }
         for name, lad in ladders.items():
             assert lad["proven"], name
             assert lad["gate"] and lad["swallow"] is None, name
             assert set(lad["counters"]) == {
                 "device.kernel.fused", "device.kernel.fallbacks",
-            } | path_counters.get(name, set()), name
+            }, name
         assert ladders["ops.topk.pallas_tile"]["witness"] == [
             "hyperspace_tpu.ops.topk.topk",
             "hyperspace_tpu.ops.topk._pallas_topk",
             "hyperspace_tpu.ops.topk._make_tile_kernel",
         ]
-        assert ladders["ops.aggregate.pallas_segment_reduce"]["witness"][0] == (
-            "hyperspace_tpu.ops.aggregate.aggregate_table"
-        )
 
     def test_known_kernels_registry_is_fresh(self, repo_tdomains):
         # Same both-directions contract as faults.KNOWN_POINTS: every
@@ -1136,7 +1124,7 @@ class TestRepoProcessDomains:
         s = report["summary"]
         assert s["trace_entry_points"] >= 25
         assert s["trace_domain_functions"] >= 15
-        assert s["trace_kernels_proven"] == 3
+        assert s["trace_kernels_proven"] == 2
         assert 0.0 < s["trace_domain_unresolved_ratio"] < 0.9
         assert s["trace_domain_unresolved_ratio"] == repo_tdomains.unresolved_ratio()
         assert repo_tdomains.unresolved_ratio() == round(

@@ -241,7 +241,7 @@ def test_e2e_join_distributed_on_mesh(tmp_path):
     """Full query path with a session mesh: the rewritten join must run
     bucket-sharded over all 8 virtual devices and match the un-indexed
     result row-for-row (the device kernel is the subject — pinned
-    explicitly so a HYPERSPACE_VENUE=host sweep does not reroute it)."""
+    explicitly)."""
     from hyperspace_tpu.config import JOIN_VENUE
     import pyarrow as pa
     import pyarrow.parquet as pq
@@ -320,7 +320,7 @@ def test_mesh_distributed_top_n_matches_host(tmp_path):
         )
         if mesh is not None:
             # Pin the venue: the assertion below is about the device
-            # kernel, and must hold under a HYPERSPACE_VENUE=host sweep.
+            # kernel.
             from hyperspace_tpu.config import SORT_VENUE
 
             session.conf.set(SORT_VENUE, "device")

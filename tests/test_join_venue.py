@@ -1,9 +1,9 @@
 """Host-native join venue: the C++ bucket-parallel merge join must be
-result-identical to the device kernel, and the venue choice must obey
-the config override. Where the device→host link is slow the readback
-of the match pairs dominates a materialized join, so the executor picks
-the host kernel when measured bandwidth is low (parallel/bandwidth.py);
-both venues share every other stage."""
+result-identical to the device kernel, and the venue must obey the
+config: `device` by default, `host` when set; both venues share every
+other stage."""
+
+import re
 
 import numpy as np
 import pandas as pd
@@ -12,7 +12,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from hyperspace_tpu import Hyperspace, HyperspaceSession, IndexConfig, col, lit
-from hyperspace_tpu.config import JOIN_VENUE
+from hyperspace_tpu.config import JOIN_VENUE, VENUE_KEYS
 from hyperspace_tpu import native
 
 
@@ -150,13 +150,20 @@ def test_native_merge_join_kernel_direct():
         assert int(totals.sum()) == len(exp_pairs)
 
 
-def test_unknown_venue_raises(joined):
+@pytest.mark.parametrize(
+    "key, value",
+    [(key, "auto") for key in VENUE_KEYS] + [(JOIN_VENUE, "hsot")],
+)
+def test_unknown_venue_raises(key, value):
+    """Every venue key takes device|host only, and says which key was
+    wrong at set(); a rejected value leaves the default in force."""
+    from hyperspace_tpu.config import HyperspaceConf
     from hyperspace_tpu.exceptions import HyperspaceError
 
-    session, fs, ds, _, _ = joined
-    session.conf.set(JOIN_VENUE, "hsot")
-    with pytest.raises(HyperspaceError, match="join.venue"):
-        session.run(fs.join(ds, ["k"]))
+    conf = HyperspaceConf(system_path="unused")
+    with pytest.raises(HyperspaceError, match=re.escape(key)):
+        conf.set(key, value)
+    assert conf.get(key) == "device"
 
 
 @needs_native
@@ -233,23 +240,3 @@ def test_filtered_sides_keep_zero_exchange_join(joined, venue):
     assert len(got) == len(exp)
     np.testing.assert_allclose(got["a"], exp["a"])
     np.testing.assert_allclose(got["b"], exp["b"])
-
-
-def test_env_venue_override_precedence(joined, monkeypatch):
-    """HYPERSPACE_VENUE overrides auto decisions; explicit per-operator
-    conf still wins; invalid values raise."""
-    from hyperspace_tpu.exceptions import HyperspaceError
-    from hyperspace_tpu.parallel.bandwidth import pick_venue
-
-    monkeypatch.setenv("HYPERSPACE_VENUE", "device")
-    assert pick_venue("auto", 200.0, False, "x", needs_native=False) == "device"
-    # Explicit request wins over the env var.
-    assert pick_venue("host", 200.0, False, "x", needs_native=False) == "host"
-    monkeypatch.setenv("HYPERSPACE_VENUE", "hOst")
-    with pytest.raises(HyperspaceError, match="HYPERSPACE_VENUE"):
-        pick_venue("auto", 200.0, False, "x", needs_native=False)
-    # End-to-end: forced device via env on an auto session.
-    monkeypatch.setenv("HYPERSPACE_VENUE", "device")
-    session, fs, ds, f, d = joined
-    session.to_pandas(fs.join(ds, ["k"]))
-    assert session.last_query_stats["join_kernel"] == "device-searchsorted"

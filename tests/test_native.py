@@ -9,6 +9,7 @@ The suite must pass whether or not the toolchain built the library
 """
 
 import hashlib
+import platform
 
 import numpy as np
 import pytest
@@ -34,6 +35,22 @@ def test_native_builds():
     if shutil.which("g++") is None:
         pytest.skip("no g++ toolchain on this host — numpy fallbacks cover it")
     assert native.available()
+
+
+def test_library_cache_is_keyed_by_host_cpu(tmp_path):
+    """A library built with -march=native on one CPU must not be loaded
+    on another: two instruction-set identities get two cached paths, the
+    same identity the same path, and an unreadable cpuinfo still names
+    the machine."""
+    a, b = tmp_path / "a", tmp_path / "b"
+    a.write_text("processor\t: 0\nflags\t\t: fpu sse2 avx2\n")
+    b.write_text("processor\t: 0\nflags\t\t: fpu sse2 avx2 avx512f\n")
+    cpu_a, cpu_b = native._cpu_identity(a), native._cpu_identity(b)
+    assert cpu_a != cpu_b
+    assert native._library_path(cpu_a) != native._library_path(cpu_b)
+    assert native._library_path(cpu_a) == native._library_path(native._cpu_identity(a))
+    missing = native._cpu_identity(tmp_path / "missing")
+    assert missing.startswith(platform.machine()) and missing not in (cpu_a, cpu_b)
 
 
 def test_hash_i64_parity(rng):

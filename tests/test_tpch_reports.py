@@ -77,7 +77,7 @@ def _spans(node, name):
 
 def test_q1_profile_shows_the_aggregate_spans_and_path_counter(reports):
     cell, ctx, _roots = reports
-    paths = ("fused", "lax", "sharded")
+    paths = ("lax", "sharded")
     before = {p: stats.get(f"device.kernel.segment_reduce_{p}") for p in paths}
     op = harness.execute(ctx, cell, "tpch_q1", draw(cell, "tpch_q1", SEEDS[1]), 0)
     trace = op.evidence["profile"].to_json()["trace"]

@@ -55,26 +55,6 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_segment_reduce_compiles_as_run_x64_calls_it(one_chip):
-    from hyperspace_tpu.ops.aggregate import (
-        _PALLAS_MAX_SEGMENTS,
-        _PALLAS_SEG_TILE,
-        _make_pallas_segment_reduce,
-    )
-    from hyperspace_tpu.parallel.x64 import run_x64
-
-    n = 1 << 23  # SF1 lineitem padded to a power of two
-    run = _make_pallas_segment_reduce(
-        ("sum", "sum", "max"), _PALLAS_MAX_SEGMENTS, _PALLAS_SEG_TILE, False
-    )
-    compiled = run_x64(
-        lambda: run.lower(
-            _shape(one_chip, (1, n), jnp.int32), _shape(one_chip, (3, n), jnp.float32)
-        ).compile()
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_run_bounds_compiles_for_every_bucket_row(one_chip):
     from hyperspace_tpu.ops.sortkeys import _RB_MAX_SECONDARY, _RB_TILE, _make_run_bounds_kernel
     from hyperspace_tpu.parallel.x64 import run_x64
