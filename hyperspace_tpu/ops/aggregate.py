@@ -7,13 +7,17 @@ reduction runs as one jitted segment-reduce on device, and only the
 K-sized per-group results come back — aggregation queries never pay the
 match/row readback.
 
-Staging (docs/architecture.md "device data path"): channel preparation
-(null masking, the indicator channels, the [A, n_pad] float64 stack)
-and the group-id pad route through the identity caches for stable
-(frozen index-cache) inputs, and the device uploads go through
-DEVICE_CACHE — a repeat aggregation over the same index version costs
-one kernel launch plus a [A, K] readback, not a re-staging of every
-channel (BENCH_VENUES group_agg was 1.06x warm-over-cold before this).
+Staging (docs/architecture.md "device data path"): the device venue
+stages the base columns its AggSpecs read, each once, padded to a power
+of two and uploaded through DEVICE_CACHE (once per version for stable,
+frozen index-cache inputs), with their validity masks and the
+identity-cached group-id pad. The jitted program evaluates the
+channels from them — one value channel per distinct (expression, fn),
+one count channel per distinct set of masks, a shared row count for
+null-free inputs and count(*) — and reduces them; only the [C, K]
+result comes back. An input the program cannot evaluate (a CASE, a
+date part, a string extremum) is built on the host (agg_input) and
+staged as one more column.
 
 SQL semantics: null inputs are ignored by sum/min/max/mean and count(col);
 count(*) counts rows; a group whose inputs are all null yields NULL
@@ -22,6 +26,7 @@ count(*) counts rows; a group whose inputs are all null yields NULL
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -36,7 +41,7 @@ from hyperspace_tpu.exceptions import HyperspaceError
 from hyperspace_tpu.execution.table import ColumnTable
 from hyperspace_tpu.obs import trace as obs_trace
 from hyperspace_tpu.ops.join_agg import _DENSE_MAX_SEGMENTS, _dense_reduce
-from hyperspace_tpu.plan.expr import Col, evaluate
+from hyperspace_tpu.plan.expr import BinOp, Col, Lit, MathFn, evaluate, expr_from_json
 from hyperspace_tpu.schema import Schema
 
 
@@ -82,30 +87,33 @@ def _dense_segment_reduce(vals, gid, num_segments: int, fns: tuple):
 
 
 @functools.lru_cache(maxsize=32)
-def _make_sharded_segment_reduce(mesh, axes: tuple, num_segments: int, fns: tuple):
-    """Mesh-distributed segment reduce: the row dimension shards across
-    devices, each shard reduces locally, and ONE collective per channel
-    (psum for sums, pmin/pmax for extrema) combines the [A, K] partials —
-    the distributed HashAggregate the reference gets from Spark's partial
-    + final aggregation (SURVEY.md §2.2), expressed as XLA collectives
-    over ICI.
+def _make_sharded_segment_reduce(mesh, axes: tuple, num_segments: int, channels: tuple):
+    """Mesh-distributed :func:`_channel_reduce`: the row dimension shards
+    across devices, each shard builds its channels and reduces locally,
+    and ONE collective per channel (psum for sums, pmin/pmax for
+    extrema) combines the [C, K] partials — the distributed
+    HashAggregate the reference gets from Spark's partial + final
+    aggregation (SURVEY.md §2.2), expressed as XLA collectives over ICI.
 
-    Invariant: `fns` only contains channels with a commutative device
-    reduction over NUMERIC lanes — string inputs never reach here (the
-    plan validator rejects sum/mean over string expressions, rule
+    Invariant: every channel is a commutative device reduction over
+    NUMERIC lanes — string inputs never reach here (the plan validator
+    rejects sum/mean over string expressions, rule
     dtype-incompatible-aggregate)."""
     from jax.sharding import PartitionSpec as P
 
     from hyperspace_tpu.compat import shard_map
 
+    fns = tuple(ch.fn for ch in channels)
+
     @functools.partial(
         shard_map,
         mesh=mesh,
-        in_specs=(P(None, axes), P(axes)),
+        in_specs=(P(axes), P(axes), P(axes)),
         out_specs=P(None, None),
         check_vma=False,
     )
-    def fn(vals, gid):
+    def fn(cols, masks, gid):
+        vals = _channel_values(cols, masks, channels, gid.shape[0])
         local = _segment_reduce_many.__wrapped__(vals, gid, num_segments, fns)
         outs = []
         with jax.named_scope("agg.segment_reduce"):
@@ -347,7 +355,8 @@ def aggregate_arrays_host(
     sorted-reduceat min/max in exact float64. The inputs are host-resident
     and the [A, K] result is tiny, so on slow-transfer deployments (or
     chips without native f64) this beats uploading every channel to the
-    device; semantics are pinned identical to aggregate_arrays."""
+    device; semantics are pinned identical to the device venue's
+    (:func:`_reduce_channels`)."""
     n = len(gid)
     order = None
     group_rows = np.bincount(gid, minlength=num_groups).astype(np.int64)
@@ -387,25 +396,180 @@ def aggregate_arrays_host(
     return np.stack(results), np.stack(counts)
 
 
-def aggregate_arrays(
-    inputs: list[tuple[np.ndarray, np.ndarray | None, str]],
-    gid: np.ndarray,
-    num_groups: int,
-    venue: str = "device",
-    mesh=None,
-):
-    """Segment-reduce of (values, valid, fn) triples sharing group
-    ids. fn ∈ sum/min/max (count/mean are composed by the caller).
-    Returns (results [A, K] float64-ish np arrays, counts [A, K]).
-    With a multi-device mesh the row dimension shards across devices
-    (partial reduce + one collective per channel). Channel staging and
-    uploads route through the identity caches for stable inputs."""
-    if not inputs:  # DISTINCT: group keys only, nothing to reduce
-        return np.zeros((0, num_groups)), np.zeros((0, num_groups))
-    if venue == "host":
-        return aggregate_arrays_host(inputs, gid, num_groups)
+@dataclasses.dataclass(frozen=True)
+class _Channel:
+    """One reduction channel of the device program: ``fn`` over the
+    float64 values of ``expr``, each row that a validity input of
+    ``mask`` marks null set to ``fn``'s identity. A None ``expr`` is a
+    count: the AND of its masks, or 1 a row. ``key``, the expression's
+    JSON over input positions, is what the compile key and the
+    deduplication compare; ``expr`` rides along unhashed."""
+
+    fn: str
+    key: str
+    mask: tuple
+    expr: object = dataclasses.field(default=None, compare=False, hash=False)
+
+
+_IDENTITY = {"sum": 0.0, "min": np.inf, "max": -np.inf}
+
+
+def _device_expr(table: ColumnTable, e) -> bool:
+    """Arithmetic (BinOp, MathFn) over int64/float64 columns and numeric
+    literals: the dtypes on which numpy's and XLA's promotions agree, so
+    the program computes the host's values."""
+    if isinstance(e, Col):
+        f = table.schema.field(e.name)
+        a = table.columns[f.name]
+        return not f.is_string and a.ndim == 1 and a.dtype in (np.int64, np.float64)
+    if isinstance(e, Lit):
+        return isinstance(e.value, (int, float)) and not isinstance(e.value, bool)
+    if isinstance(e, BinOp):
+        return _device_expr(table, e.left) and _device_expr(table, e.right)
+    if isinstance(e, MathFn):
+        return _device_expr(table, e.child)
+    return False
+
+
+def _device_spec(table: ColumnTable, spec) -> bool:
+    """Whether the device program builds this AggSpec's channels: a count
+    reads only validity; other fns a bare numeric column (any width,
+    cast to float64 as the host casts it) or :func:`_device_expr`. A
+    CASE, a date part or a string extremum is built on the host."""
+    e = spec.expr
+    if spec.fn == "count":
+        return e is None or isinstance(e, Col) or _device_expr(table, e)
+    if spec.fn not in _IDENTITY and spec.fn != "mean":
+        return False
+    if isinstance(e, Col):
+        f = table.schema.field(e.name)
+        a = table.columns[f.name]
+        return not f.is_string and a.ndim == 1 and a.dtype.kind in "biuf"
+    return _device_expr(table, e)
+
+
+def _positional(d: dict, col_pos) -> dict:
+    """An expression's JSON with each column named by its input position."""
+    if d.get("type") == "col":
+        return {"type": "col", "name": str(col_pos(d["name"]))}
+    return {k: _positional(v, col_pos) if isinstance(v, dict) else v for k, v in d.items()}
+
+
+def _plan_channels(table: ColumnTable, aggs: list):
+    """The deduplicated channels of a device-venue aggregate.
+
+    Returns (arrays, masks, channels, slots, n_host): the host arrays
+    the program reads (each base column once, then each host-built
+    channel), the validity masks, the channels, per AggSpec the
+    positions of its (value, count) channels, and how many AggSpecs were
+    built on the host. One value channel per distinct
+    (expression, fn); one count channel per distinct set of masks, so
+    every null-free input and count(*) share the group's row count."""
+    import json
+
+    arrays: list[np.ndarray] = []
+    masks: list[np.ndarray] = []
+    col_at: dict[str, int] = {}
+    mask_at: dict[str, int] = {}
+    channels: dict[_Channel, int] = {}
+
+    def col_pos(name: str) -> int:
+        f = table.schema.field(name)
+        if f.name not in col_at:
+            col_at[f.name] = len(arrays)
+            arrays.append(table.columns[f.name])
+        return col_at[f.name]
+
+    def mask_pos(names) -> tuple:
+        out = []
+        for nm in sorted(names):
+            f = table.schema.field(nm)
+            m = table.validity.get(f.name)
+            if m is not None:
+                if f.name not in mask_at:
+                    mask_at[f.name] = len(masks)
+                    masks.append(m)
+                out.append(mask_at[f.name])
+        return tuple(sorted(out))
+
+    def channel(fn: str, expr, mask: tuple) -> int:
+        key = "" if expr is None else json.dumps(expr.to_json(), sort_keys=True)
+        return channels.setdefault(_Channel(fn, key, mask, expr), len(channels))
+
+    slots: list[tuple[int, int]] = []
+    n_host = 0
+    for spec in aggs:
+        fn = "sum" if spec.fn in ("count", "mean") else spec.fn
+        if _device_spec(table, spec):
+            mask = mask_pos(spec.references()) if spec.expr is not None else ()
+            cnt = channel("sum", None, mask)
+            if spec.fn == "count":
+                slots.append((cnt, cnt))
+                continue
+            expr = expr_from_json(_positional(spec.expr.to_json(), col_pos))
+        else:
+            # The host build (agg_input): a CASE, a date part, a string.
+            n_host += 1
+            vals, valid, fn = prepared_agg_input(table, spec)
+            expr = Col(str(len(arrays)))
+            arrays.append(np.asarray(vals))
+            mask = ()
+            if valid is not None:
+                mask = (len(masks),)
+                masks.append(valid)
+            cnt = channel("sum", None, mask)
+        slots.append((channel(fn, expr, mask), cnt))
+    return arrays, masks, tuple(channels), slots, n_host
+
+
+def _channel_values(cols: tuple, masks: tuple, channels: tuple, n: int) -> list:
+    """Each channel's [n] float64 values, traced inside the reduction
+    program from the staged inputs."""
+    env = {str(i): c for i, c in enumerate(cols)}
+    out = []
+    with jax.named_scope("agg.channels"):
+        for ch in channels:
+            valid = None
+            for m in ch.mask:
+                valid = masks[m] if valid is None else valid & masks[m]
+            if ch.expr is None:
+                v = jnp.ones(n, jnp.float64) if valid is None else valid.astype(jnp.float64)
+            else:
+                v = jnp.asarray(evaluate(ch.expr, env.__getitem__, jnp)).astype(jnp.float64)
+                v = jnp.broadcast_to(v, (n,))
+                if valid is not None:
+                    v = jnp.where(valid, v, _IDENTITY[ch.fn])
+            out.append(v)
+    return out
+
+
+@functools.partial(jit, static_argnames=("num_segments", "channels", "reduce"))
+def _channel_reduce(cols, masks, gid, num_segments: int, channels: tuple, reduce: str):
+    """[C, num_segments]: the channels built from the staged inputs, then
+    reduced densely (``reduce`` 'dense') or by segment scatter."""
+    vals = _channel_values(cols, masks, channels, gid.shape[0])
+    fns = tuple(ch.fn for ch in channels)
+    if reduce == "dense":
+        return _dense_segment_reduce.__wrapped__(vals, gid, num_segments, fns)
+    return _segment_reduce_many.__wrapped__(vals, gid, num_segments, fns)
+
+
+def _reduce_channels(
+    arrays: list, masks: list, channels: tuple, gid: np.ndarray, num_groups: int, mesh=None
+) -> np.ndarray:
+    """[C, num_groups] float64: each channel reduced over the group ids.
+    Every input array is written once into an n_pad-long buffer (a power
+    of two, so one program serves every filtered row count) and uploaded
+    through DEVICE_CACHE, once per version for a stable table; the
+    channels are built inside the program. With a multi-device mesh the
+    rows shard across devices (partial reduce + one collective per
+    channel)."""
     from hyperspace_tpu.execution import device_cache as dcache
     from hyperspace_tpu.parallel.mesh import mesh_axes, mesh_size
+
+    # 53-bit accumulation on the persistent x64 worker thread — the
+    # process-wide flag is never touched (round 1 weakness #8).
+    from hyperspace_tpu.parallel.x64 import run_x64
 
     d = mesh_size(mesh) if mesh is not None else 1
     n = len(gid)
@@ -419,59 +583,32 @@ def aggregate_arrays(
         g[:n] = gid
         return g
 
-    fns: list[str] = []
-    for _vals, _valid, fn in inputs:
-        fns.append(fn)
-        fns.append("sum")  # the per-input non-null count channel
+    sharding = None
+    if d > 1:
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-    def build_channels() -> np.ndarray:
-        vals_list: list[np.ndarray] = []
-        for vals, valid, fn in inputs:
-            v = np.asarray(vals, dtype=np.float64)
-            if fn == "sum":
-                if valid is not None:
-                    v = np.where(valid, v, 0.0)
-            elif fn == "min":
-                v = np.where(valid, v, np.inf) if valid is not None else v
-            elif fn == "max":
-                v = np.where(valid, v, -np.inf) if valid is not None else v
-            vals_list.append(np.pad(v, (0, n_pad - n)) if fn == "sum" else _pad_const(v, n_pad, fn))
-            # Every input also gets a non-null count (for mean/null results).
-            cnt = np.ones(n, np.float64) if valid is None else valid.astype(np.float64)
-            vals_list.append(np.pad(cnt, (0, n_pad - n)))
-        return np.stack(vals_list)
-
-    stable = dcache.is_stable(gid) and all(
-        dcache.is_stable(v) and (m is None or dcache.is_stable(m))
-        for v, m, _fn in inputs
-    )
-    with obs_trace.span("agg.channels"):
+        sharding = NamedSharding(mesh, P(mesh_axes(mesh)))
+    upload = n_pad * (4 + sum(a.dtype.itemsize for a in (*arrays, *masks)))
+    with obs_trace.span("agg.channels", channels=len(channels), upload_bytes=upload):
         if dcache.is_stable(gid):
             gid_p = dcache.derived(
                 ("gidpad1", id(gid), n_pad, num_groups), (gid,), build_gid_pad
             )
         else:
             gid_p = build_gid_pad()
-        if stable:
-            ids = tuple((id(v), id(m) if m is not None else None) for v, m, _fn in inputs)
-            refs = tuple(
-                a for v, m, _fn in inputs for a in ((v, m) if m is not None else (v,))
-            )
-            stacked = dcache.derived(
-                ("aggstack", ids, tuple(fns), n_pad), refs, build_channels
-            )
-        else:
-            stacked = build_channels()
-    # 53-bit accumulation on the persistent x64 worker thread — the
-    # process-wide flag is never touched (round 1 weakness #8).
-    from hyperspace_tpu.parallel.x64 import run_x64
-
+        staged = run_x64(
+            lambda: [
+                dcache.device_put_padded(a, n_pad, sharding)
+                for a in (*arrays, *masks, gid_p)
+            ]
+        )
+    cols, vmasks, gid_d = (
+        tuple(staged[: len(arrays)]), tuple(staged[len(arrays) : -1]), staged[-1]
+    )
     if d > 1:
         stats.increment("device.kernel.segment_reduce_sharded")
         path = "sharded"
-        reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, tuple(fns))
-        with obs_trace.span("agg.channels"):
-            args = run_x64(lambda: (jnp.asarray(stacked), jnp.asarray(gid_p)))
+        reduce_fn = _make_sharded_segment_reduce(mesh, mesh_axes(mesh), k_seg, channels)
     else:
         stats.increment("device.kernel.segment_reduce_lax")
         path = "lax"
@@ -482,28 +619,12 @@ def aggregate_arrays(
         # keeps it.
         dense = k_seg <= _DENSE_MAX_SEGMENTS and jax.default_backend() != "cpu"
         reduce_fn = functools.partial(
-            _dense_segment_reduce if dense else _segment_reduce_many,
-            num_segments=k_seg, fns=tuple(fns),
+            _channel_reduce, num_segments=k_seg, channels=channels,
+            reduce="dense" if dense else "scatter",
         )
-        # Stable stacks/pads serve the upload from the HBM cache on
-        # repeat queries — the staging tax is paid once per version.
-        with obs_trace.span("agg.channels"):
-            args = run_x64(
-                lambda: (dcache.device_put_cached(stacked), dcache.device_put_cached(gid_p))
-            )
     with obs_trace.span("agg.reduce", path=path):
-        out = np.asarray(run_x64(lambda: to_host(reduce_fn(*args))))
-    out = out[:, :num_groups]
-    results = out[0::2]
-    counts = out[1::2]
-    return results, counts
-
-
-def _pad_const(v: np.ndarray, n_pad: int, fn: str) -> np.ndarray:
-    fill = np.inf if fn == "min" else -np.inf
-    out = np.full(n_pad, fill, np.float64)
-    out[: len(v)] = v
-    return out
+        out = np.asarray(run_x64(lambda: to_host(reduce_fn(cols, vmasks, gid_d))))
+    return out[:, :num_groups]
 
 
 def finalize_agg_values(vals: np.ndarray, empty: np.ndarray, dtype) -> np.ndarray:
@@ -597,18 +718,31 @@ def aggregate_table(
     aggregations (distinct expansion, grouping sets) don't re-factorize."""
     gid, k, first_idx = groups if groups is not None else group_ids(table, group_by)
 
-    inputs = []
     string_dicts: dict[int, np.ndarray] = {}
     for i, spec in enumerate(aggs):
         if isinstance(spec.expr, Col):
             f = table.schema.field(spec.expr.name)
             if f.is_string:
                 string_dicts[i] = table.dictionaries[f.name]
-        inputs.append(prepared_agg_input(table, spec))
+    if venue == "host":
+        inputs = [prepared_agg_input(table, spec) for spec in aggs]
+    else:
+        arrays, masks, channels, slots, n_host = _plan_channels(table, aggs)
 
     if k == 0:
         return ColumnTable.empty(out_schema)
-    results, counts = aggregate_arrays(inputs, gid, k, venue=venue, mesh=mesh)
+    if not aggs:  # DISTINCT: group keys only, nothing to reduce
+        results = counts = np.zeros((0, k))
+    elif venue == "host":
+        results, counts = aggregate_arrays_host(inputs, gid, k)
+    else:
+        if len(aggs) > n_host:
+            stats.increment("device.kernel.agg_channels_device", len(aggs) - n_host)
+        if n_host:
+            stats.increment("device.kernel.agg_channels_host", n_host)
+        out = _reduce_channels(arrays, masks, channels, gid, k, mesh)
+        results = out[[v for v, _c in slots]]
+        counts = out[[c for _v, c in slots]]
 
     cols: dict[str, np.ndarray] = {}
     dicts: dict[str, np.ndarray] = {}

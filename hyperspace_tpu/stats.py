@@ -89,6 +89,10 @@ Counter names in use:
   aggregates (ops/aggregate.py) by the reduction they took: the jitted
   float64 lax reduce on one device, or the mesh-sharded reduce with one
   collective per channel
+- ``device.kernel.agg_channels_device`` / ``_host``  AggSpecs of device
+  grouped aggregates whose channels the reduction program builds from
+  the staged base columns, or that the host builds first (a CASE, a
+  date part, a string extremum)
 - ``controller.ticks``  reconciliation steps the self-driving operations
   controller ran while armed (serve/controller.py,
   docs/fault_tolerance.md "self-driving operations")
@@ -198,6 +202,8 @@ KNOWN_COUNTERS = (
     "device.kernel.bucket_reduce",
     "device.kernel.segment_reduce_lax",
     "device.kernel.segment_reduce_sharded",
+    "device.kernel.agg_channels_device",
+    "device.kernel.agg_channels_host",
     "controller.ticks",
     "controller.actuations",
     "controller.actuation_failures",

@@ -1036,12 +1036,7 @@ def test_count_distinct_empty_input_counts_are_zero(tmp_path):
 def test_dense_segment_reduce_matches_scatter_and_numpy(n, num_groups):
     """The accelerators' dense grouped reduction (sums, extrema, pads in
     the dead segment) against the segment scatter and numpy."""
-    from hyperspace_tpu.ops.aggregate import (
-        _dense_segment_reduce,
-        _pad_const,
-        _pow2,
-        _segment_reduce_many,
-    )
+    from hyperspace_tpu.ops.aggregate import _dense_segment_reduce, _pow2, _segment_reduce_many
     from hyperspace_tpu.parallel.x64 import run_x64
 
     rng = np.random.default_rng(n + num_groups)
@@ -1053,8 +1048,8 @@ def test_dense_segment_reduce_matches_scatter_and_numpy(n, num_groups):
     vals = np.stack([
         np.pad(v, (0, n_pad - n)),
         np.pad(np.round(v), (0, n_pad - n)),
-        _pad_const(v, n_pad, "min"),
-        _pad_const(v, n_pad, "max"),
+        np.pad(v, (0, n_pad - n), constant_values=np.inf),
+        np.pad(v, (0, n_pad - n), constant_values=-np.inf),
     ])
     dense, scatter = (
         np.asarray(run_x64(lambda f=f: f(vals, gid, num_segments=k_seg, fns=fns)))
@@ -1070,3 +1065,181 @@ def test_dense_segment_reduce_matches_scatter_and_numpy(n, num_groups):
     mins = np.full(num_groups, np.inf)
     np.minimum.at(mins, g, v)
     np.testing.assert_array_equal(dense[2, :num_groups], mins)
+
+
+# -- channels built on the device from the staged base columns -----------------
+
+def _lineitem(n, seed=7, nulls=False):
+    """A TPC-H lineitem-shaped table: two flags and the four decimals
+    Q1 reads (optionally with nulls in quantity and discount)."""
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, n).astype(np.float64)
+    mask = (rng.random(n) < 0.2) if nulls else None
+    return pa.table({
+        "l_returnflag": pa.array(rng.choice(["A", "N", "R"], n).tolist(), pa.string()),
+        "l_linestatus": pa.array(rng.choice(["F", "O"], n).tolist(), pa.string()),
+        "l_quantity": pa.array(qty, mask=mask),
+        "l_extendedprice": np.round(qty * (900 + rng.random(n) * 1e5) / 100, 2),
+        "l_discount": pa.array(np.round(rng.integers(0, 11, n) / 100, 2),
+                               mask=None if mask is None else np.roll(mask, 1)),
+        "l_tax": np.round(rng.integers(0, 9, n) / 100, 2),
+        "l_shipdate": rng.integers(8000, 10500, n).astype(np.int64),
+    })
+
+
+def _q1_specs():
+    from hyperspace_tpu import lit
+
+    disc_price = col("l_extendedprice") * (lit(1.0) - col("l_discount"))
+    return [
+        AggSpec.of("sum", "l_quantity", "sum_qty"),
+        AggSpec.of("sum", "l_extendedprice", "sum_base_price"),
+        AggSpec.of("sum", disc_price, "sum_disc_price"),
+        AggSpec.of("sum", disc_price * (lit(1.0) + col("l_tax")), "sum_charge"),
+        AggSpec.of("mean", "l_quantity", "avg_qty"),
+        AggSpec.of("mean", "l_extendedprice", "avg_price"),
+        AggSpec.of("mean", "l_discount", "avg_disc"),
+        AggSpec.of("count", None, "count_order"),
+    ]
+
+
+def _agg_both_venues(ct, group_by, aggs):
+    """aggregate_table on the device and the host venue, with the
+    (device, host) channel counters' moves of the device call."""
+    from hyperspace_tpu import stats
+    from hyperspace_tpu.execution.exec_common import _TableLeaf
+    from hyperspace_tpu.ops.aggregate import aggregate_table
+    from hyperspace_tpu.plan.nodes import Aggregate
+
+    schema = Aggregate(_TableLeaf(ct), group_by, aggs).schema
+    names = ("device.kernel.agg_channels_device", "device.kernel.agg_channels_host")
+    before = [stats.get(c) for c in names]
+    dev = aggregate_table(ct, group_by, aggs, schema, venue="device")
+    moved = tuple(stats.get(c) - b for c, b in zip(names, before))
+    return dev, aggregate_table(ct, group_by, aggs, schema, venue="host"), moved
+
+
+def _case_table(case):
+    from hyperspace_tpu import lit
+    from hyperspace_tpu.execution.table import ColumnTable
+    from hyperspace_tpu.plan.expr import CaseBuilder
+
+    if case == "q1":
+        return ColumnTable.from_arrow(_lineitem(3_000)), ["l_returnflag", "l_linestatus"], _q1_specs()
+    if case == "nulls":
+        specs = [
+            AggSpec.of(fn, e, f"{fn}_{i}")
+            for i, e in enumerate(("l_quantity", "l_discount", col("l_quantity") * col("l_discount")))
+            for fn in ("sum", "mean", "count", "min", "max")
+        ]
+        return ColumnTable.from_arrow(_lineitem(2_000, nulls=True)), ["l_linestatus"], specs
+    if case == "null_groups":
+        t = pa.table({
+            "g": pa.array([1, None, 2, None, 1, 3], pa.int64()),
+            "x": pa.array([1.5, 2.0, None, 4.0, None, 7.25]),
+        })
+        specs = [AggSpec.of(fn, "x", fn) for fn in ("sum", "mean", "count", "min", "max")]
+        return ColumnTable.from_arrow(t), ["g"], [*specs, AggSpec.of("count", None, "rows")]
+    if case in ("n_odd", "n_one"):
+        n = 777 if case == "n_odd" else 1
+        return ColumnTable.from_arrow(_lineitem(n)), ["l_returnflag"], _q1_specs()
+    if case == "empty":
+        return ColumnTable.from_arrow(_lineitem(0)), [], _q1_specs()
+    assert case == "case"
+    big = CaseBuilder([(col("l_returnflag") == lit("R"), col("l_quantity"))]).otherwise(lit(0.0))
+    return (
+        ColumnTable.from_arrow(_lineitem(1_500)),
+        ["l_linestatus"],
+        [AggSpec.of("sum", big, "returned_qty"), AggSpec.of("sum", "l_tax", "tax")],
+    )
+
+
+#: Per case, the AggSpecs whose channels the device program builds and
+#: those the host builds.
+_CHANNEL_PATHS = {
+    "q1": (8, 0), "nulls": (15, 0), "null_groups": (6, 0), "n_odd": (8, 0), "n_one": (8, 0),
+    "empty": (8, 0), "case": (1, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHANNEL_PATHS))
+def test_device_channels_match_host_venue(case):
+    """Channels the reduction program builds from the staged base
+    columns give the host venue's results bit for bit (XLA:CPU's
+    scatter adds rows in the host's order), and each AggSpec counts on
+    the path that built its channels: a CASE takes the host build."""
+    ct, group_by, aggs = _case_table(case)
+    dev, host, got_moved = _agg_both_venues(ct, group_by, aggs)
+    assert got_moved == _CHANNEL_PATHS[case]
+    assert dev.num_rows == host.num_rows and dev.num_rows > 0
+    assert set(dev.columns) == set(host.columns)
+    for name in host.columns:
+        np.testing.assert_array_equal(dev.columns[name], host.columns[name], err_msg=name)
+        assert (dev.validity.get(name) is None) == (host.validity.get(name) is None), name
+        if host.validity.get(name) is not None:
+            np.testing.assert_array_equal(dev.validity[name], host.validity[name], err_msg=name)
+
+
+def test_device_channels_are_shape_stable():
+    """A stable table uploads each base column once: a second
+    aggregation over it hits the device cache for every input and adds
+    no entry. Two filtered tables of different row counts under one
+    n_pad compile the channel program once."""
+    from hyperspace_tpu.execution import device_cache as dcache
+    from hyperspace_tpu.execution.table import ColumnTable
+    from hyperspace_tpu.obs import runtime as obs_runtime
+    from hyperspace_tpu.ops.aggregate import group_ids
+
+    ct = ColumnTable.from_arrow(_lineitem(2_500))
+    for a in ct.columns.values():
+        dcache.freeze(a)
+    group_by = ["l_returnflag", "l_linestatus"]
+    gid, k, rep = group_ids(ct, group_by)
+    groups = (dcache.freeze(gid), k, rep)
+
+    def run(table, grp=None):
+        from hyperspace_tpu.execution.exec_common import _TableLeaf
+        from hyperspace_tpu.ops.aggregate import aggregate_table
+        from hyperspace_tpu.plan.nodes import Aggregate
+
+        aggs = _q1_specs()
+        schema = Aggregate(_TableLeaf(table), group_by, aggs).schema
+        return aggregate_table(table, group_by, aggs, schema, venue="device", groups=grp)
+
+    run(ct, groups)
+    s1 = dcache.DEVICE_CACHE.stats()
+    run(ct, groups)
+    s2 = dcache.DEVICE_CACHE.stats()
+    # Four base columns and the group-id pad, each served from HBM.
+    assert (s2["entries"], s2["misses"], s2["hits"] - s1["hits"]) == (s1["entries"], s1["misses"], 5)
+
+    key = "hyperspace_tpu.ops.aggregate._channel_reduce"
+    compiles = lambda: obs_runtime.jit_report().get(key, {}).get("compiles", 0)  # noqa: E731
+    small = ColumnTable.from_arrow(_lineitem(600, seed=1))
+    run(small)
+    after_first = compiles()
+    run(ColumnTable.from_arrow(_lineitem(1_000, seed=2)))  # n_pad 1024 for both
+    assert compiles() == after_first
+
+
+def test_q1_channels_deduplicate(tmp_path):
+    """Q1's eight AggSpecs reduce six channels (four sums, the discount,
+    the shared row count): the span says so, and each mean is its sum
+    over the row count on every group."""
+    root = tmp_path / "lineitem"
+    root.mkdir()
+    pq.write_table(_lineitem(4_000), root / "p.parquet")
+    session = _session(tmp_path)
+    got = session.run(session.parquet(root).aggregate(["l_returnflag", "l_linestatus"], _q1_specs()))
+
+    stack, attrs = [session.last_profile().trace], []
+    while stack:
+        node = stack.pop()
+        if node["name"] == "agg.channels":
+            attrs.append(node["attrs"])
+        stack.extend(node.get("children", ()))
+    assert [a["channels"] for a in attrs] == [6]
+    assert attrs[0]["upload_bytes"] == 4096 * (4 * 8 + 4)
+    cnt = got.column("count_order").astype(np.float64)
+    for avg, total in (("avg_qty", "sum_qty"), ("avg_price", "sum_base_price")):
+        np.testing.assert_array_equal(got.column(avg), got.column(total) / cnt)

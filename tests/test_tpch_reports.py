@@ -79,9 +79,16 @@ def test_q1_profile_shows_the_aggregate_spans_and_path_counter(reports):
     cell, ctx, _roots = reports
     paths = ("lax", "sharded")
     before = {p: stats.get(f"device.kernel.segment_reduce_{p}") for p in paths}
+    built = ("device", "host")
+    built_before = {b: stats.get(f"device.kernel.agg_channels_{b}") for b in built}
     op = harness.execute(ctx, cell, "tpch_q1", draw(cell, "tpch_q1", SEEDS[1]), 0)
     trace = op.evidence["profile"].to_json()["trace"]
-    assert _spans(trace, "agg.channels")
+    # Q1's eight AggSpecs all build their channels in the device program:
+    # six channels from four staged columns.
+    assert {b: stats.get(f"device.kernel.agg_channels_{b}") - built_before[b] for b in built} == {
+        "device": 8, "host": 0,
+    }
+    assert [s["attrs"]["channels"] for s in _spans(trace, "agg.channels")] == [6]
     reduces = _spans(trace, "agg.reduce")
     assert len(reduces) == 1
     path = reduces[0]["attrs"]["path"]

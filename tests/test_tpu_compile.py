@@ -127,26 +127,43 @@ def test_join_agg_bucket_reduce_compiles_at_sf1_q3_shape(one_chip):
 
 
 def test_aggregate_dense_reduce_compiles_at_sf1_q1_shape(one_chip):
-    """TPC-H Q1's grouped aggregate at SF1: eight inputs and their
-    non-null counts over 8,388,608 padded lineitem rows into 8 segments,
-    by the dense reduction: no scatter, and temporaries under twice the
-    1.07 GB channel stack, where a [K, rows] float64 buffer per channel
-    would take 8.6 GB."""
-    from hyperspace_tpu.ops.aggregate import _dense_segment_reduce
+    """TPC-H Q1's grouped aggregate at SF1: its four float64 lineitem
+    columns over 8,388,608 padded rows, the six channels the program
+    builds from them (four sums, the discount, the shared row count)
+    into 8 segments by the dense reduction: no scatter, and temporaries
+    under twice the six channels, where a [K, rows] float64 buffer per
+    channel would take 3.2 GB."""
+    import pyarrow as pa
+
+    from hyperspace_tpu import AggSpec, col, lit
+    from hyperspace_tpu.execution.table import ColumnTable
+    from hyperspace_tpu.ops.aggregate import _channel_reduce, _plan_channels
     from hyperspace_tpu.parallel.x64 import run_x64
 
+    names = ("l_quantity", "l_extendedprice", "l_discount", "l_tax")
+    table = ColumnTable.from_arrow(pa.table({c: np.ones(4) for c in names}))
+    disc_price = col("l_extendedprice") * (lit(1.0) - col("l_discount"))
+    aggs = [
+        AggSpec.of("sum", "l_quantity"), AggSpec.of("sum", "l_extendedprice"),
+        AggSpec.of("sum", disc_price), AggSpec.of("sum", disc_price * (lit(1.0) + col("l_tax"))),
+        AggSpec.of("mean", "l_quantity"), AggSpec.of("mean", "l_extendedprice"),
+        AggSpec.of("mean", "l_discount"), AggSpec.of("count", None),
+    ]
+    arrays, masks, channels, _slots, _n_host = _plan_channels(table, aggs)
+    assert (len(arrays), len(masks), len(channels)) == (4, 0, 6)
     n, k = 1 << 23, 8
-    fns = ("sum",) * 16
     compiled = run_x64(
-        lambda: _dense_segment_reduce.lower(
-            _shape(one_chip, (len(fns), n), jnp.float64),
+        lambda: _channel_reduce.lower(
+            tuple(_shape(one_chip, (n,), jnp.float64) for _ in arrays),
+            (),
             _shape(one_chip, (n,), jnp.int32),
             num_segments=k,
-            fns=fns,
+            channels=channels,
+            reduce="dense",
         ).compile()
     )
     assert " scatter(" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * len(fns) * n * 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * len(channels) * n * 8
 
 
 def test_topk_tile_kernel_compiles(one_chip):
